@@ -30,9 +30,9 @@ def hb(msg: str) -> None:
 
 
 def main() -> None:
-    from bench_common import init_jax_with_watchdog
+    from bench_common import init_jax
 
-    jax = init_jax_with_watchdog("rlc_breakdown", "secs")
+    jax = init_jax()
     import jax.numpy as jnp
 
     platform = jax.devices()[0].platform

@@ -1028,7 +1028,6 @@ async def build_node(config: Config) -> Node:
     # in-process validator client for simnet runs (ref: app/vmock.go —
     # the reference wires validatormock when --simnet-validator-mock)
     if config.simnet and config.simnet_vmock:
-        from charon_tpu.core.types import DutyType
         from charon_tpu.testutil.validatormock import ValidatorMock
 
         vmock = ValidatorMock(
@@ -1218,7 +1217,7 @@ async def build_node(config: Config) -> Node:
                     shapes = await crypto_plane.prewarm()
                 except Exception as e:  # noqa: BLE001 — background task:
                     # lifecycle gathers it silently at shutdown, so a
-                    # failed warmup (wedged claim, compile error) must
+                    # failed warmup (lost device, compile error) must
                     # log here or the operator believes the shapes are
                     # warm while the first live slot eats a cold compile
                     log.warn(
@@ -1303,9 +1302,7 @@ async def build_node(config: Config) -> Node:
         # first live slot never pays the python-bigint cold burst
         warmup = config.crypto_plane_warmup
         if warmup == "auto":
-            # the canonical backend probe (not default_backend() ==
-            # "tpu"): plugin/tunneled TPUs report other platform names,
-            # and the decode rung + warm_point_caches auto both resolve
+            # the decode rung and warm_point_caches' auto resolve
             # through the same helper — the gates must agree
             from charon_tpu.ops import limb as _limb
 

@@ -218,7 +218,7 @@ def env_overrides(environ=None) -> dict:
 def apply_env(environ=None) -> KernelConfig:
     """Defaults + env overrides, applied. The entry point for harnesses
     that pin kernels by env instead of running the tuner
-    (__graft_entry__'s canonical dryrun env, .tpu_watch5.sh)."""
+    (__graft_entry__'s canonical dryrun env)."""
     cfg = dataclasses.replace(KernelConfig(), **env_overrides(environ))
     cfg.apply()
     return cfg

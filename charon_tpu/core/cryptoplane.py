@@ -1269,8 +1269,8 @@ class SlotCoalescer:
 
         def rebuild_and_run():
             # worker thread, NOT the event loop: the factory touches
-            # jax.devices()/compilation, which can block for minutes on
-            # a wedged device claim
+            # jax.devices()/compilation, which blocks for minutes (a
+            # pairing program is minutes of compile)
             self.plane = self._plane_factory()
             return self._run_device(vq, rq, None, window_used, inflight)
 

@@ -9,7 +9,7 @@ failure the affected jobs run on the local rung (`local` — the node's
 own SlotCoalescer / TenantPlane, which itself sits on the tbls ladder)
 and duties keep completing.
 
-Failure taxonomy -> behavior:
+Failure classes -> behavior:
 
   * connect refused / handshake failure ... jobs go local immediately
     ("down" state); a supervisor task reconnects on the expbackoff

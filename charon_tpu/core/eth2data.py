@@ -1,7 +1,7 @@
 """Eth2 duty data objects: unsigned inputs and signed outputs.
 
 Mirrors the reference's UnsignedData / SignedData / Eth2SignedData value
-taxonomy (ref: core/types.go:52-91, core/eth2signeddata.go,
+classification (ref: core/types.go:52-91, core/eth2signeddata.go,
 core/unsigneddata.go, core/signeddata.go) with frozen dataclasses and
 spec-exact SSZ roots (charon_tpu/eth2util/ssz.py).
 

@@ -693,16 +693,10 @@ FR32 = make_ctx("fr32", FR_MOD, 22, limb_bits=12, np_dtype=np.uint32)
 
 
 def _is_tpu_backend() -> bool:
-    """True when the default device is a TPU — including TPUs exposed via
-    alternative PJRT plugins whose platform name is not literally "tpu"
-    (e.g. tunneled plugins reporting device_kind "TPU v5 lite")."""
-    if jax.default_backend() == "tpu":
-        return True
-    try:
-        d = jax.devices()[0]
-        return "tpu" in f"{d.platform} {d.device_kind}".lower()
-    except Exception:
-        return False
+    """True when the default JAX backend is a TPU. A backend that fails
+    to initialise raises here: answering "not a TPU" would silently
+    select the CPU limb geometry."""
+    return jax.default_backend() == "tpu"
 
 
 def default_fp_ctx() -> ModCtx:

@@ -364,20 +364,32 @@ def _mxu_usable(ctx: ModCtx) -> bool:
     return ctx.limb_bits == 12 and ctx.np_dtype is np.uint32
 
 
+def _vma_of(*arrays) -> frozenset:
+    """Union of the operands' shard_map varying axes: a pallas_call
+    under `jax.shard_map(check_vma=True)` must declare them on its
+    out_shape (empty outside shard_map, where it is ignored)."""
+    return frozenset().union(*(jax.typeof(a).vma for a in arrays))
+
+
 @functools.lru_cache(maxsize=None)
-def _mont_call(ctx: ModCtx, interpret: bool, mxu: bool = False):
+def _mont_call(
+    ctx: ModCtx,
+    interpret: bool,
+    mxu: bool = False,
+    vma: frozenset = frozenset(),
+):
     """Gridless pallas_call over one (TILE, n_limbs) block. Batches
-    larger than TILE run it under lax.map — Mosaic on this platform
-    fails to legalize block index maps (i64 returns), and a device-side
-    map over a fixed-shape kernel compiles the kernel exactly once
-    anyway."""
+    larger than TILE run it under lax.map: a device-side map over a
+    fixed-shape kernel compiles the kernel exactly once. (The gridless
+    design dates from an installation whose Mosaic could not legalize
+    block index maps; a grid has not been tried on the installed one.)"""
     n = ctx.n_limbs
     body = _mont_mxu_kernel_body if mxu else _mont_kernel_body
     n_in = 7 if mxu else 3
     kernel = functools.partial(body, ctx)
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((TILE, n), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((TILE, n), jnp.uint32, vma=vma),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * n_in,
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         interpret=interpret,
@@ -385,13 +397,19 @@ def _mont_call(ctx: ModCtx, interpret: bool, mxu: bool = False):
 
 
 @functools.lru_cache(maxsize=None)
-def _fp2_call(ctx: ModCtx, kind: str, interpret: bool, mxu: bool = False):
+def _fp2_call(
+    ctx: ModCtx,
+    kind: str,
+    interpret: bool,
+    mxu: bool = False,
+    vma: frozenset = frozenset(),
+):
     """Gridless pallas_call for the fused Fp2 kernels (same lax.map
     chunking strategy as the mont kernel)."""
     n = ctx.n_limbs
     out_shape = (
-        jax.ShapeDtypeStruct((TILE, n), jnp.uint32),
-        jax.ShapeDtypeStruct((TILE, n), jnp.uint32),
+        jax.ShapeDtypeStruct((TILE, n), jnp.uint32, vma=vma),
+        jax.ShapeDtypeStruct((TILE, n), jnp.uint32, vma=vma),
     )
     if kind == "mul":
         body = _fp2_mul_mxu_kernel_body if mxu else _fp2_mul_kernel_body
@@ -446,7 +464,7 @@ def _run_fp2(
         flats = [jnp.pad(f, ((0, padded - rows), (0, 0))) for f in flats]
     extras = _mxu_extras(ctx, mxu)
     consts = jnp.asarray(_ctx_consts(ctx))
-    call = _fp2_call(ctx, kind, interpret, mxu)
+    call = _fp2_call(ctx, kind, interpret, mxu, _vma_of(*flats))
     if padded == TILE:
         c0, c1 = call(*flats, *extras, consts)
     else:
@@ -500,7 +518,7 @@ def mont_mul_pallas(
         flat_b = jnp.pad(flat_b, pad)
     extras = _mxu_extras(ctx, mxu)
     consts = jnp.asarray(_ctx_consts(ctx))
-    call = _mont_call(ctx, interpret, mxu)
+    call = _mont_call(ctx, interpret, mxu, _vma_of(flat_a, flat_b))
     if padded == TILE:
         out = call(flat_a, flat_b, *extras, consts)
     else:

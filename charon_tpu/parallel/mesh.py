@@ -23,11 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.4.35 top-level spelling
-    _shard_map = jax.shard_map
-except AttributeError:  # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from charon_tpu.ops import blsops
 from charon_tpu.ops import curve as C
 from charon_tpu.ops import decompress as DEC
@@ -183,7 +178,7 @@ class SlotCryptoPlane:
     def _build(self):
         axis = self.axis
 
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             self._step_body,
             mesh=self.mesh,
             in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis), P(axis)),
@@ -207,7 +202,7 @@ class SlotCryptoPlane:
                 ps, msg, partials, gpk, idx, jnp.logical_and(live, row_ok)
             )
 
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             local_step,
             mesh=self.mesh,
             in_specs=(
@@ -231,7 +226,7 @@ class SlotCryptoPlane:
         per shard — core/sigagg/sigagg.go:84-122)."""
         axis = self.axis
 
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             self._step_rlc_body,
             mesh=self.mesh,
             in_specs=(
@@ -325,7 +320,7 @@ class SlotCryptoPlane:
             )
             return group_sig, all_ok, row_ok
 
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             local_step,
             mesh=self.mesh,
             in_specs=(
@@ -350,7 +345,7 @@ class SlotCryptoPlane:
             ok = DP.batched_verify(ctx, pk, msg, sig)
             return jnp.logical_and(jnp.logical_and(ok, dec_ok), live)
 
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             local,
             mesh=self.mesh,
             in_specs=(
@@ -378,7 +373,7 @@ class SlotCryptoPlane:
             bad = jax.lax.psum(jnp.logical_not(ok).astype(jnp.int32), axis)
             return bad == 0, lane_ok
 
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             local,
             mesh=self.mesh,
             in_specs=(
@@ -401,7 +396,7 @@ class SlotCryptoPlane:
             )
             return aff, jnp.logical_and(valid, live)
 
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             local,
             mesh=self.mesh,
             in_specs=(
@@ -423,7 +418,7 @@ class SlotCryptoPlane:
             )
             return aff, jnp.logical_and(valid, live)
 
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             local,
             mesh=self.mesh,
             in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis)),
@@ -441,7 +436,7 @@ class SlotCryptoPlane:
             ok = DP.batched_verify(ctx, pk, msg, sig)
             return jnp.logical_and(ok, live)
 
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             local,
             mesh=self.mesh,
             in_specs=(P(axis), P(axis), P(axis), P(axis)),
@@ -463,7 +458,7 @@ class SlotCryptoPlane:
             bad = jax.lax.psum(jnp.logical_not(ok).astype(jnp.int32), axis)
             return bad == 0
 
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             local,
             mesh=self.mesh,
             in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis)),
@@ -985,6 +980,113 @@ class SlotCryptoPlane:
     PREWARM_VERIFY_LANES = (1, 16, 64, 256)
     PREWARM_RECOMBINE_LANES = (1, 16, 64)
 
+    def prewarm_programs(
+        self,
+        verify_lanes=None,
+        recombine_lanes=None,
+        decompress: bool = False,
+    ) -> list[tuple[str, str, int, "callable"]]:
+        """The programs `prewarm` compiles, one entry each:
+        [(kind, family, bucket_lanes, run)] where `run()` packs
+        generator-point dummies for that bucket, dispatches the ONE
+        program `family` names (same names as kernel_families /
+        on_program, minus the "mesh/" prefix) and syncs its result.
+        Callers that cannot afford every tier (a cold boot that only
+        ever lands on the RLC fast path) pick the families they need;
+        `prewarm` runs them all."""
+        from charon_tpu.crypto.g1g2 import G1_GEN, G2_GEN, g2_to_bytes
+
+        if verify_lanes is None:
+            verify_lanes = self.PREWARM_VERIFY_LANES
+        if recombine_lanes is None:
+            recombine_lanes = self.PREWARM_RECOMBINE_LANES
+        verify_lanes = _dedupe_buckets(verify_lanes, self.bucket_lanes)
+        recombine_lanes = _dedupe_buckets(recombine_lanes, self.bucket_lanes)
+        t = self.t
+        idx_row = list(range(1, t + 1))
+        # generator-point encodings: decompression takes the live
+        # (finite, subgroup-valid) path through the sqrt chain
+        gen_parsed = DEC.parse_g2_lane(g2_to_bytes(G2_GEN))
+
+        def verify_args(n):
+            return (
+                *self.pack_verify_inputs(
+                    [G1_GEN] * n, [G2_GEN] * n, [G2_GEN] * n
+                ),
+                self.make_lane_rand(n),
+            )
+
+        def verify_dec_args(n):
+            return (
+                *self.pack_verify_inputs_parsed(
+                    [G1_GEN] * n, [G2_GEN] * n, [gen_parsed] * n
+                ),
+                self.make_lane_rand(n),
+            )
+
+        def step_args(v):
+            return (
+                *self.pack_inputs(
+                    [[G1_GEN] * t] * v,
+                    [G2_GEN] * v,
+                    [[G2_GEN] * t] * v,
+                    [G1_GEN] * v,
+                    [idx_row] * v,
+                ),
+                self.make_rand(v),
+            )
+
+        def step_dec_args(v):
+            return (
+                *self.pack_inputs_parsed(
+                    [[G1_GEN] * t] * v,
+                    [G2_GEN] * v,
+                    [[gen_parsed] * t] * v,
+                    [G1_GEN] * v,
+                    [idx_row] * v,
+                ),
+                self.make_rand(v),
+            )
+
+        # (kind, lanes, args builder, [(family, program, takes rand)]):
+        # each shape compiles BOTH tiers — the RLC fast path AND the
+        # per-lane attribution program
+        groups = [
+            ("verify", verify_lanes, verify_args,
+             [("verify_rlc", self._verify_rlc, True),
+              ("verify", self._verify, False)]),
+            ("recombine", recombine_lanes, step_args,
+             [("step_rlc", self._step_rlc, True),
+              ("step", self._step, False)]),
+        ]
+        if decompress:
+            # decode-fused programs (decode_mode device): same buckets
+            groups += [
+                ("verify-dec", verify_lanes, verify_dec_args,
+                 [("verify_rlc_dec", self._verify_rlc_dec, True),
+                  ("verify_dec", self._verify_dec, False)]),
+                ("recombine-dec", recombine_lanes, step_dec_args,
+                 [("step_rlc_dec", self._step_rlc_dec, True),
+                  ("step_dec", self._step_dec, False)]),
+            ]
+
+        def runner(build, prog, with_rand, n):
+            def run():
+                args = build(n)
+                jax.block_until_ready(
+                    prog(*(args if with_rand else args[:-1]))
+                )
+
+            return run
+
+        return [
+            (kind, family, self.bucket_lanes(n),
+             runner(build, prog, with_rand, n))
+            for kind, lanes, build, tiers in groups
+            for n in lanes
+            for family, prog, with_rand in tiers
+        ]
+
     def prewarm(
         self,
         verify_lanes=None,
@@ -1001,85 +1103,24 @@ class SlotCryptoPlane:
         the attribution tier and the first forged lane mid-slot would
         still eat a cold compile). Shapes land on the same bucket
         ladder live flushes pad to, deduplicated per bucket. Returns
-        [(kind, bucket_lanes, seconds)] per compiled shape.
+        [(kind, bucket_lanes, seconds)] per compiled shape (both tiers'
+        seconds summed; prewarm_programs has the per-program split).
 
         app/run.py sequences this AFTER core/autotune.resolve so the
         programs compile under the TUNED KernelConfig routing (and,
         warm, replay as persistent-cache loads — the AOT artifact
         story); the tuner's prewarm ladder (autotune.PREWARM_LANES)
         deliberately matches these shapes."""
-        import time as _time
-
-        from charon_tpu.crypto.g1g2 import G1_GEN, G2_GEN
-
-        if verify_lanes is None:
-            verify_lanes = self.PREWARM_VERIFY_LANES
-        if recombine_lanes is None:
-            recombine_lanes = self.PREWARM_RECOMBINE_LANES
-        verify_lanes = _dedupe_buckets(verify_lanes, self.bucket_lanes)
-        recombine_lanes = _dedupe_buckets(recombine_lanes, self.bucket_lanes)
-        report: list[tuple[str, int, float]] = []
-        for n in verify_lanes:
-            t0 = _time.monotonic()
-            pk, msg, sig, live = self.pack_verify_inputs(
-                [G1_GEN] * n, [G2_GEN] * n, [G2_GEN] * n
+        seconds: dict[tuple[str, int], float] = {}
+        for kind, _family, bucket, run in self.prewarm_programs(
+            verify_lanes, recombine_lanes, decompress
+        ):
+            t0 = time.monotonic()
+            run()
+            seconds[kind, bucket] = (
+                seconds.get((kind, bucket), 0.0) + time.monotonic() - t0
             )
-            rand = self.make_lane_rand(n)
-            bool(self._verify_rlc(pk, msg, sig, live, rand))
-            np.asarray(self._verify(pk, msg, sig, live))
-            report.append(("verify", self.bucket_lanes(n),
-                           _time.monotonic() - t0))
-        t = self.t
-        idx_row = list(range(1, t + 1))
-        for v in recombine_lanes:
-            t0 = _time.monotonic()
-            args = self.pack_inputs(
-                [[G1_GEN] * t] * v,
-                [G2_GEN] * v,
-                [[G2_GEN] * t] * v,
-                [G1_GEN] * v,
-                [idx_row] * v,
-            )
-            rand = self.make_rand(v)
-            self.step_rlc(*args, rand)
-            np.asarray(self.step(*args)[1])
-            report.append(("recombine", self.bucket_lanes(v),
-                           _time.monotonic() - t0))
-        if decompress:
-            # decode-fused programs (decode_mode device): same buckets,
-            # generator-point encodings so decompression takes the live
-            # (finite, subgroup-valid) path through the sqrt chain
-            from charon_tpu.crypto.g1g2 import g2_to_bytes
-
-            gen_parsed = DEC.parse_g2_lane(g2_to_bytes(G2_GEN))
-            for n in verify_lanes:
-                t0 = _time.monotonic()
-                arrays = self.pack_verify_inputs_parsed(
-                    [G1_GEN] * n, [G2_GEN] * n, [gen_parsed] * n
-                )
-                rand = self.make_lane_rand(n)
-                pk, msg, sx0, sx1, sign, live = arrays
-                bool(
-                    self._verify_rlc_dec(pk, msg, sx0, sx1, sign, live, rand)[0]
-                )
-                np.asarray(self._verify_dec(pk, msg, sx0, sx1, sign, live))
-                report.append(("verify-dec", self.bucket_lanes(n),
-                               _time.monotonic() - t0))
-            for v in recombine_lanes:
-                t0 = _time.monotonic()
-                args = self.pack_inputs_parsed(
-                    [[G1_GEN] * t] * v,
-                    [G2_GEN] * v,
-                    [[gen_parsed] * t] * v,
-                    [G1_GEN] * v,
-                    [idx_row] * v,
-                )
-                rand = self.make_rand(v)
-                self._step_rlc_dec(*args, rand)
-                np.asarray(self._step_dec(*args)[1])
-                report.append(("recombine-dec", self.bucket_lanes(v),
-                               _time.monotonic() - t0))
-        return report
+        return [(kind, bucket, s) for (kind, bucket), s in seconds.items()]
 
 
 _ANALYSIS_PLANE_T = 3  # canonical threshold for the analyzer's plane

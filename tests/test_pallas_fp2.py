@@ -132,7 +132,9 @@ orig_call = PK._fp2_call
 with mock.patch.object(
     PK,
     "_fp2_call",
-    lambda ctx, kind, interpret, mxu=False: orig_call(ctx, kind, True, mxu),
+    lambda ctx, kind, interpret, mxu=False, vma=frozenset(): orig_call(
+        ctx, kind, True, mxu, vma
+    ),
 ):
     got_ops = T._fp2_batch_pallas(CTX, ops)
 assert len(got_ops) == len(want_ops)
