@@ -1,0 +1,203 @@
+"""The readers of the node's own spans (benchmark/nodespans.py and the
+readers built on it), on a synthetic span forest and on the recorded trace
+tests/data/tiny.xplane.pb: the innermost-span rule, self time with
+overlapping children, `shared` copies counted once, the idle causes summing
+to `window_s - busy_s`, and nothing read where the ring is not one whole
+node's.
+
+    python -m pytest benchmark/tests/test_nodespans.py -q -p no:cacheprovider
+"""
+
+from __future__ import annotations
+
+import sys
+from pathlib import Path
+
+import pytest
+
+REPO = Path(__file__).resolve().parents[2]
+sys.path.insert(0, str(REPO))
+
+from benchmark import manifest as M, nodespans, tracered  # noqa: E402
+from charon_tpu.app import tracer  # noqa: E402
+
+TRACE = "c" * 32
+SLOT = 12.0
+T0 = 1000.0  # the traced slot's start; its trigger is due at T0 + 4
+
+
+def span(name, start, end, sid, parent="", trace=TRACE, **attrs):
+    return tracer.Span(trace, sid, parent, name, T0 + start, T0 + end, dict(attrs))
+
+
+def wave():
+    """One attester wave as the node records it, seconds from the slot's
+    start: decided 4.2, the VC's submission 4.4 and a peer's set 4.5 ride
+    one verify flush (window 4.6-4.9, decode/pack to 5.3, device to 6.4)."""
+    duty = {"duty": "7/attester", "slot": 7}
+    flush = [  # (name, start, end): bridged under each submitter
+        ("cryptoplane.window", 4.6, 4.9), ("cryptoplane.flush", 4.45, 6.4)]
+    stages = [("cryptoplane.decode", 4.45, 4.6), ("cryptoplane.decode", 4.7, 4.8),
+              ("cryptoplane.pack", 4.9, 5.3), ("cryptoplane.device", 5.3, 6.4)]
+    out = [
+        span("fetcher.fetch", 4.0, 4.25, "ff", **duty),
+        span("consensus.propose", 4.01, 4.25, "p0", "ff", **duty),
+        span("qbft.instance", 4.02, 4.2, "q0", "p0", **duty),
+        span("dutydb.store", 4.2, 4.25, "d0", "p0", **duty),
+        span("qbft.deliver", 4.05, 4.06, "q1", **duty),
+        span("vapi.submit", 4.4, 6.5, "v0", **duty),
+        span("cryptosvc.queue", 4.42, 4.45, "vq", "v0"),
+        span("parsigex.receive", 4.5, 6.6, "r0", **duty),
+        span("parsigex.verify", 4.5, 6.45, "r1", "r0"),
+        span("cryptosvc.queue", 4.55, 4.6, "rq", "r1"),
+        span("parsigdb.store_external", 6.45, 6.6, "r2", "r0", **duty),
+        # a duty from before the window that never decided, cancelled at
+        # its deadline slots later, and a duty the traffic never completes:
+        # both open all the while, neither the window's attester duty
+        span("qbft.instance", -20.0, 10.0, "st", trace="d" * 32, duty="5/attester", slot=5),
+        span("fetcher.fetch", 0.0, 16.0, "a0", trace="a" * 32, duty="7/prepare_aggregator",
+             slot=7),
+    ]
+    for n, parent in enumerate(("v0", "r1")):
+        shared = {"shared": True} if n else {}
+        for name, a, b in flush:
+            out.append(span(name, a, b, f"{name[12]}{n}", parent, **shared))
+        for k, (name, a, b) in enumerate(stages):
+            out.append(span(name, a, b, f"s{n}{k}", f"f{n}", **shared))
+    return out
+
+
+class Run:
+    slot_duration = SLOT
+    window = (T0, T0 + SLOT)
+    slots = [7]
+    trace = None
+
+    def in_window(self, ts):
+        return self.window[0] <= ts < self.window[1] + SLOT
+
+    def waves(self):
+        return [{"slot": 7, "duties": 2, "due": T0 + 4.0, "last_done": T0 + 7.0}]
+
+
+@pytest.fixture()
+def node(monkeypatch):
+    """One registered node whose ring holds the synthetic wave."""
+    t = tracer.Tracer()
+    for s in wave():
+        t.record(s)
+    monkeypatch.setattr(tracer, "_NODE_TRACERS", {0: t})
+    monkeypatch.setattr(nodespans, "_last", (None, None))
+    return t
+
+
+def read(name, run):
+    man = M.load_manifest(REPO)
+    entry = next(m for m in man["per_layer"] if m["name"] == name)
+    metric = M._metric(M.bench_dir(REPO, man), entry)
+    return M.load_reader(REPO, man, metric.reader)(run, **metric.params)
+
+
+def test_self_time_is_the_span_minus_what_its_children_cover():
+    spans = wave()
+    children = nodespans.by_parent(spans)
+    by_id = {s.span_id: s for s in spans}
+    rel = lambda ivs: [(round(a - T0, 3), round(b - T0, 3)) for a, b in ivs]  # noqa: E731
+    # overlapping children (queue, flush, window) are a union, not a sum
+    assert rel(nodespans.self_intervals(by_id["v0"], children)) == [(4.4, 4.42), (6.4, 6.5)]
+    # a child that starts before its parent is clipped to it
+    assert rel(nodespans.self_intervals(by_id["r1"], children)) == [(6.4, 6.45)]
+    assert rel(nodespans.self_intervals(by_id["r0"], children)) == []
+    assert rel(nodespans.self_intervals(by_id["q0"], children)) == [(4.02, 4.2)]
+
+
+def test_the_span_metrics_on_the_synthetic_wave(node):
+    run = Run()
+    # vapi.submit 0.02 + 0.1, parsigex.verify 0.05 (inside vapi.submit's
+    # 6.4-6.5: the union counts those instants once), parsigex.receive 0
+    assert read("entry_self_s", run) == pytest.approx(0.12)
+    assert read("qbft_decide_s", run) == pytest.approx(0.18)
+    assert read("svc_queue_s", run) == pytest.approx(0.04)  # median of 0.03, 0.05
+    # the window is bridged twice; the `shared` copy is no second flush
+    assert read("window_wait_s", run) == pytest.approx(0.3)
+    assert read("agg_bcast_self_s", run) is None  # nothing aggregated yet: left out
+
+
+def test_an_idle_instant_gets_the_cause_nearest_the_device(node):
+    spans = nodespans.duty_spans(Run(), list(node.spans), "attester")
+    assert all(s.trace_id == TRACE for s in spans)  # the parked fetch is another duty's
+    seg = nodespans.cause_segments(spans, [T0 + 4.0], SLOT, T0, T0 + 7.0)
+    assert seg[0][0] == T0 and seg[-1][1] == T0 + 7.0
+    assert all(a[1] == b[0] for a, b in zip(seg, seg[1:]))  # a partition
+
+    def cause(at):
+        return next(c for lo, hi, c in seg if lo <= T0 + at < hi)
+
+    assert cause(2.0) == "pre_trigger"
+    assert cause(4.005) == "other"  # fetcher.fetch alone
+    assert cause(4.015) == cause(4.1) == cause(4.055) == "consensus"
+    assert cause(4.22) == "other"  # dutydb.store under the propose edge
+    assert cause(4.3) == "awaiting_input"  # decided; the VC has not come back
+    assert cause(4.41) == "entry" and cause(4.43) == "window"  # the tenant's queue
+    assert cause(4.5) == "pack"  # a decode stretch beats the entry spans beside it
+    assert cause(4.65) == "window" and cause(4.75) == "pack" and cause(4.85) == "window"
+    assert cause(5.0) == "pack" and cause(6.0) == "other"  # device span: not idle time anyway
+    assert cause(6.42) == "entry" and cause(6.55) == "entry"
+    assert cause(6.8) == "awaiting_input"
+
+
+def test_the_idle_causes_sum_to_window_minus_busy(node):
+    run = Run()
+    run.trace = tracered.reduce_file(
+        str(REPO / "benchmark/tests/data/tiny.xplane.pb"), T0 + 4.1, 0.786)
+    total = nodespans.idle_seconds(run, "attester")
+    assert set(total) == set(nodespans.ORDER + nodespans.NO_SPAN)
+    assert sum(total.values()) == pytest.approx(run.trace.window_s - run.trace.busy_s, abs=1e-9)
+    # the 0.786 s from 4.1: consensus to 4.2, other to 4.25, waiting to 4.4, ...
+    assert total["consensus"] == pytest.approx(0.1, abs=2e-3)
+    assert total["awaiting_input"] == pytest.approx(0.15, abs=2e-3)
+    assert total["pack"] == pytest.approx(0.15 + 0.1, abs=2e-3)
+    assert total["window"] == pytest.approx(0.03 + 0.1 + 0.086, abs=2e-3)
+    assert total["pre_trigger"] == 0.0
+    for name in ("consensus", "awaiting_input", "entry", "window", "pack"):
+        assert read(f"idle_s.{name}", run) == total[name]
+    run.trace = None  # --trace 0, or a reader with nothing to read
+    nodespans._last = (None, None)
+    assert read("idle_s.pack", run) is None
+
+
+@pytest.mark.parametrize("state", ["no node", "two nodes", "wrapped ring", "no registry"])
+def test_a_ring_that_is_not_one_whole_nodes_is_not_read(monkeypatch, node, state):
+    if state == "no node":
+        monkeypatch.setattr(tracer, "_NODE_TRACERS", {})
+    elif state == "two nodes":
+        monkeypatch.setattr(tracer, "_NODE_TRACERS", {0: node, 1: tracer.Tracer()})
+    elif state == "wrapped ring":
+        small = tracer.Tracer(capacity=8)
+        for s in wave():
+            small.record(s)
+        assert small.evicted > 0
+        monkeypatch.setattr(tracer, "_NODE_TRACERS", {0: small})
+    else:  # the program before it had a tracer per node
+        monkeypatch.delattr(tracer, "node_tracers")
+    assert nodespans.node_spans() is None
+    run = Run()
+    run.trace = tracered.reduce_file(
+        str(REPO / "benchmark/tests/data/tiny.xplane.pb"), T0 + 4.1, 0.786)
+    for name in ("entry_self_s", "qbft_decide_s", "svc_queue_s", "window_wait_s",
+                 "agg_bcast_self_s", "idle_s.window"):
+        assert read(name, run) is None
+
+
+def test_the_new_metrics_are_files_and_entries_like_the_old_ones():
+    man = M.load_manifest(REPO)
+    assert M.validate(man) == []
+    new = [m for m in man["per_layer"] if m["source"] in ("program_span", "device_trace")
+           and (m["name"].startswith("idle_s.") or m["layer"] in ("Entry", "Tenant service")
+                or m["name"] in ("qbft_decide_s", "agg_bcast_self_s", "window_wait_s"))]
+    assert len(new) == 10 and man["per_layer"][-10:] == new  # appended, in one block
+    cells = [w["name"] for w in man["workloads"]]
+    for m in new:
+        assert m["moves"] == "duty_p50_s" and m["workloads"] == cells
+        assert M._metric(M.bench_dir(REPO, man), m).reader in (
+            "span_self", "span_duration", "idle_cause")

@@ -413,7 +413,8 @@ class ClusterMetrics:
         self.step_latency = Histogram(
             "core_step_latency_seconds",
             "Workflow step latency derived from span ends (wire edges, "
-            "parsigex/qbft receive paths, crypto-plane stages)",
+            "entry and consensus spans, tenant queue, coalescing window, "
+            "crypto-plane stages)",
             labels + ["step"],
             registry=self.registry,
             buckets=(0.001, 0.005, 0.02, 0.05, 0.2, 0.5, 2.0, 10.0),
@@ -522,16 +523,20 @@ class ClusterMetrics:
         )
         self.plane_kernel_seconds = counter(
             "tpu_plane_kernel_seconds_total",
-            "Device-dispatch wall seconds by mesh kernel family "
-            "(mesh/verify_rlc, mesh/step, ... per kernel_inventory; "
-            "'device' = plane without program hooks), sampled by the "
-            "plane profiler from SlotCryptoPlane.on_program",
+            "Host stopwatch around dispatch + result sync of each "
+            "compiled program, by mesh kernel family (mesh/verify_rlc, "
+            "mesh/step, ... per kernel_inventory; 'device' = plane "
+            "without program hooks), sampled by the plane profiler from "
+            "SlotCryptoPlane.on_program. Not device busy time: it holds "
+            "dispatch, transfer and sync beside the kernels",
             ["family"],
         )
         self.plane_device_utilization = Gauge(
             "tpu_plane_device_utilization",
-            "Device duty cycle: flush device_span seconds over the "
-            "profiler's rolling window, 0..1",
+            "Share of the profiler's rolling window spent inside flush "
+            "device_span — the host's clock around a flush's dispatch + "
+            "sync, 0..1. An upper bound on the device's duty cycle, not "
+            "a device-side reading",
             labels,
             registry=self.registry,
         )

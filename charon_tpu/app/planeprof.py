@@ -77,6 +77,10 @@ class PlaneProfiler:
         self.tenant_seconds: dict[str, float] = {}
         self.flushes = 0
         self.utilization = 0.0
+        # the samples attributed to the most recent flush — read by the
+        # hooks chained BEHIND stats_hook (same worker thread), e.g. to
+        # name the programs on the flush's `cryptoplane.device` span
+        self.last_samples: list[tuple[str, float, int]] = []
 
     # -- producers ---------------------------------------------------------
 
@@ -118,6 +122,7 @@ class PlaneProfiler:
             # hook-less plane (SimHostPlane, host rungs): the whole span
             # is one opaque device dispatch
             samples = [(FALLBACK_FAMILY, device_s, getattr(stats, "lanes", 0))]
+        self.last_samples = samples
         for family, seconds, _lanes in samples:
             self.kernel_seconds[family] = (
                 self.kernel_seconds.get(family, 0.0) + seconds

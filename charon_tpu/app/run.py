@@ -47,6 +47,12 @@ from charon_tpu.eth2util.signing import ForkInfo
 from charon_tpu.p2p.adapters import TcpParSigTransport, TcpQbftNet
 from charon_tpu.p2p.transport import P2PNode, PeerSpec
 
+# The node's span ring (app/tracer.Tracer capacity), sized from what a run
+# records: ~175 spans a slot for a 4-of-7 cluster's attester wave of 31-32
+# validators (~65 for 3-of-4; a verify flush is bridged under each of its
+# submitters), so four slots with a factor of two need ~1,400 (PERF.md §3).
+TRACE_RING_SPANS = 4096
+
 
 @dataclass
 class Config:
@@ -184,6 +190,7 @@ class Node:
     flightrec: object | None = None  # app/flightrec.FlightRecorder
     profiler: object | None = None  # app/planeprof.PlaneProfiler
     slo: object | None = None  # app/health.SLOEngine
+    tracer: object | None = None  # app/tracer.Tracer: this node's own
     # the live pubshare registry (shared with Eth2Verifier/ValidatorAPI
     # by reference) — apply_reshare rotates it in place
     pubshares_by_idx: dict | None = None
@@ -450,19 +457,22 @@ async def build_node(config: Config) -> Node:
     # installed BEFORE the workflow wires so every span — including those
     # recorded during component construction — lands in this node's
     # tracer (ref: app/app.go:162 wireTracing runs first)
+    # The tracer is the NODE's own, handed to every span site of the
+    # served path below: components built bare in the same process (an
+    # in-process peer, a test's fake) keep the process-global default,
+    # so their spans reach neither this ring nor the hooks on it.
     otlp = None
     if config.tracing_endpoint:
         otlp = tracer.OTLPExporter(
             config.tracing_endpoint,
             service_name=f"charon-tpu-node{config.node_index}",
         )
-    if otlp is not None or config.tracing_jsonl:
-        tracer.set_global_tracer(
-            tracer.Tracer(
-                jsonl_path=config.tracing_jsonl or None, exporter=otlp
-            )
-        )
-    node_tracer = tracer.global_tracer()
+    node_tracer = tracer.Tracer(
+        capacity=TRACE_RING_SPANS,
+        jsonl_path=config.tracing_jsonl or None,
+        exporter=otlp,
+    )
+    tracer.register_node_tracer(config.node_index, node_tracer)
     # span ends feed the per-step latency histograms and the slow-duty
     # detector (finalized at duty expiry, below)
     from charon_tpu.app.metrics import SlowDutyDetector, span_metrics
@@ -477,11 +487,9 @@ async def build_node(config: Config) -> Node:
             return
         slo.observe_step(max(0.0, span.end - span.start), tenant=slo_tenant)
 
-    # keep handles so shutdown can unhook: node_tracer may be the
-    # process-global tracer (default build), and a later build_node in
-    # the same process must not feed spans into THIS node's registry
-    _node_hooks = [span_metrics(metrics), slow_detector.observe, _slo_span]
-    node_tracer.hooks.extend(_node_hooks)
+    node_tracer.hooks.extend(
+        [span_metrics(metrics), slow_detector.observe, _slo_span]
+    )
     if crypto_plane is not None:
         # one rich per-flush stats hook (runs on the device worker
         # thread — prometheus client objects are thread-safe)
@@ -530,8 +538,20 @@ async def build_node(config: Config) -> Node:
         # the profiler attributes the buffered per-program samples to
         # this flush, and the flight recorder logs the flush summary —
         # all on the serialized device worker thread
+        def _flush_programs() -> list[str]:
+            # "family@bucket" of every program the profiler attributed
+            # to the flush being bridged (same worker thread); the
+            # coalescer may have rebuilt its plane, so it is read late
+            bucket = getattr(crypto_plane.plane, "bucket_lanes", int)
+            return [
+                f"{family.split('/', 1)[-1]}@{bucket(lanes)}"
+                for family, _seconds, lanes in profiler.last_samples
+            ]
+
         _stats_chain = profiler.stats_hook(
-            inner=tracer.plane_span_bridge(node_tracer, inner_hook=_plane_stats)
+            inner=tracer.plane_span_bridge(
+                node_tracer, inner_hook=_plane_stats, programs=_flush_programs
+            )
         )
         if flight is not None:
             _stats_chain = flightrec_mod.stats_hook(flight, inner=_stats_chain)
@@ -558,6 +578,7 @@ async def build_node(config: Config) -> Node:
             crypto_plane,
             round_lanes=config.crypto_plane_round_lanes,
             observer=tenant_obs,
+            tracer=node_tracer,
         )
         tenant_plane = crypto_svc.register(
             tenant_id,
@@ -829,6 +850,7 @@ async def build_node(config: Config) -> Node:
         privkey=k1_key,
         pubkeys=op_pubkeys,
         gater=duty_gater,
+        tracer=node_tracer,
         evidence=evidence,
     )
     consensus = ConsensusController(qbft_consensus)
@@ -853,6 +875,7 @@ async def build_node(config: Config) -> Node:
         fork=fork,
         slots_per_epoch=config.slots_per_epoch,
         plane=tenant_plane,
+        tracer=node_tracer,
     )
     verifier = Eth2Verifier(
         fork,
@@ -866,6 +889,7 @@ async def build_node(config: Config) -> Node:
         parsig_transport,
         verifier,
         gater=duty_gater,
+        tracer=node_tracer,
         evidence=evidence,
     )
     scheduler = Scheduler(
@@ -1523,26 +1547,15 @@ async def build_node(config: Config) -> Node:
         life.register_stop(Order.TRACKER, "flightrec", stop_flight)
 
     # exporter/JSONL built at the top of build_node (spans flow for the
-    # node's whole life); flushed + closed at shutdown. Registered
-    # unconditionally: the metric/slow-duty hooks must come OFF the
-    # tracer even in default builds where it is the process-global one,
-    # or a rebuild in the same process would keep feeding spans into
-    # this node's dead registry.
-    _own_tracer = otlp is not None or bool(config.tracing_jsonl)
-
+    # node's whole life); flushed + closed at shutdown. The ring and its
+    # registry entry stay: a reader walks them after the teardown.
     async def stop_tracing():
-        for h in _node_hooks:
-            try:
-                node_tracer.hooks.remove(h)
-            except ValueError:
-                pass
-        if _own_tracer:
-            # close() joins the export thread (final POST can take
-            # seconds against a dead collector) — keep the loop free so
-            # later stop hooks' grace timeouts still fire
-            await asyncio.get_running_loop().run_in_executor(
-                None, node_tracer.close
-            )
+        # close() joins the export thread (final POST can take seconds
+        # against a dead collector) — keep the loop free so later stop
+        # hooks' grace timeouts still fire
+        await asyncio.get_running_loop().run_in_executor(
+            None, node_tracer.close
+        )
 
     # TRACKER order (lowest): stop hooks run highest-first, so the
     # exporter flushes AFTER p2p/beacon teardown — spans recorded
@@ -1588,6 +1601,7 @@ async def build_node(config: Config) -> Node:
         profiler=profiler,
         slo=slo,
         pubshares_by_idx=pubshares_by_idx,
+        tracer=node_tracer,
     )
 
 

@@ -259,6 +259,11 @@ class Tracer:
         import threading
 
         self.spans: deque[Span] = deque(maxlen=capacity)
+        # spans the ring has dropped to make room: a reader that needs a
+        # whole window refuses a ring with evicted > 0 rather than read
+        # the part that is left
+        self.evicted = 0
+        self._ring_lock = threading.Lock()
         self.jsonl_path = jsonl_path
         self.exporter = exporter
         self._file = None
@@ -272,7 +277,10 @@ class Tracer:
         self.hooks: list = []
 
     def record(self, span: Span) -> None:
-        self.spans.append(span)
+        with self._ring_lock:
+            if len(self.spans) == self.spans.maxlen:
+                self.evicted += 1
+            self.spans.append(span)
         for hook in self.hooks:
             try:
                 hook(span)
@@ -319,9 +327,18 @@ def global_tracer() -> Tracer:
     return _GLOBAL
 
 
-def set_global_tracer(tracer: Tracer) -> None:
-    global _GLOBAL
-    _GLOBAL = tracer
+# node index -> that node's tracer. Entries outlive the node (never
+# removed, replaced when the index is built again): whoever reads a run's
+# spans does so after the node has been torn down.
+_NODE_TRACERS: dict[int, Tracer] = {}
+
+
+def register_node_tracer(node_index: int, tracer: Tracer) -> None:
+    _NODE_TRACERS[node_index] = tracer
+
+
+def node_tracers() -> dict[int, Tracer]:
+    return dict(_NODE_TRACERS)
 
 
 def duty_trace_id(duty) -> str:
@@ -385,15 +402,6 @@ def span(
         tracer.record(s)
 
 
-def tracing(tracer: Tracer | None = None):
-    """wire() option wrapping every subscription edge in a span.
-    Canonical implementation lives in core/wire.py (sibling of
-    instrument/tracking); kept here as an alias for existing callers."""
-    from charon_tpu.core.wire import tracing as _wire_tracing
-
-    return _wire_tracing(tracer)
-
-
 def record_span(
     name: str,
     trace_id: str,
@@ -422,50 +430,64 @@ def record_span(
     return s
 
 
-def plane_span_bridge(tracer: Tracer | None = None, inner_hook=None):
-    """SlotCoalescer.stats_hook adapter: bridge each flush's pipeline
-    stages (decode, pack, device) into real tracer spans, replacing the
-    old ad-hoc `trace=True` (start, end) tuples.
+def _stretches(windows) -> list[tuple[float, float, int]]:
+    """Union of (start, end) windows as disjoint (start, end, n) stretches,
+    in time order; n = windows merged into the stretch."""
+    out: list[list] = []
+    for start, end in sorted(windows):
+        if out and start <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], end)
+            out[-1][2] += 1
+        else:
+            out.append([start, end, 1])
+    return [tuple(w) for w in out]
 
-    A flush coalesces submissions from several duties; `stats.parents`
-    carries each submission's captured span context, so the stage spans
-    are recorded into EVERY participating duty trace — each duty's
-    timeline shows the shared device window it rode. Submissions with
-    no active trace context get one standalone flush trace. Runs on the
-    device worker thread (Tracer.record is thread-safe); `inner_hook`
-    chains the plain metrics hook."""
+
+def plane_span_bridge(
+    tracer: Tracer | None = None, inner_hook=None, programs=None
+):
+    """SlotCoalescer.stats_hook adapter: bridge each flush's coalescing
+    window and pipeline stages (decode, pack, device) into real tracer
+    spans, replacing the old ad-hoc `trace=True` (start, end) tuples.
+    `programs()` names the compiled programs dispatched inside the
+    flush's device stage ("family@bucket", from the plane profiler's
+    samples); they ride on `cryptoplane.device` as its `programs` attr.
+
+    A flush coalesces submissions from several spans of several duties;
+    `stats.parents` carries each submission's captured span context, and
+    the spans are recorded under EVERY distinct submitting span — each
+    duty's timeline shows the shared device window it rode, and each
+    submitter's self time is its span minus these children (one duty's
+    VC submission and its peers' sets ride one flush in ONE trace: a copy
+    per trace alone would leave all but the first submitter waiting on
+    nothing). Submissions with no active trace context get one
+    standalone flush trace. Runs on the device worker thread
+    (Tracer.record is thread-safe); `inner_hook` chains the plain
+    metrics hook."""
 
     def hook(stats) -> None:
         t = tracer or _GLOBAL
-        parents = []
-        seen: set[str] = set()
-        for trace_id, span_id in getattr(stats, "parents", ()) or ():
-            if trace_id not in seen:
-                seen.add(trace_id)
-                parents.append((trace_id, span_id))
+        parents = list(dict.fromkeys(stats.parents))
         if not parents:
             parents = [(secrets.token_hex(16), "")]
-        stages = []
-        if stats.decode_spans:
-            stages.append(
-                (
-                    "cryptoplane.decode",
-                    min(s for s, _ in stats.decode_spans),
-                    max(e for _, e in stats.decode_spans),
-                    {"chunks": len(stats.decode_spans)},
-                )
-            )
+        # one decode span per stretch in which some chunk was decoding:
+        # the jobs of a flush decode as they arrive, over the whole
+        # window, and one span from the first chunk to the last would
+        # cover the waiting in between
+        stages = [
+            ("cryptoplane.decode", start, end, {"chunks": chunks})
+            for start, end, chunks in _stretches(stats.decode_spans)
+        ]
         if stats.pack_span is not None:
             stages.append(
                 ("cryptoplane.pack", *stats.pack_span, {})
             )
         if stats.device_span is not None:
+            device_attrs = {"fallback": stats.fallback}
+            if programs is not None:
+                device_attrs["programs"] = ",".join(programs())
             stages.append(
-                (
-                    "cryptoplane.device",
-                    *stats.device_span,
-                    {"fallback": stats.fallback},
-                )
+                ("cryptoplane.device", *stats.device_span, device_attrs)
             )
         start = min((s for _, s, _, _ in stages), default=0.0)
         end = max((e for _, _, e, _ in stages), default=0.0)
@@ -485,11 +507,30 @@ def plane_span_bridge(tracer: Tracer | None = None, inner_hook=None):
             # whose lanes rode this flush, so a duty timeline shows WHO
             # shared the device window with it
             flush_attrs["tenants"] = ",".join(t for t, _ in tenant_lanes)
+        # None on remote briefs (core/cryptosvc_client): the window ran on
+        # the server
+        window_span = stats.window_span
         for i, (trace_id, parent_id) in enumerate(parents):
-            # one flush -> one record per participating duty trace: mark
-            # the copies beyond the first so metric hooks (span_metrics)
-            # count each physical flush stage once, not once per duty
+            # one flush -> one record per submitting span: mark the
+            # copies beyond the first so metric hooks (span_metrics)
+            # count each physical flush stage once, not once per copy
             dup = {"shared": True} if i else {}
+            if window_span is not None:
+                # what the first job waited before decode/pack could
+                # start: a sibling of the flush, not one of its stages
+                # (cryptoplane.flush keeps its decode..device extent)
+                record_span(
+                    "cryptoplane.window",
+                    trace_id,
+                    parent_id,
+                    *window_span,
+                    tracer=t,
+                    window=stats.window,
+                    jobs=stats.jobs,
+                    lanes=stats.lanes,
+                    closed_by=stats.window_closed_by,
+                    **dup,
+                )
             flush = record_span(
                 "cryptoplane.flush",
                 trace_id,

@@ -186,6 +186,10 @@ class QBFTConsensus:
         self._instances: dict[Duty, qbft.Transport] = {}
         self._running: dict[Duty, asyncio.Task] = {}
         self._decided: set[Duty] = set()
+        # duty -> [wall clock of the instance's first sign of life (a
+        # delivered message or our own propose), messages delivered]:
+        # where the `qbft.instance` span starts and what it counts
+        self._seen: dict[Duty, list] = {}
         # most recent decide's {duty, round, duration, timer} + optional
         # observer (run.py wires it into the metrics catalogue)
         self.last_decided: dict | None = None
@@ -290,6 +294,7 @@ class QBFTConsensus:
             round=msg.round,
             source=msg.source,
         ):
+            self._note(duty)[1] += 1
             self._sniff("in", duty, msg)
             # Inbox first: if the sender is over its per-source buffer
             # bound, its value payloads are dropped too — otherwise the
@@ -310,6 +315,46 @@ class QBFTConsensus:
                 except Exception:
                     continue
                 cache.setdefault(rh, v)
+
+    def _note(self, duty: Duty) -> list:
+        import time as _time
+
+        # span start: trace attribution on the wall clock, never math
+        return self._seen.setdefault(duty, [_time.time(), 0])  # lint: allow(monotonic-clock)
+
+    def _instance_span(
+        self, duty: Duty, seen: list, stats: dict, error: str = ""
+    ) -> None:
+        """`qbft.instance`: this node's instance from its first sign of
+        life to decided (or to the error that ended it: the Deadliner's
+        trim cancels an instance that ran out of time), under the span
+        that started it — the propose edge, or the delivery that came
+        first."""
+        import time as _time
+
+        from charon_tpu.app.tracer import (
+            current_ctx,
+            duty_trace_id,
+            record_span,
+        )
+
+        started, messages = seen
+        trace_id, parent_id = current_ctx() or (duty_trace_id(duty), "")
+        attrs = {"error": error} if error else {}
+        record_span(
+            "qbft.instance",
+            trace_id,
+            parent_id,
+            started,
+            _time.time(),  # lint: allow(monotonic-clock)
+            tracer=self.tracer,
+            status="error" if error else "ok",
+            duty=str(duty),
+            slot=duty.slot,
+            round=stats.get("round", 0),
+            messages=messages,
+            **attrs,
+        )
 
     def _sniff(self, direction: str, duty: Duty, msg: qbft.Msg) -> None:
         import time as _time
@@ -349,19 +394,30 @@ class QBFTConsensus:
         if task is None:
             tr = self._transport(duty)
             task = asyncio.create_task(
-                self._run_instance(duty, tr, value_hash_or_none)
+                self._run_instance(
+                    duty, tr, value_hash_or_none, self._note(duty)
+                )
             )
             self._running[duty] = task
         return task
 
-    async def _run_instance(self, duty: Duty, tr: qbft.Transport, vhash) -> None:
+    async def _run_instance(
+        self, duty: Duty, tr: qbft.Transport, vhash, seen: list
+    ) -> None:
+        """`seen` is the duty's `_seen` entry, held here because trim()
+        drops it before the cancelled instance records its span."""
         import time as _time
 
         stats: dict = {}
         t0 = _time.monotonic()
-        decided_hash = await qbft.run(
-            self.defn, tr, duty, self.node_idx, vhash, stats=stats
-        )
+        try:
+            decided_hash = await qbft.run(
+                self.defn, tr, duty, self.node_idx, vhash, stats=stats
+            )
+        except BaseException as e:
+            self._instance_span(duty, seen, stats, error=repr(e))
+            raise
+        self._instance_span(duty, seen, stats)
         if duty in self._decided:
             return
         self._decided.add(duty)
@@ -392,6 +448,7 @@ class QBFTConsensus:
         if task is not None and not task.done():
             task.cancel()
         self._decided.discard(duty)
+        self._seen.pop(duty, None)
 
     # -- workflow API ------------------------------------------------------
 

@@ -68,7 +68,7 @@ from __future__ import annotations
 import asyncio
 import time
 from dataclasses import dataclass
-from typing import Sequence
+from typing import NamedTuple, Sequence
 
 from charon_tpu.crypto import g1g2
 from charon_tpu.tbls import TblsError
@@ -151,6 +151,13 @@ class FlushStats:
     decode_spans: tuple[tuple[float, float], ...] = ()  # per decode chunk
     pack_span: tuple[float, float] | None = None
     device_span: tuple[float, float] | None = None
+    # the coalescing window itself: first job into the idle coalescer ->
+    # the window closed (what that job WAITED, where `window` above is
+    # what was configured), and what closed it: "timer" (ran its
+    # length), "deadline" (armed already capped by a duty deadline),
+    # "pulled_earlier" (a later submission's deadline pulled it in)
+    window_span: tuple[float, float] | None = None
+    window_closed_by: str = ""
     # (trace_id, span_id) captured from each submission's active span
     parents: tuple[tuple[str, str], ...] = ()
     # live lanes per submitting tenant (ISSUE 8): (tenant_id, lanes)
@@ -158,6 +165,15 @@ class FlushStats:
     # attribution the tenant-labeled metric families and the span
     # bridge's tenant attrs are built from
     tenant_lanes: tuple[tuple[str, int], ...] = ()
+
+
+class _Window(NamedTuple):
+    """The window a flush closed, as it travels with the flush from the
+    event loop to the device lane (and through the retry rungs)."""
+
+    seconds: float = 0.0  # adaptive window in force when the flush armed
+    span: tuple[float, float] | None = None  # wall clock: opened, closed
+    closed_by: str = ""
 
 
 class PlaneConfigError(ValueError):
@@ -291,7 +307,6 @@ class SlotCoalescer:
         self,
         plane,
         window: float = 0.02,
-        metrics_hook=None,
         plane_factory=None,
         window_min: float = 0.002,
         window_max: float = 0.08,
@@ -333,6 +348,8 @@ class SlotCoalescer:
         self._flush_at: float = 0.0  # monotonic flush target of armed task
         self._flush_wake = asyncio.Event()
         self._queue_deadline: float | None = None  # monotonic, min over jobs
+        self._window_opened = 0.0  # wall clock: first job into this window
+        self._window_closed_by = "timer"
         self._wall_offset = 0.0  # wall->monotonic, snapshotted per window
         # submissions mid-decode (closing windows wait for these)
         self._decode_tickets: set[asyncio.Future] = set()
@@ -364,12 +381,9 @@ class SlotCoalescer:
         self.overlapped_flushes = 0  # submitted while the device was busy
         self._inflight = 0  # flushes inside the device lane (incl. queued)
         self.max_inflight = 0
-        # called after each flush with (jobs, lanes) — thread-safe
-        # counters only (runs on the device worker thread)
-        self.metrics_hook = metrics_hook
-        # richer per-flush pipeline stats (FlushStats) — same threading
-        # contract as metrics_hook. Stage timing travels IN the stats
-        # (decode_spans/pack_span/device_span wall-clock windows), so
+        # per-flush pipeline stats (FlushStats), delivered on the device
+        # worker thread: thread-safe sinks only. Stage timing travels IN
+        # the stats (window/decode/pack/device wall-clock windows), so
         # the tracer bridge and bench_hostplane.py both read per-flush
         # spans from here instead of a coalescer-global trace list.
         self.stats_hook = stats_hook
@@ -664,6 +678,12 @@ class SlotCoalescer:
             target = min(target, now + cap)
         if new_window:
             self._flush_at = target
+            # the window's own span (FlushStats.window_span): trace
+            # attribution on the wall clock, never math
+            self._window_opened = time.time()  # lint: allow(monotonic-clock)
+            self._window_closed_by = (
+                "deadline" if target < now + self._window_current else "timer"
+            )
             # fresh Event per armed task: asyncio primitives bind to the
             # running loop on first use, and one coalescer may serve
             # several asyncio.run() lifetimes (tests, CLI tools)
@@ -673,6 +693,7 @@ class SlotCoalescer:
             # a tighter deadline arrived while the window timer sleeps:
             # pull the armed flush earlier (never later)
             self._flush_at = target
+            self._window_closed_by = "pulled_earlier"
             self._flush_wake.set()
 
     async def _flush_after_window(self) -> None:
@@ -687,6 +708,10 @@ class SlotCoalescer:
                 )
             except asyncio.TimeoutError:
                 pass
+        # read before the first await below: a submission arriving from
+        # here on may still pull `_flush_at`, but the window has closed
+        window_span = (self._window_opened, time.time())  # lint: allow(monotonic-clock)
+        closed_by = self._window_closed_by
         gate = self.dispatch_gate
         if gate is not None and not gate.is_set():
             # startup tuner still settling the kernel dispatch flags:
@@ -717,7 +742,7 @@ class SlotCoalescer:
                 if not job.fut.done():
                     job.fut.set_exception(TblsError("crypto plane closed"))
             return
-        window_used = self._window_current
+        window_used = _Window(self._window_current, window_span, closed_by)
         self._adapt_window(vq, rq)
         loop = asyncio.get_running_loop()
         # host stage 2: pack the batch on the decode pool so the device
@@ -944,7 +969,7 @@ class SlotCoalescer:
         vq: list[_VerifyJob],
         rq: list[_RecombineJob],
         packed=None,
-        window_used: float = 0.0,
+        window_used: _Window = _Window(),
         inflight: int = 1,
     ):
         # counters update only AFTER both stages succeed: a failed flush
@@ -1054,7 +1079,7 @@ class SlotCoalescer:
                 jobs=len(vq) + len(rq),
                 lanes=lanes,
                 flush_seconds=time.monotonic() - t0,
-                window=window_used,
+                window=window_used.seconds,
                 inflight=inflight,
                 pad_lanes=pad_lanes,
                 padded_lanes=padded_lanes,
@@ -1066,6 +1091,8 @@ class SlotCoalescer:
                 decode_spans=self._job_decode_spans(vq, rq),
                 pack_span=pack_span,
                 device_span=(w0, time.time()),  # lint: allow(monotonic-clock)
+                window_span=window_used.span,
+                window_closed_by=window_used.closed_by,
                 parents=self._job_parents(vq, rq),
                 tenant_lanes=self._job_tenant_lanes(vq, rq),
             ),
@@ -1163,13 +1190,11 @@ class SlotCoalescer:
             self.pad_lanes_flushed += stats.pad_lanes
         if len(vq) + len(rq) >= 2:
             self.coalesced_flushes += 1
-        if self.metrics_hook is not None:
-            self.metrics_hook(len(vq) + len(rq), lanes)
         if self.stats_hook is not None:
             self.stats_hook(stats)
 
     async def _decode_stepdown_and_retry(
-        self, vq, rq, err, window_used: float = 0.0, inflight: int = 1
+        self, vq, rq, err, window_used: _Window = _Window(), inflight: int = 1
     ):
         """Decode-ladder rung (ISSUE 5): a failed flush that shipped
         PARSED lanes steps this coalescer's decode rung down to python
@@ -1225,7 +1250,7 @@ class SlotCoalescer:
             return None
 
     async def _degrade_and_retry(
-        self, vq, rq, err, window_used: float = 0.0, inflight: int = 1
+        self, vq, rq, err, window_used: _Window = _Window(), inflight: int = 1
     ):
         """One-shot msm-off rung: flip the MSM family off, rebuild the
         plane so its programs re-trace, and retry the SAME batch on the

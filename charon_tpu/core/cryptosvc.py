@@ -45,7 +45,9 @@ per-flush tenant attribution rides `FlushStats.tenant_lanes`.
 from __future__ import annotations
 
 import asyncio
+import contextvars
 import heapq
+import secrets
 import time
 from dataclasses import dataclass, field
 
@@ -173,6 +175,12 @@ class _Entry:
     deadline: float | None  # wall clock (time.time), as submitted
     fut: asyncio.Future
     seq: int
+    # the submitter's context: the entry runs in it once popped, so the
+    # coalescer captures THIS submission's span (not the span of
+    # whichever submission happened to start the dispatcher task)
+    ctx: contextvars.Context
+    parent: tuple[str, str] | None  # submitter's (trace_id, span_id)
+    admitted: float  # wall clock: into the tenant's queue
 
 
 class _Tenant:
@@ -240,8 +248,10 @@ class CryptoPlaneService:
         observer=None,
         quarantine_window: float = 0.005,
         quarantine_factory=None,  # callable(tenant_id) -> coalescer
+        tracer=None,  # app/tracer.Tracer; None = process-global
     ):
         self._coal = coalescer
+        self.tracer = tracer
         self.round_lanes = round_lanes
         self._round = (
             round_interval
@@ -290,6 +300,30 @@ class CryptoPlaneService:
         tests; the scheduling state inside is service-private."""
         return self._tenants[tenant_id]
 
+    def _queue_span(
+        self, tenant: str, kind: str, lanes: int, parent, start: float,
+        shed: bool,
+    ) -> None:
+        """`cryptosvc.queue`: admitted to the tenant's queue -> popped for
+        dispatch to the coalescer, under the submission's own span. A
+        shed submission never queues: it leaves a zero-length span so the
+        duty's timeline shows where its lanes went."""
+        from charon_tpu.app.tracer import record_span  # lazy: core !-> app
+
+        trace_id, parent_id = parent or (secrets.token_hex(16), "")
+        record_span(
+            "cryptosvc.queue",
+            trace_id,
+            parent_id,
+            start,
+            start if shed else time.time(),  # lint: allow(monotonic-clock) — trace attribution
+            tracer=self.tracer,
+            tenant=tenant,
+            kind=kind,
+            lanes=lanes,
+            shed=shed,
+        )
+
     def _observe(self, kind: str, tenant: str, **fields) -> None:
         if self.observer is not None:
             try:
@@ -313,6 +347,10 @@ class CryptoPlaneService:
         if lanes == 0:
             # empty submissions short-circuit like the coalescer's own
             return [] if kind == "verify" else ([], [])
+        from charon_tpu.app.tracer import current_ctx  # lazy: core !-> app
+
+        parent = current_ctx()
+        admitted = time.time()  # lint: allow(monotonic-clock) — span start
         q = ten.quota
         reason = None
         if ten.pending_jobs + 1 > q.max_queue_jobs:
@@ -325,6 +363,7 @@ class CryptoPlaneService:
             ten.shed[reason] = ten.shed.get(reason, 0) + 1
             ten.shed_lanes += lanes
             self._observe("shed", tenant_id, reason=reason, lanes=lanes)
+            self._queue_span(tenant_id, kind, lanes, parent, admitted, True)
             raise PlaneOverloadError(
                 tenant_id,
                 reason,
@@ -340,6 +379,9 @@ class CryptoPlaneService:
             deadline=deadline,
             fut=loop.create_future(),
             seq=self._seq,
+            ctx=contextvars.copy_context(),
+            parent=parent,
+            admitted=admitted,
         )
         key = deadline if deadline is not None else float("inf")
         heapq.heappush(ten.queue, (key, entry.seq, entry))
@@ -411,8 +453,12 @@ class CryptoPlaneService:
                 "dispatch", ten.id,
                 lanes=entry.lanes, quarantined=quarantined,
             )
+            self._queue_span(
+                ten.id, entry.kind, entry.lanes, entry.parent,
+                entry.admitted, False,
+            )
             task = asyncio.create_task(
-                self._run_entry(ten, entry, quarantined)
+                self._run_entry(ten, entry, quarantined), context=entry.ctx
             )
             self._entry_tasks.add(task)
             task.add_done_callback(self._entry_tasks.discard)

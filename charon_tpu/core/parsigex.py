@@ -345,14 +345,22 @@ class ParSigEx:
             pubkeys=len(signed_set),
         ):
             if self.verifier is not None:
-                check = getattr(self.verifier, "verify_async", None)
-                if check is not None:
-                    ok = await check(duty, signed_set)
-                else:
-                    # duck-typed sync verifier (test fakes): inline on
-                    # purpose, same rationale as verify_async's plane-
-                    # less rung above
-                    ok = self.verifier.verify(duty, signed_set)  # lint: allow(event-loop-blocking)
+                # verifier called -> verdict: its self time is the signing
+                # roots; the plane's queue, window and flush nest under it
+                with span(
+                    "parsigex.verify",
+                    tracer=self.tracer,
+                    pubkeys=len(signed_set),
+                ) as vspan:
+                    check = getattr(self.verifier, "verify_async", None)
+                    if check is not None:
+                        ok = await check(duty, signed_set)
+                    else:
+                        # duck-typed sync verifier (test fakes): inline
+                        # on purpose, same rationale as verify_async's
+                        # plane-less rung above
+                        ok = self.verifier.verify(duty, signed_set)  # lint: allow(event-loop-blocking)
+                    vspan.attrs["ok"] = bool(ok)
                 if not ok:
                     # drop invalid sets; billed to the channel peer when
                     # known, else to the claimed share indices (the best

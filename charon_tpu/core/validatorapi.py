@@ -45,6 +45,7 @@ class ValidatorAPI:
     # optional core.cryptoplane.SlotCoalescer: partial-sig pubshare checks
     # from concurrent VC submissions merge into one sharded device program
     plane: object | None = None
+    tracer: object | None = None  # app/tracer.Tracer; None = process-global
 
     def __post_init__(self) -> None:
         self._subs: list = []
@@ -110,52 +111,42 @@ class ValidatorAPI:
     async def submit_attestations(self, atts: Sequence[Attestation]) -> None:
         """POST /eth/v1/beacon/pool/attestations analogue
         (ref: validatorapi.go:274 SubmitAttestations)."""
-        by_duty: dict[Duty, dict[PubKey, ParSignedData]] = {}
-        items = []
-        metas = []
-        for att in atts:
-            slot = att.data.slot
-            root = att.data.hash_tree_root()
-            pubkey = self._pubkey_by_att(slot, root)
-            if pubkey is None:
-                raise VapiError("unknown attestation (no DutyDB entry)")
-            signed = SignedData("attestation", att, att.signature)
-            items.append(self._verify_item(pubkey, signed, slot))
-            metas.append((Duty(slot, DutyType.ATTESTER), pubkey, signed))
-        await self._check_batch(items)
-        for duty, pubkey, signed in metas:
-            by_duty.setdefault(duty, {})[pubkey] = ParSignedData(
-                data=signed, share_idx=self.share_idx
-            )
-        for duty, signed_set in by_duty.items():
-            for sub in self._subs:
-                await sub(duty, signed_set)
+        if not atts:
+            return
+
+        def entries():
+            for att in atts:
+                slot = att.data.slot
+                pubkey = self._pubkey_by_att(slot, att.data.hash_tree_root())
+                if pubkey is None:
+                    raise VapiError("unknown attestation (no DutyDB entry)")
+                yield (
+                    Duty(slot, DutyType.ATTESTER),
+                    pubkey,
+                    SignedData("attestation", att, att.signature),
+                )
+
+        await self._submit(Duty(atts[0].data.slot, DutyType.ATTESTER), entries())
 
     async def submit_proposal(self, pubkey: PubKey, proposal: Proposal, signature: bytes) -> None:
         signed = SignedData("block", proposal, signature)
-        await self._check_batch([self._verify_item(pubkey, signed, proposal.slot)])
         duty = Duty(proposal.slot, DutyType.PROPOSER)
-        for sub in self._subs:
-            await sub(duty, {pubkey: ParSignedData(signed, self.share_idx)})
+        await self._submit(duty, [(duty, pubkey, signed)])
 
     async def submit_randao(self, slot: int, pubkey: PubKey, signature: bytes) -> None:
         """Randao reveals arrive with proposal requests
         (ref: validatorapi.go:335 Proposal flow)."""
         epoch = slot // self.slots_per_epoch
         signed = SignedData("randao", epoch, signature)
-        await self._check_batch([self._verify_item(pubkey, signed, slot)])
         duty = Duty(slot, DutyType.RANDAO)
-        for sub in self._subs:
-            await sub(duty, {pubkey: ParSignedData(signed, self.share_idx)})
+        await self._submit(duty, [(duty, pubkey, signed)])
 
     async def submit_selection_proof(self, slot: int, pubkey: PubKey, signature: bytes) -> None:
         """Beacon-committee selection partials
         (ref: validatorapi.go:724 AggregateBeaconCommitteeSelections)."""
         signed = SignedData("selection_proof", slot, signature)
-        await self._check_batch([self._verify_item(pubkey, signed, slot)])
         duty = Duty(slot, DutyType.PREPARE_AGGREGATOR)
-        for sub in self._subs:
-            await sub(duty, {pubkey: ParSignedData(signed, self.share_idx)})
+        await self._submit(duty, [(duty, pubkey, signed)])
 
     async def aggregate_attestation(self, slot: int, att_data_root: bytes):
         """Blocking fetch of the cluster-agreed aggregate."""
@@ -163,12 +154,8 @@ class ValidatorAPI:
 
     async def submit_aggregate_and_proof(self, pubkey: PubKey, agg, signature: bytes) -> None:
         signed = SignedData("aggregate_and_proof", agg, signature)
-        await self._check_batch(
-            [self._verify_item(pubkey, signed, agg.aggregate.data.slot)]
-        )
         duty = Duty(agg.aggregate.data.slot, DutyType.AGGREGATOR)
-        for sub in self._subs:
-            await sub(duty, {pubkey: ParSignedData(signed, self.share_idx)})
+        await self._submit(duty, [(duty, pubkey, signed)])
 
     async def aggregate_selection(self, slot: int, pubkey: PubKey):
         """Blocking fetch of the threshold-aggregated beacon-committee
@@ -186,10 +173,8 @@ class ValidatorAPI:
 
         payload = SyncSelectionData(slot, subcommittee_index)
         signed = SignedData("sync_selection", payload, signature)
-        await self._check_batch([self._verify_item(pubkey, signed, slot)])
         duty = Duty(slot, DutyType.PREPARE_SYNC_CONTRIBUTION)
-        for sub in self._subs:
-            await sub(duty, {pubkey: ParSignedData(signed, self.share_idx)})
+        await self._submit(duty, [(duty, pubkey, signed)])
 
     async def sync_selection_aggregate(self, slot: int, pubkey: PubKey):
         duty = Duty(slot, DutyType.PREPARE_SYNC_CONTRIBUTION)
@@ -208,39 +193,67 @@ class ValidatorAPI:
     ) -> None:
         signed = SignedData("contribution_and_proof", cap, signature)
         slot = cap.contribution.slot
-        await self._check_batch([self._verify_item(pubkey, signed, slot)])
         duty = Duty(slot, DutyType.SYNC_CONTRIBUTION)
-        for sub in self._subs:
-            await sub(duty, {pubkey: ParSignedData(signed, self.share_idx)})
+        await self._submit(duty, [(duty, pubkey, signed)])
 
     async def sync_message_duty(self, slot: int, pubkey: PubKey):
         return await self._await_sync_msg(slot, pubkey)
 
     async def submit_sync_message(self, slot: int, pubkey: PubKey, msg, signature: bytes) -> None:
         signed = SignedData("sync_message", msg, signature)
-        await self._check_batch([self._verify_item(pubkey, signed, slot)])
         duty = Duty(slot, DutyType.SYNC_MESSAGE)
-        for sub in self._subs:
-            await sub(duty, {pubkey: ParSignedData(signed, self.share_idx)})
+        await self._submit(duty, [(duty, pubkey, signed)])
 
     async def submit_exit(self, pubkey: PubKey, exit_msg, signature: bytes) -> None:
         """Voluntary exit partial (ref: exit flow, validatorapi exit
         endpoints + cmd/exit_sign.go)."""
         signed = SignedData("exit", exit_msg, signature)
         slot = exit_msg.epoch * self.slots_per_epoch
-        await self._check_batch([self._verify_item(pubkey, signed, slot)])
         duty = Duty(slot, DutyType.EXIT)
-        for sub in self._subs:
-            await sub(duty, {pubkey: ParSignedData(signed, self.share_idx)})
+        await self._submit(duty, [(duty, pubkey, signed)])
 
     async def submit_registration(self, pubkey: PubKey, reg, signature: bytes, slot: int = 0) -> None:
         signed = SignedData("registration", reg, signature)
-        await self._check_batch([self._verify_item(pubkey, signed, slot)])
         duty = Duty(slot, DutyType.BUILDER_REGISTRATION)
-        for sub in self._subs:
-            await sub(duty, {pubkey: ParSignedData(signed, self.share_idx)})
+        await self._submit(duty, [(duty, pubkey, signed)])
 
     # -- helpers -----------------------------------------------------------
+
+    async def _submit(self, duty: Duty, entries) -> None:
+        """Where every submit_* ends: `entries` yields (duty, pubkey,
+        signed) — lazily, so a submitter's own look-ups are inside the
+        span. All signatures of the request are checked against this
+        node's pubshares in ONE batch; then each duty's set goes to the
+        subscribers (ParSigDB). Span `vapi.submit`: request parsed ->
+        the last `parsigdb.store_internal` returned, in `duty`'s trace."""
+        from charon_tpu.app.tracer import span  # lazy: core !-> app
+
+        with span(
+            "vapi.submit",
+            duty=duty,
+            tracer=self.tracer,
+            duty_type=str(duty.type),
+            count=0,
+            rejected=0,
+        ) as s:
+            metas = list(entries)
+            s.attrs["count"] = len(metas)
+            ok = await self._check_batch(
+                [self._verify_item(pk, signed, d.slot) for d, pk, signed in metas]
+            )
+            if not all(ok):
+                s.attrs["rejected"] = sum(1 for lane in ok if not lane)
+                raise VapiError(
+                    "partial signature failed pubshare verification"
+                )
+            by_duty: dict[Duty, dict[PubKey, ParSignedData]] = {}
+            for d, pk, signed in metas:
+                by_duty.setdefault(d, {})[pk] = ParSignedData(
+                    data=signed, share_idx=self.share_idx
+                )
+            for d, signed_set in by_duty.items():
+                for sub in self._subs:
+                    await sub(d, signed_set)
 
     def _verify_item(self, pubkey: PubKey, signed: SignedData, slot: int):
         pubshare = self.pubshares.get(pubkey)
@@ -249,8 +262,8 @@ class ValidatorAPI:
         root = signed.signing_root(self.fork, slot // self.slots_per_epoch)
         return (pubshare, root, signed.signature)
 
-    async def _check_batch(self, items) -> None:
-        """Verify partial signatures against pubshares — batched
+    async def _check_batch(self, items) -> list[bool]:
+        """Per-lane verdicts of partial signatures against pubshares — batched
         (ref: validatorapi.go:1213 one herumi call per signature). With a
         crypto plane installed, concurrent submissions coalesce into one
         sharded device program."""
@@ -275,5 +288,4 @@ class ValidatorAPI:
             # 7-17x e2e slowdown); production wires the plane, whose
             # path above is truly async
             ok = tbls.verify_batch(items)  # lint: allow(event-loop-blocking)
-        if not all(ok):
-            raise VapiError("partial signature failed pubshare verification")
+        return ok

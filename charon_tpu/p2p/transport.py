@@ -32,6 +32,7 @@ follow the reference's envelope (p2p/sender.go:23-29).
 from __future__ import annotations
 
 import asyncio
+import contextvars
 import hashlib
 import json
 import os
@@ -497,7 +498,16 @@ class P2PNode:
     # -- receive ----------------------------------------------------------
 
     def _spawn_recv(self, conn: _Conn) -> None:
-        task = asyncio.create_task(self._recv_loop(conn))
+        # A connection outlives the call that dialed it, so its reader
+        # starts from an EMPTY context, not the dialer's: otherwise the
+        # span active at the first broadcast (one duty's propose edge)
+        # stays the ambient parent of every span an inbound frame ever
+        # opens, and all later duties' receive paths join that duty's
+        # trace. Trace context crosses the wire in the frame's `tctx`
+        # only — the socket twin of MemTransport's `detached()`.
+        task = asyncio.create_task(
+            self._recv_loop(conn), context=contextvars.Context()
+        )
         self._recv_tasks.add(task)
         task.add_done_callback(self._recv_tasks.discard)
 

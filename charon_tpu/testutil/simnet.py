@@ -435,6 +435,7 @@ def _build_node(
         pubshares=cluster.pubshares_by_idx[share_idx],
         fork=fork,
         slots_per_epoch=spe,
+        tracer=node_tracer,
     )
     verifier = Eth2Verifier(
         fork, cluster.pubshares_by_idx, spe, plane=plane

@@ -185,6 +185,13 @@ async def main(args) -> int:
             status == 200 and b"fetcher.fetch" in body,
             f"/debug/duty/{slot}?format=text renders the waterfall",
         )
+        # the served path's own spans (simnet: echo consensus and a bare
+        # coalescer, so no qbft.instance and no tenant queue)
+        own = (b"vapi.submit", b"parsigex.verify", b"cryptoplane.window")
+        gate(
+            all(name in body for name in own),
+            f"/debug/duty/{slot} shows the entry and window spans",
+        )
         try:
             await asyncio.to_thread(_get, f"{base}/debug/duty/999999")
             gate(False, "/debug/duty/<unknown> 404s")

@@ -296,16 +296,6 @@ def test_close_racing_flush_fails_waiters_without_degrading():
         MSM.set_msm(None)
 
 
-def test_legacy_metrics_hook_still_fires():
-    seen = []
-    plane = SlotCoalescer(
-        FakePlane(T), window=0.01, metrics_hook=lambda j, l: seen.append((j, l))
-    )
-    asyncio.run(plane.verify(_sig_items(2)))
-    plane.close()
-    assert seen == [(1, 2)]
-
-
 # ---------------------------------------------------------------------------
 # shape buckets: flushes land on the declared ladder, jit cache bounded
 # ---------------------------------------------------------------------------

@@ -1,0 +1,120 @@
+"""The node's own duty timeline, end to end (ISSUE 26): one rehearsal of the
+benchmark's served path on the CPU — the real `build_node` node beside its
+in-process peers (bare QBFTConsensus + ParSigEx objects), the crypto-plane
+service path patched in over a plane that runs no program
+(benchmark/tests/planepatch.py), the recorded device trace standing in for
+the profiler — and what the node's tracer, its hooks, /debug/duty's
+timeline and the ten per-layer metrics say afterwards. The readers' own
+tests (synthetic span forest) live in benchmark/tests/test_nodespans.py and
+run here too."""
+
+from __future__ import annotations
+
+import json
+import subprocess
+import sys
+from collections import Counter
+from pathlib import Path
+
+import pytest
+
+REPO = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(REPO))
+
+from benchmark.tests.test_nodespans import *  # noqa: E402,F401,F403 — the readers' tests
+from charon_tpu.app import tracer  # noqa: E402
+
+NEW = ("entry_self_s", "qbft_decide_s", "agg_bcast_self_s", "svc_queue_s", "window_wait_s",
+       "idle_s.consensus", "idle_s.awaiting_input", "idle_s.entry", "idle_s.window",
+       "idle_s.pack")
+
+
+@pytest.fixture(scope="module")
+def rehearsal():
+    proc = subprocess.run(
+        [sys.executable, str(REPO / "benchmark/tests/rehearse_spans.py")],
+        capture_output=True, text=True, timeout=240, cwd=str(REPO))
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    lines = proc.stdout.strip().splitlines()
+    return json.loads(lines[-2]), json.loads(lines[-1]), proc.stderr
+
+
+def test_the_node_records_only_its_own_spans_and_its_hooks_see_only_those(rehearsal):
+    line, rings, _err = rehearsal
+    assert line["correct"] is True and line["failed"] == 0
+    assert list(rings["nodes"]) == ["0"]  # the registry outlived the teardown
+    ring = rings["nodes"]["0"]
+    assert ring["evicted"] == 0 and len(ring["spans"]) < ring["capacity"] // 8
+    names = Counter(s["name"] for s in ring["spans"])
+    slots = sorted({s["attrs"]["slot"] for s in ring["spans"] if "slot" in s["attrs"]})
+    # ONE instance a duty: the three peers' instances are in the global ring
+    assert names["qbft.instance"] == len(slots)
+    assert rings["global"]["qbft.instance"] == 3 * names["qbft.instance"]
+    assert rings["global"]["qbft.deliver"] > names["qbft.deliver"] > 0
+    # the peers are bare: no workflow edge, no plane
+    assert set(rings["global"]) == {"qbft.deliver", "qbft.instance", "parsigex.receive"}
+    # the node's span hook (core_step_latency_seconds) counted each of the
+    # node's physical spans once, and nothing a peer recorded
+    own = Counter(s["name"] for s in ring["spans"] if not s["attrs"].get("shared"))
+    assert rings["hooked"] == dict(own)
+
+
+def test_one_duty_reads_from_consensus_to_broadcast_nested_as_caused(rehearsal):
+    _line, rings, _err = rehearsal
+    spans = rings["nodes"]["0"]["spans"]
+    slot = max(s["attrs"]["slot"] for s in spans if s["name"] == "broadcaster.broadcast")
+    (timeline,) = [tl for tl in tracer.duty_timeline(slot, spans=spans)
+                   if tl["duty"].endswith("/attester")]
+    by_id = {s["span_id"]: s for s in timeline["spans"]}
+
+    def parents(name):
+        return {by_id[s["parent_id"]]["name"] if s["parent_id"] in by_id else ""
+                for s in timeline["spans"] if s["name"] == name}
+
+    assert parents("qbft.instance") <= {"consensus.propose", "qbft.deliver"}
+    assert parents("vapi.submit") == {""}  # the VC's request roots its own branch
+    assert parents("parsigex.verify") == {"parsigex.receive"}
+    submitters = {"vapi.submit", "parsigex.verify", "sigagg.aggregate"}
+    assert parents("cryptosvc.queue") == submitters
+    assert parents("cryptoplane.window") == parents("cryptoplane.flush") == submitters
+    for stage in ("cryptoplane.decode", "cryptoplane.pack", "cryptoplane.device"):
+        assert parents(stage) == {"cryptoplane.flush"}
+    assert parents("sigagg.aggregate") <= {"parsigdb.store_external", "parsigdb.store_internal"}
+    assert parents("broadcaster.broadcast") == {"sigagg.aggregate"}
+    # in time: decided, then the submissions, their queue, the window, the
+    # stages, and the broadcast last
+    first = {}
+    for s in timeline["spans"]:
+        first.setdefault(s["name"], s["offset_us"])
+    order = ["qbft.instance", "vapi.submit", "cryptosvc.queue", "cryptoplane.window",
+             "cryptoplane.pack", "cryptoplane.device", "sigagg.aggregate",
+             "broadcaster.broadcast"]
+    assert [first[n] for n in order] == sorted(first[n] for n in order)
+    text = tracer.render_waterfall([timeline])
+    assert all(name in text for name in order)
+
+
+def test_a_traced_run_prints_the_ten_new_metrics_beside_the_old(rehearsal):
+    line, _rings, err = rehearsal
+    assert set(NEW) <= set(line["metrics"])
+    # the old ones that a host-only node with the service path can report
+    assert {"wave_host_s", "flush_window_s", "flush_pack_s", "flushes_per_wave"} <= set(
+        line["metrics"])
+    m = {k: v["value"] for k, v in line["metrics"].items()}
+    assert all(v["unit"] == "s" for k, v in line["metrics"].items() if k in NEW)
+    assert 0 < m["qbft_decide_s"] < 3 and 0 < m["entry_self_s"] < m["wave_host_s"]
+    assert 0 <= m["svc_queue_s"] < 2 and 0 < m["agg_bcast_self_s"] < m["wave_host_s"]
+    # what the first job waited, beside the window configured
+    assert 0.04 < m["window_wait_s"] < 2 and 0.04 < m["flush_window_s"] < 0.3
+    # every idle instant of the traced window has one cause
+    note = next(ln for ln in err.splitlines() if ln.startswith("node spans:"))
+    causes = dict(p.strip().rsplit(" ", 1) for p in
+                  note.split("by cause:")[1].split(";")[0].split(","))
+    idle = line["device"]["window_s"] - line["device"]["busy_s"]
+    assert sum(float(v) for v in causes.values()) == pytest.approx(idle, abs=1e-3)
+    assert set(causes) == {"pack", "window", "entry", "consensus", "other", "pre_trigger",
+                           "awaiting_input"}
+    for name in NEW[5:]:
+        assert m[name] == pytest.approx(float(causes[name.split(".", 1)[1]]), abs=1e-6)
+    # the recorded trace is 0.786 s from the slot's start: before the trigger
+    assert float(causes["pre_trigger"]) == pytest.approx(idle, abs=1e-3)

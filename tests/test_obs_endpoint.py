@@ -221,3 +221,69 @@ def test_slow_duty_detector():
     # duties with no spans at all never flag
     assert det.finalize(Duty(slot=31, type=DutyType.ATTESTER), 1e-9) is None
     assert det.slow_total == 1
+
+
+def test_a_built_node_serves_and_keeps_its_own_tracer(tmp_path, monkeypatch):
+    """build_node gives the node a tracer of its own: /debug/traces and
+    /debug/duty/<slot> serve that ring, a bare component beside it in the
+    process records into the process-global one (so neither the node's
+    ring nor the hooks on it see its spans), and the registry still finds
+    the node's tracer after its lifecycle has stopped."""
+    import socket
+
+    from charon_tpu.app.run import TRACE_RING_SPANS, Config, build_node
+    from charon_tpu.cmd.cli import main as cli
+    from charon_tpu.core.parsigex import MemTransport, ParSigEx
+
+    monkeypatch.setattr(tracer, "_NODE_TRACERS", {})
+    out = tmp_path / "c"
+    cli(["create-cluster", "--nodes", "2", "--threshold", "2", "--validators", "1",
+         "--output-dir", str(out)])
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+
+    async def run():
+        node = await build_node(Config(
+            data_dir=str(out / "node0"), node_index=0, simnet=True, slot_duration=0.5,
+            slots_per_epoch=8, use_tpu_tbls=False, monitoring_port=port))
+        assert node.tracer is not tracer.global_tracer()
+        assert tracer.node_tracers() == {0: node.tracer}
+        assert node.tracer.spans.maxlen == TRACE_RING_SPANS
+        hooked = []
+        node.tracer.hooks.append(lambda s: hooked.append(s.name))
+        stop = asyncio.Event()
+        life = asyncio.create_task(node.life.run(stop))
+        duty = Duty(slot=4, type=DutyType.ATTESTER)
+        # a peer's component, built bare in the node's process
+        before = len(tracer.global_tracer().spans)
+        await ParSigEx(2, MemTransport()).receive(duty, {})
+        assert len(tracer.global_tracer().spans) == before + 1
+        with tracer.span("vapi.submit", duty=duty, tracer=node.tracer):
+            pass
+
+        def get(path):
+            with urllib.request.urlopen(f"http://127.0.0.1:{port}{path}") as resp:
+                return json.loads(resp.read())
+
+        for _ in range(100):
+            try:
+                traces = await asyncio.to_thread(get, "/debug/traces")
+                break
+            except OSError:
+                await asyncio.sleep(0.05)
+        names = {s["name"] for s in traces}
+        assert "vapi.submit" in names and "parsigex.receive" not in names
+        assert "parsigex.receive" not in hooked and "vapi.submit" in hooked
+        (timeline,) = [tl for tl in await asyncio.to_thread(get, "/debug/duty/4")
+                       if tl["duty"] == str(duty)]
+        assert [s["name"] for s in timeline["spans"]] == ["vapi.submit"]
+        stop.set()
+        await asyncio.wait_for(life, 30)
+        return node
+
+    node = asyncio.run(run())
+    # torn down; the ring and its registry entry stay for a reader
+    assert tracer.node_tracers()[0] is node.tracer
+    assert any(s.name == "vapi.submit" for s in node.tracer.spans)
+    assert node.tracer.evicted == 0

@@ -174,3 +174,45 @@ def test_parsigex_over_tcp():
                 await node.stop()
 
     asyncio.run(run())
+
+
+def test_inbound_spans_do_not_inherit_the_span_that_dialed_the_connection():
+    """A connection outlives the call that opened it. The span active at
+    a node's FIRST broadcast (one duty's propose edge) must not become the
+    ambient parent of what later arrives on that connection: a later
+    duty's receive span belongs to its own duty's trace (trace context
+    crosses the wire in the frame's tctx only)."""
+    from charon_tpu.app import tracer
+
+    async def run():
+        nodes = await make_mesh(2)
+        try:
+            t0 = tracer.Tracer()
+            transports = [TcpParSigTransport(node) for node in nodes]
+            exes = [
+                ParSigEx(1, transports[0], verifier=None, tracer=t0),
+                ParSigEx(2, transports[1], verifier=None),
+            ]
+            first, later = Duty(5, DutyType.ATTESTER), Duty(6, DutyType.ATTESTER)
+
+            def psig(share):
+                return ParSignedData(
+                    data=SignedData("randao", 0, b"\x07" * 96), share_idx=share
+                )
+
+            with tracer.span("consensus.propose", duty=first, tracer=t0):
+                # dials node 1 inside duty 5's span
+                await exes[0].broadcast(first, {PubKey("0xbb"): psig(1)})
+            await asyncio.sleep(0.2)
+            # node 1 answers on whatever connection the pair has, from no span
+            await exes[1].broadcast(later, {PubKey("0xbb"): psig(2)})
+            await asyncio.sleep(0.3)
+            (recv,) = [s for s in t0.spans if s.name == "parsigex.receive"]
+            assert recv.attrs["duty"] == str(later)
+            assert recv.trace_id == tracer.duty_trace_id(later)
+            assert recv.parent_id == ""
+        finally:
+            for node in nodes:
+                await node.stop()
+
+    asyncio.run(run())
