@@ -149,6 +149,15 @@ class ClusterMetrics:
             "tpu_plane_lanes_total",
             "Crypto lanes executed through the coalesced plane",
         )
+        self.plane_windows_closed = counter(
+            "tpu_plane_windows_closed_total",
+            "Coalescing windows closed, by cause: complete = every wave "
+            "in it was whole (nothing waited out), timer = it ran its "
+            "length (a set was missing or late, or a job carried no "
+            "wave hint), deadline / pulled_earlier = a duty deadline "
+            "capped it",
+            ["cause"],
+        )
         # pipelined host plane (ISSUE 3): per-flush latency/occupancy,
         # decode-pool queueing, bucket-padding waste, device-lane depth
         self.plane_flush_seconds = Histogram(

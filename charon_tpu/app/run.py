@@ -498,6 +498,10 @@ async def build_node(config: Config) -> Node:
             if s.jobs >= 2:
                 metrics.labels(metrics.plane_coalesced).inc()
             metrics.labels(metrics.plane_lanes).inc(s.lanes)
+            if s.window_closed_by:  # a host-fallback flush names none
+                metrics.labels(
+                    metrics.plane_windows_closed, s.window_closed_by
+                ).inc()
             metrics.labels(metrics.plane_flush_seconds).observe(
                 s.flush_seconds
             )
@@ -876,6 +880,7 @@ async def build_node(config: Config) -> Node:
         slots_per_epoch=config.slots_per_epoch,
         plane=tenant_plane,
         tracer=node_tracer,
+        operators=len(pubshares_by_idx),
     )
     verifier = Eth2Verifier(
         fork,

@@ -46,11 +46,31 @@ math:
     program, preserving the device-contention and counter-integrity
     guarantees of the original single-lane design.
 
-The coalescing window is adaptive: it grows toward `window_max` under
+What closes a window (`FlushStats.window_closed_by`, the
+`cryptoplane.window` span's `closed_by`, `SlotCoalescer.windows_closed`):
+
+  * "complete" — the wave is whole. A submission may say which waves it
+    belongs to and how many submissions each expects (`wave=((key,
+    expected), ...)`: a duty's partial-signature set is one of n, its
+    recombine job one of 1). The window keeps a ledger of the jobs it
+    holds per (kind, key); as soon as every wave seen in it has
+    `seen >= expected`, every job in it carried a hint and no
+    submission is still decoding, it closes at once: nothing more can
+    come, so waiting buys nothing. Never on fewer than `expected`: the
+    stragglers would flush alone on another bucket.
+  * "timer" — the window ran its length: a wave came short (a peer's set
+    missing or late) or a job carried no hint (the remote client, a
+    quarantine coalescer, tools): exactly the behaviour before hints.
+  * "deadline" / "pulled_earlier" — a submission carrying a duty
+    deadline (core/deadline.SlotClock.duty_deadline) armed the window
+    already capped, or pulled an armed one earlier, so near-deadline
+    work never waits out a grown window.
+
+The window's length is adaptive: it grows toward `window_max` under
 sustained multi-job load (catch more of the burst per program) and
-decays back to the base once traffic thins; a submission carrying a duty
-deadline (core/deadline.SlotClock.duty_deadline) pulls the flush earlier
-so near-deadline work never waits out a grown window.
+decays back to the base once traffic thins. A window closed "complete"
+feeds the controller nothing either way: a wave that came whole is no
+evidence that waiting longer catches more.
 
 Decode failures (malformed compressed points) never reach the device:
 those lanes fail on host and are replaced by lane-0 padding in the batch.
@@ -155,7 +175,8 @@ class FlushStats:
     # the window closed (what that job WAITED, where `window` above is
     # what was configured), and what closed it: "timer" (ran its
     # length), "deadline" (armed already capped by a duty deadline),
-    # "pulled_earlier" (a later submission's deadline pulled it in)
+    # "pulled_earlier" (a later submission's deadline pulled it in),
+    # "complete" (every hinted wave in it was whole: module docstring)
     window_span: tuple[float, float] | None = None
     window_closed_by: str = ""
     # (trace_id, span_id) captured from each submission's active span
@@ -279,14 +300,22 @@ class SlotCoalescer:
     window: base seconds to wait after the first submission before
     flushing; the adaptive controller moves the live window within
     [window, window_max] under load and deadlines cap it down to
-    window_min.
+    window_min. The wait ends early when the window's waves are whole
+    (`wave=` on verify / recombine; module docstring "What closes a
+    window"): such a close leaves the controller as it was. Without
+    hints, or a set short, the timer closes it as before.
     decode_workers: decode/pack pool size; 0 disables the pipeline
     entirely (decode runs synchronously on the caller — the pre-pipeline
     path, kept for A/B benching). The pool is created lazily on first
     use, so an idle or disabled plane owns no threads.
-    flushes / coalesced_flushes / lanes_flushed: observability counters
-    (exported as node metrics by app/run.py).
+    flushes / coalesced_flushes / lanes_flushed / windows_closed (by
+    cause): observability counters (exported as node metrics by
+    app/run.py).
     """
+
+    # submitters may pass `wave=` (TenantPlane says the same; the remote
+    # client and test fakes do not, and their callers send no hint)
+    wave_hints = True
 
     # decode-pool chunking: large enough to amortize executor submission,
     # small enough to spread one burst across the workers
@@ -353,6 +382,10 @@ class SlotCoalescer:
         self._wall_offset = 0.0  # wall->monotonic, snapshotted per window
         # submissions mid-decode (closing windows wait for these)
         self._decode_tickets: set[asyncio.Future] = set()
+        # the armed window's wave ledger: (kind, key) -> [jobs seen,
+        # jobs expected], and how many of its jobs carried no hint
+        self._waves: dict[tuple, list[int]] = {}
+        self._unhinted_jobs = 0
         self._window_current = window
         # first-dispatch gate (app/run.py wires the autotune tune_done
         # event here): the boot-time tuner's trial.apply() flips the
@@ -373,6 +406,7 @@ class SlotCoalescer:
         # sees traffic (or runs with decode_workers=0) owns no threads
         self._decode_pool: concurrent.futures.ThreadPoolExecutor | None = None
         self.flushes = 0
+        self.windows_closed: dict[str, int] = {}  # by `closed_by` cause
         self.coalesced_flushes = 0  # flushes that merged >= 2 jobs
         self.lanes_flushed = 0
         self.host_fallback_flushes = 0  # served by the python-spec rung
@@ -518,13 +552,18 @@ class SlotCoalescer:
         items: Sequence[tuple[bytes, bytes, bytes]],
         deadline: float | None = None,
         tenant: str | None = None,
+        wave: Sequence[tuple[object, int]] | None = None,
     ) -> list[bool]:
         """Batch-verify (pubkey_bytes, signing_root, sig_bytes) lanes.
         Returns per-lane validity; malformed encodings are False.
         deadline: optional absolute wall-clock (time.time) duty deadline
         — pulls the flush earlier when the window would overshoot it.
         tenant: optional tenant id (core/cryptosvc) for per-flush
-        attribution in FlushStats/metrics/span attrs."""
+        attribution in FlushStats/metrics/span attrs.
+        wave: optional ((key, expected), ...) — this job is one of
+        `expected` verify jobs of wave `key` (a duty's partial-signature
+        set is one of n); a window whose waves are all whole closes
+        without waiting out its timer (module docstring)."""
         if not items:
             return []
         loop = asyncio.get_running_loop()
@@ -552,6 +591,7 @@ class SlotCoalescer:
                 tenant=tenant,
             )
             self._verify_q.append(job)
+            self._count_wave("verify", wave)
             self._arm(deadline)
         finally:
             # resolve AFTER the append above (same synchronous block):
@@ -560,6 +600,7 @@ class SlotCoalescer:
             self._decode_tickets.discard(ticket)
             if not ticket.done():
                 ticket.set_result(None)
+            self._close_if_whole()
         return await job.fut
 
     async def recombine(
@@ -571,9 +612,12 @@ class SlotCoalescer:
         indices: Sequence[Sequence[int]],
         deadline: float | None = None,
         tenant: str | None = None,
+        wave: Sequence[tuple[object, int]] | None = None,
     ) -> tuple[list[bytes | None], list[bool]]:
         """Threshold-recombine + verify a duty's [V, t] workload.
-        Returns ([V] group signature bytes or None, [V] ok flags)."""
+        Returns ([V] group signature bytes or None, [V] ok flags).
+        wave: as verify(); recombine jobs are counted apart from verify
+        jobs of the same key (a duty's recombine job is one of 1)."""
         if not roots:
             return [], []
         t = self.plane.t
@@ -636,11 +680,13 @@ class SlotCoalescer:
                 tenant=tenant,
             )
             self._recombine_q.append(job)
+            self._count_wave("recombine", wave)
             self._arm(deadline)
         finally:
             self._decode_tickets.discard(ticket)
             if not ticket.done():
                 ticket.set_result(None)
+            self._close_if_whole()
         sigs_pts, oks = await job.fut
         return (
             [
@@ -651,6 +697,37 @@ class SlotCoalescer:
         )
 
     # -- flush machinery ---------------------------------------------------
+
+    def _count_wave(self, kind: str, wave) -> None:
+        """Enter the job just appended into the window's wave ledger.
+        Hints that disagree on a wave's size keep the larger: a window
+        must never close on fewer jobs than any submitter expects."""
+        if not wave:
+            self._unhinted_jobs += 1
+            return
+        for key, expected in wave:
+            entry = self._waves.setdefault((kind, key), [0, 0])
+            entry[0] += 1
+            entry[1] = max(entry[1], expected)
+
+    def _window_whole(self) -> bool:
+        """Nothing more can join the armed window: every job in it said
+        which wave it belongs to, every such wave has all the jobs it
+        expects, and no submission is still decoding."""
+        return bool(
+            self._waves
+            and not self._unhinted_jobs
+            and not self._decode_tickets
+            and all(seen >= expected for seen, expected in self._waves.values())
+        )
+
+    def _close_if_whole(self) -> None:
+        """Wake the flush task when the window's waves are whole; it
+        judges again when it runs, so a job that joins in between keeps
+        the window open for ITS wave. Otherwise the window closes as it
+        always did: timer, deadline, pulled earlier."""
+        if self._window_whole():
+            self._flush_wake.set()
 
     def _arm(self, deadline: float | None = None) -> None:
         now = time.monotonic()
@@ -699,8 +776,12 @@ class SlotCoalescer:
     async def _flush_after_window(self) -> None:
         while True:
             self._flush_wake.clear()
+            if self._window_whole():
+                closed_by = "complete"
+                break
             remaining = self._flush_at - time.monotonic()
             if remaining <= 0:
+                closed_by = self._window_closed_by
                 break
             try:
                 await asyncio.wait_for(
@@ -711,7 +792,6 @@ class SlotCoalescer:
         # read before the first await below: a submission arriving from
         # here on may still pull `_flush_at`, but the window has closed
         window_span = (self._window_opened, time.time())  # lint: allow(monotonic-clock)
-        closed_by = self._window_closed_by
         gate = self.dispatch_gate
         if gate is not None and not gate.is_set():
             # startup tuner still settling the kernel dispatch flags:
@@ -732,6 +812,8 @@ class SlotCoalescer:
         # decode/pack stages overlap this flush's device stage
         self._flush_task = None
         self._queue_deadline = None
+        self._waves = {}
+        self._unhinted_jobs = 0
         if not vq and not rq:
             return
         if self._closed:
@@ -743,7 +825,11 @@ class SlotCoalescer:
                     job.fut.set_exception(TblsError("crypto plane closed"))
             return
         window_used = _Window(self._window_current, window_span, closed_by)
-        self._adapt_window(vq, rq)
+        self.windows_closed[closed_by] = self.windows_closed.get(closed_by, 0) + 1
+        if closed_by != "complete":
+            # a wave that came whole is no evidence that waiting longer
+            # catches more (nor that traffic thinned): controller untouched
+            self._adapt_window(vq, rq)
         loop = asyncio.get_running_loop()
         # host stage 2: pack the batch on the decode pool so the device
         # lane (possibly still executing the previous window) is never

@@ -181,6 +181,10 @@ class _Entry:
     ctx: contextvars.Context
     parent: tuple[str, str] | None  # submitter's (trace_id, span_id)
     admitted: float  # wall clock: into the tenant's queue
+    # the submitter's wave hint, keys namespaced by tenant: one tenant's
+    # jobs never make another tenant's wave whole (core/cryptoplane
+    # "What closes a window")
+    wave: tuple | None = None
 
 
 class _Tenant:
@@ -207,6 +211,8 @@ class TenantPlane:
     coalescer — same duck type (`t`, `verify`, `recombine`), tenant
     identity bound once at registration."""
 
+    wave_hints = True  # as SlotCoalescer: submitters may pass `wave=`
+
     def __init__(self, svc: "CryptoPlaneService", tenant_id: str):
         self._svc = svc
         self.tenant_id = tenant_id
@@ -215,21 +221,22 @@ class TenantPlane:
     def t(self) -> int:
         return self._svc.t
 
-    async def verify(self, items, deadline: float | None = None):
+    async def verify(self, items, deadline: float | None = None, wave=None):
         return await self._svc.submit(
-            self.tenant_id, "verify", (list(items),), len(items), deadline
+            self.tenant_id, "verify", (list(items),), len(items), deadline,
+            wave,
         )
 
     async def recombine(
         self, pubshares, roots, partials, group_pks, indices,
-        deadline: float | None = None,
+        deadline: float | None = None, wave=None,
     ):
         rows = (
             list(pubshares), list(roots), list(partials),
             list(group_pks), list(indices),
         )
         return await self._svc.submit(
-            self.tenant_id, "recombine", rows, len(rows[1]), deadline
+            self.tenant_id, "recombine", rows, len(rows[1]), deadline, wave
         )
 
 
@@ -340,6 +347,7 @@ class CryptoPlaneService:
         args: tuple,
         lanes: int,
         deadline: float | None,
+        wave=None,
     ):
         ten = self._tenants[tenant_id]
         if self._closed:
@@ -382,6 +390,11 @@ class CryptoPlaneService:
             ctx=contextvars.copy_context(),
             parent=parent,
             admitted=admitted,
+            wave=(
+                tuple(((tenant_id, key), n) for key, n in wave)
+                if wave
+                else None
+            ),
         )
         key = deadline if deadline is not None else float("inf")
         heapq.heappush(ten.queue, (key, entry.seq, entry))
@@ -521,17 +534,23 @@ class CryptoPlaneService:
     ) -> None:
         t0 = time.monotonic()
         coal = self._quarantine_coal(ten) if quarantined else self._coal
+        kwargs = {"deadline": entry.deadline, "tenant": ten.id}
+        if (
+            entry.wave is not None
+            and not quarantined
+            and getattr(coal, "wave_hints", False)
+        ):
+            # a quarantined job leaves its wave short in the shared
+            # window (the timer closes that) and says nothing to the
+            # tenant's own coalescer: it closes as it always did
+            kwargs["wave"] = entry.wave
         try:
             if entry.kind == "verify":
-                res = await coal.verify(
-                    entry.args[0], deadline=entry.deadline, tenant=ten.id
-                )
+                res = await coal.verify(entry.args[0], **kwargs)
                 ok = sum(1 for r in res if r)
                 failed = len(res) - ok
             else:
-                res = await coal.recombine(
-                    *entry.args, deadline=entry.deadline, tenant=ten.id
-                )
+                res = await coal.recombine(*entry.args, **kwargs)
                 oks = res[1]
                 ok = sum(1 for r in oks if r)
                 failed = len(oks) - ok

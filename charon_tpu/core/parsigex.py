@@ -133,6 +133,15 @@ class Eth2Verifier:
             # near-deadline sets shrink the coalescing window instead of
             # waiting out a load-grown one (core/cryptoplane adaptive)
             kwargs["deadline"] = self.clock.duty_deadline(duty)
+        if getattr(self.plane, "wave_hints", False):
+            # this set is one of n for its duty and validators (n - 1
+            # peers' and the node's own VC's): the window closes when
+            # the wave is whole instead of waiting out its timer
+            # (core/cryptoplane). Sets cut differently by their senders
+            # share no key and fall to the timer.
+            kwargs["wave"] = (
+                ((duty, frozenset(signed_set)), len(self.pubshares_by_idx)),
+            )
         try:
             return all(await self.plane.verify(items, **kwargs))
         except PlaneOverloadError:

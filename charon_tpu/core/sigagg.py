@@ -170,6 +170,10 @@ class SigAgg:
         kwargs = {}
         if self.clock is not None:
             kwargs["deadline"] = self.clock.duty_deadline(duty)
+        if getattr(self.plane, "wave_hints", False):
+            # the duty's one recombine job: nothing more can join it, so
+            # its window need not wait out the timer (core/cryptoplane)
+            kwargs["wave"] = ((duty, 1),)
         from charon_tpu.core.cryptosvc import PlaneOverloadError
 
         try:

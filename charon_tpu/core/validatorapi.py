@@ -46,6 +46,11 @@ class ValidatorAPI:
     # from concurrent VC submissions merge into one sharded device program
     plane: object | None = None
     tracer: object | None = None  # app/tracer.Tracer; None = process-global
+    # cluster size n: the VC's set of a duty is one of the n sets the
+    # plane verifies for it (the peers' come through ParSigEx), which a
+    # submission tells the plane so that the coalescing window closes
+    # when the wave is whole; 0 = unknown, no hint (core/cryptoplane)
+    operators: int = 0
 
     def __post_init__(self) -> None:
         self._subs: list = []
@@ -238,20 +243,21 @@ class ValidatorAPI:
         ) as s:
             metas = list(entries)
             s.attrs["count"] = len(metas)
+            signed_sets: dict[Duty, dict[PubKey, ParSignedData]] = {}
+            for d, pk, signed in metas:
+                signed_sets.setdefault(d, {})[pk] = ParSignedData(
+                    data=signed, share_idx=self.share_idx
+                )
             ok = await self._check_batch(
-                [self._verify_item(pk, signed, d.slot) for d, pk, signed in metas]
+                [self._verify_item(pk, signed, d.slot) for d, pk, signed in metas],
+                sets=[(d, frozenset(ss)) for d, ss in signed_sets.items()],
             )
             if not all(ok):
                 s.attrs["rejected"] = sum(1 for lane in ok if not lane)
                 raise VapiError(
                     "partial signature failed pubshare verification"
                 )
-            by_duty: dict[Duty, dict[PubKey, ParSignedData]] = {}
-            for d, pk, signed in metas:
-                by_duty.setdefault(d, {})[pk] = ParSignedData(
-                    data=signed, share_idx=self.share_idx
-                )
-            for d, signed_set in by_duty.items():
+            for d, signed_set in signed_sets.items():
                 for sub in self._subs:
                     await sub(d, signed_set)
 
@@ -262,18 +268,24 @@ class ValidatorAPI:
         root = signed.signing_root(self.fork, slot // self.slots_per_epoch)
         return (pubshare, root, signed.signature)
 
-    async def _check_batch(self, items) -> list[bool]:
+    async def _check_batch(self, items, sets=()) -> list[bool]:
         """Per-lane verdicts of partial signatures against pubshares — batched
         (ref: validatorapi.go:1213 one herumi call per signature). With a
         crypto plane installed, concurrent submissions coalesce into one
-        sharded device program."""
+        sharded device program. `sets`: (duty, its validators in this
+        request) per duty the request spans — for each, this batch is one
+        of the `operators` sets of that wave (the peers' come through
+        ParSigEx under the same key)."""
         if self.plane is not None:
             import asyncio
 
             from charon_tpu.core.cryptosvc import PlaneOverloadError
 
+            kwargs = {}
+            if self.operators and getattr(self.plane, "wave_hints", False):
+                kwargs["wave"] = tuple((key, self.operators) for key in sets)
             try:
-                ok = await self.plane.verify(items)
+                ok = await self.plane.verify(items, **kwargs)
             except PlaneOverloadError:
                 # admission shed (core/cryptosvc backpressure): this
                 # VC's submission verifies on the host tbls rung, off

@@ -393,3 +393,313 @@ def test_no_dispatch_gate_means_no_gating():
     sig = impl.sign(sk, b"\x99" * 32)
     assert asyncio.run(plane.verify([(pk, b"\x99" * 32, sig)])) == [True]
     assert plane.gated_flushes == 0
+
+
+# -- what closes a window (ISSUE 27) ------------------------------------------
+#
+# A submission may say which wave it belongs to and how many submissions
+# that wave expects; the window closes "complete" the moment every wave
+# in it is whole and nothing is still decoding. The coalescer's clock
+# stands still in these tests and its timer is a year long on the real
+# one, so a window closes on its timer only when a test says so: no
+# assertion here races the wall clock.
+
+from charon_tpu.core import cryptoplane as _cp
+from charon_tpu.crypto import g1g2
+
+YEAR = 3.0e7
+
+
+class _StillClock:
+    """Stands where the `time` module stands in core/cryptoplane."""
+
+    def __init__(self):
+        self.now = 1000.0
+
+    def monotonic(self):
+        return self.now
+
+    def time(self):
+        return self.now
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    c = _StillClock()
+    monkeypatch.setattr(_cp, "time", c)
+    return c
+
+
+def _ring_timer(coal, clock):
+    """The armed window's timer runs out."""
+    clock.now = coal._flush_at + 0.001
+    coal._flush_wake.set()
+
+
+def _lane(root=b"\x07" * 32):
+    return (g1g2.g1_to_bytes(g1g2.G1_GEN), root, g1g2.g2_to_bytes(g1g2.G2_GEN))
+
+
+def _coalescer(**kw):
+    fake, stats = FakePlane(T), []
+    kw.setdefault("decode_workers", 0)  # decode inline: a submission is
+    # in the queue by the time its task first yields
+    coal = SlotCoalescer(
+        fake, window=YEAR, window_max=2 * YEAR, stats_hook=stats.append, **kw
+    )
+    return coal, fake, stats
+
+
+async def _settle(turns=5):
+    for _ in range(turns):
+        await asyncio.sleep(0)
+
+
+async def _all(*aws):
+    return await asyncio.wait_for(asyncio.gather(*aws), 30)
+
+
+def test_whole_wave_closes_complete_and_runs_one_program(clock):
+    coal, fake, stats = _coalescer()
+    wave = (("duty-5", 4),)
+
+    async def main():
+        return await _all(*(coal.verify([_lane()], wave=wave) for _ in range(4)))
+
+    assert asyncio.run(main()) == [[True]] * 4
+    assert fake.verify_calls == 1 and fake.verify_lane_count == 4
+    (s,) = stats
+    assert s.window_closed_by == "complete" and s.jobs == 4
+    assert s.window_span == (1000.0, 1000.0), "nothing was waited out"
+    assert s.window == YEAR, "the window CONFIGURED travels as before"
+    assert coal.windows_closed == {"complete": 1}
+
+
+def test_wave_one_set_short_waits_for_the_timer(clock):
+    """A silent operator: n - 1 of n sets. Never close on fewer than the
+    wave expects (the straggler would flush alone, on another bucket)."""
+    coal, fake, stats = _coalescer()
+    wave = (("duty-5", 4),)
+
+    async def main():
+        jobs = [asyncio.create_task(coal.verify([_lane()], wave=wave)) for _ in range(3)]
+        await _settle()
+        assert len(coal._verify_q) == 3 and fake.verify_calls == 0
+        assert not coal._flush_task.done(), "a short wave keeps its window open"
+        _ring_timer(coal, clock)
+        return await _all(*jobs)
+
+    assert asyncio.run(main()) == [[True]] * 3
+    assert fake.verify_calls == 1
+    (s,) = stats
+    assert s.window_closed_by == "timer" and s.jobs == 3
+    assert s.window_span[1] - s.window_span[0] == pytest.approx(YEAR, rel=1e-6)
+    assert coal.windows_closed == {"timer": 1}
+
+
+@pytest.mark.parametrize("second", ["verify", "recombine"])
+def test_window_stays_open_until_every_wave_in_it_is_whole(clock, second):
+    """Two duties in one window, one whole and one not: the window waits
+    for the second. Verify and recombine jobs of one key are counted
+    apart, so a duty's recombine job never completes its verify wave."""
+    impl = PythonImpl()
+    tbls.set_implementation(impl)
+    coal, fake, stats = _coalescer()
+    pk, gpk, psigs, root, _, ps = _duty_workload(impl, slot=5)
+    idx = [1, 2, 3]
+    row = dict(
+        pubshares=[[ps[i] for i in idx]], roots=[root],
+        partials=[[p.data.signature for p in psigs]], group_pks=[gpk], indices=[idx],
+    )
+
+    async def main():
+        whole = [asyncio.create_task(coal.verify([_lane()], wave=(("A", 2),))) for _ in range(2)]
+        half = asyncio.create_task(coal.verify([_lane()], wave=(("B", 2),)))
+        if second == "recombine":
+            # B's recombine job is whole by itself and adds nothing to
+            # B's verify wave
+            other = asyncio.create_task(coal.recombine(**row, wave=(("B", 1),)))
+        await _settle()
+        assert fake.verify_calls == 0 and not coal._flush_task.done()
+        last = asyncio.create_task(coal.verify([_lane()], wave=(("B", 2),)))
+        out = await _all(*whole, half, last)
+        if second == "recombine":
+            await asyncio.wait_for(other, 30)
+        return out
+
+    assert asyncio.run(main()) == [[True]] * 4
+    assert fake.verify_calls == 1 and fake.verify_lane_count == 4
+    (s,) = stats
+    assert s.window_closed_by == "complete"
+    assert s.jobs == (5 if second == "recombine" else 4)
+
+
+def test_submission_still_decoding_joins_the_complete_flush(clock, monkeypatch):
+    """The count completes while another submission is on the decode
+    pool: the window waits for it and both leave in ONE program."""
+    import threading
+
+    coal, fake, stats = _coalescer(decode_workers=2)
+    gate, slow_root = threading.Event(), b"\x09" * 32
+    decode = _cp._decode_verify_lane
+
+    def held(item):
+        if item[1] == slow_root:
+            assert gate.wait(30)
+        return decode(item)
+
+    monkeypatch.setattr(_cp, "_decode_verify_lane", held)
+
+    async def main():
+        slow = asyncio.create_task(coal.verify([_lane(slow_root)], wave=(("B", 1),)))
+        await _settle()
+        fast = [asyncio.create_task(coal.verify([_lane()], wave=(("A", 2),))) for _ in range(2)]
+        while len(coal._verify_q) < 2:  # both decoded and counted
+            await asyncio.sleep(0.001)
+        await _settle()
+        assert fake.verify_calls == 0 and not coal._flush_task.done(), (
+            "wave A is whole, but a submission is still decoding")
+        gate.set()
+        return await _all(slow, *fast)
+
+    try:
+        assert asyncio.run(main()) == [[True]] * 3
+    finally:
+        gate.set()
+        coal.close()
+    assert fake.verify_calls == 1, "no split"
+    (s,) = stats
+    assert s.window_closed_by == "complete" and s.jobs == 3
+
+
+def test_hinted_recombine_flushes_after_its_decode(clock):
+    """SigAgg says its job is the duty's one recombine job: the window
+    holds nothing that could still come, so it does not wait."""
+    impl = PythonImpl()
+    tbls.set_implementation(impl)
+    coal, fake, stats = _coalescer(decode_workers=1)
+    pk, gpk, psigs, root, want, ps = _duty_workload(impl, slot=5)
+    agg = SigAgg(
+        threshold=T, fork=FORK, plane=coal,
+        pubshares_by_idx={i: {pk: ps[i]} for i in (1, 2, 3, 4)},
+    )
+    out: dict = {}
+
+    async def on_agg(duty, data_set):
+        out.update(data_set)
+
+    agg.subscribe(on_agg)
+    try:
+        asyncio.run(asyncio.wait_for(agg.aggregate(Duty(5, DutyType.ATTESTER), {pk: psigs}), 30))
+    finally:
+        coal.close()
+    assert out[pk].signature == want and fake.recombine_calls == 1
+    (s,) = stats
+    assert s.window_closed_by == "complete" and s.jobs == 1
+    assert s.decode_spans and s.window_span[0] >= s.decode_spans[-1][1]
+
+
+@pytest.mark.parametrize("hinted", [0, 2])
+def test_job_without_a_hint_closes_its_window_as_before(clock, hinted):
+    """No hint, no change: the timer closes the window and the controller
+    adapts — also where a whole hinted wave shares the window with it."""
+    coal, fake, stats = _coalescer()
+
+    async def main():
+        jobs = [asyncio.create_task(coal.verify([_lane()])) for _ in range(2)]
+        jobs += [
+            asyncio.create_task(coal.verify([_lane()], wave=(("A", hinted),)))
+            for _ in range(hinted)
+        ]
+        await _settle()
+        assert fake.verify_calls == 0 and not coal._flush_task.done()
+        _ring_timer(coal, clock)
+        return await _all(*jobs)
+
+    assert asyncio.run(main()) == [[True]] * (2 + hinted)
+    (s,) = stats
+    assert s.window_closed_by == "timer" and s.jobs == 2 + hinted
+    assert coal.current_window == pytest.approx(YEAR * coal.WINDOW_GROW)
+    assert coal.windows_closed == {"timer": 1}
+
+
+def test_complete_close_leaves_the_adaptive_window_as_it_was(clock):
+    """A wave that came whole is no evidence that waiting longer catches
+    more, nor that traffic thinned: neither grow nor decay."""
+    coal, fake, stats = _coalescer()
+
+    async def whole(key):
+        await _all(*(coal.verify([_lane()], wave=((key, 3),)) for _ in range(3)))
+
+    async def main():
+        await whole("A")  # three jobs: a timer close would grow the window
+        assert coal.current_window == YEAR
+        jobs = [asyncio.create_task(coal.verify([_lane()])) for _ in range(2)]
+        await _settle()
+        _ring_timer(coal, clock)
+        await _all(*jobs)
+        grown = coal.current_window
+        assert grown == pytest.approx(YEAR * coal.WINDOW_GROW)
+        await _all(coal.verify([_lane()], wave=(("B", 1),)))  # one job: a timer close would decay it
+        assert coal.current_window == grown
+
+    asyncio.run(main())
+    assert [s.window_closed_by for s in stats] == ["complete", "timer", "complete"]
+    assert stats[2].window == pytest.approx(YEAR * coal.WINDOW_GROW)
+
+
+def test_a_deadline_still_caps_a_window_whose_wave_is_short(clock):
+    coal, fake, stats = _coalescer()
+
+    async def main():
+        job = asyncio.create_task(
+            coal.verify([_lane()], wave=(("A", 2),), deadline=clock.now + 1.0))
+        await _settle()
+        assert coal._flush_at == pytest.approx(clock.now + 0.01)  # 1 % of what is left
+        _ring_timer(coal, clock)
+        return await asyncio.wait_for(job, 30)
+
+    assert asyncio.run(main()) == [True]
+    assert stats[0].window_closed_by == "deadline"
+
+
+def test_vc_submission_and_peer_sets_make_one_wave(clock):
+    """The call sites agree on the key: the VC's request (ValidatorAPI)
+    and the n - 1 peers' sets (ParSigEx's verifier) of one duty and one
+    set of validators are ONE wave of n, through a tenant's handle."""
+    from charon_tpu.core.cryptosvc import CryptoPlaneService
+    from charon_tpu.core.types import PubKey
+    from charon_tpu.core.validatorapi import ValidatorAPI
+
+    coal, fake, stats = _coalescer()
+    svc = CryptoPlaneService(coal, round_interval=0.001)
+    plane = svc.register("cluster-a")
+    pk = PubKey("0x" + "ab" * 48)
+    share, sig = g1g2.g1_to_bytes(g1g2.G1_GEN), g1g2.g2_to_bytes(g1g2.G2_GEN)
+    n = 4
+    pubshares_by_idx = {i: {pk: share} for i in range(1, n + 1)}
+    vapi = ValidatorAPI(1, pubshares_by_idx[1], FORK, plane=plane, operators=n)
+    verifier = Eth2Verifier(FORK, pubshares_by_idx, plane=plane)
+    duty = Duty(64, DutyType.RANDAO)
+
+    def peer_set(idx):
+        return {pk: d.ParSignedData(data=d.SignedData("randao", 2, sig), share_idx=idx)}
+
+    async def main():
+        peers = [asyncio.create_task(verifier.verify_async(duty, peer_set(i))) for i in (2, 3)]
+        mine = asyncio.create_task(vapi.submit_randao(duty.slot, pk, sig))
+        await _settle(20)
+        assert len(coal._verify_q) == 3 and fake.verify_calls == 0, "3 of 4: wait"
+        last = asyncio.create_task(verifier.verify_async(duty, peer_set(4)))
+        assert await _all(*peers, last) == [True] * 3
+        await asyncio.wait_for(mine, 30)
+
+    try:
+        asyncio.run(main())
+    finally:
+        svc.close()
+        coal.close()
+    assert fake.verify_calls == 1 and fake.verify_lane_count == n
+    (s,) = stats
+    assert s.window_closed_by == "complete" and s.jobs == n
+    assert s.tenant_lanes == (("cluster-a", n),)

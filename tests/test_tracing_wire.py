@@ -510,7 +510,7 @@ def test_each_submission_runs_under_its_own_span_not_the_dispatchers():
     asyncio.run(run())
 
 
-@pytest.mark.parametrize("closed_by", ["timer", "pulled_earlier", "deadline"])
+@pytest.mark.parametrize("closed_by", ["timer", "pulled_earlier", "deadline", "complete"])
 def test_cryptoplane_window_says_what_closed_it(closed_by):
     async def run():
         t = tracer.Tracer()
@@ -518,6 +518,11 @@ def test_cryptoplane_window_says_what_closed_it(closed_by):
         near = time.time() + 2.0  # the graded shrink: 1 % of what is left
         if closed_by == "timer":
             await plane.verify([_lane()])
+        elif closed_by == "complete":
+            # the wave's two sets: the second makes it whole
+            wave = (("5/attester", 2),)
+            await asyncio.gather(
+                plane.verify([_lane()], wave=wave), plane.verify([_lane()], wave=wave))
         elif closed_by == "deadline":
             await plane.verify([_lane()], deadline=near)
         else:
@@ -531,6 +536,7 @@ def test_cryptoplane_window_says_what_closed_it(closed_by):
         windows = [s for s in t.spans if s.name == "cryptoplane.window"
                    and not s.attrs.get("shared")]
         assert len(windows) == 1
+        assert coal.windows_closed == {closed_by: 1}
         return windows[0]
 
     w = asyncio.run(run())
