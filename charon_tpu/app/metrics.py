@@ -158,6 +158,12 @@ class ClusterMetrics:
             "capped it",
             ["cause"],
         )
+        self.plane_wave_sets_short = counter(
+            "tpu_plane_wave_sets_short_total",
+            "Partial-signature sets that verify windows expected and "
+            "closed without (each set says it is one of n): a steady "
+            "rise of k a duty is k operators not signing",
+        )
         # pipelined host plane (ISSUE 3): per-flush latency/occupancy,
         # decode-pool queueing, bucket-padding waste, device-lane depth
         self.plane_flush_seconds = Histogram(

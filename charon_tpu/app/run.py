@@ -502,6 +502,10 @@ async def build_node(config: Config) -> Node:
                 metrics.labels(
                     metrics.plane_windows_closed, s.window_closed_by
                 ).inc()
+            if s.sets_expected is not None:
+                metrics.labels(metrics.plane_wave_sets_short).inc(
+                    max(0, s.sets_expected - s.sets_seen)
+                )
             metrics.labels(metrics.plane_flush_seconds).observe(
                 s.flush_seconds
             )

@@ -402,6 +402,16 @@ def span(
         tracer.record(s)
 
 
+def annotate(name: str, **attrs) -> None:
+    """Add `attrs` to the context's active span if it is called `name`:
+    for a component whose span its caller opened (core/wire.tracing
+    wraps every edge) and that knows more than the wrapper. Anywhere
+    else (no tracing option, a bare component) it does nothing."""
+    s = _current.get()
+    if s is not None and s.name == name:
+        s.attrs.update(attrs)
+
+
 def record_span(
     name: str,
     trace_id: str,
@@ -510,6 +520,18 @@ def plane_span_bridge(
         # None on remote briefs (core/cryptosvc_client): the window ran on
         # the server
         window_span = stats.window_span
+        # which queues the window held, and how short its verify waves
+        # came (sets the submitters expected against sets seen; absent
+        # where the window held no verify wave or a job without a
+        # hint): a `timer` close with sets_seen < sets_expected is
+        # operators down, not unhinted traffic
+        window_attrs = {
+            "verify_jobs": stats.verify_jobs,
+            "recombine_jobs": stats.recombine_jobs,
+        }
+        if stats.sets_expected is not None:
+            window_attrs["sets_expected"] = stats.sets_expected
+            window_attrs["sets_seen"] = stats.sets_seen
         for i, (trace_id, parent_id) in enumerate(parents):
             # one flush -> one record per submitting span: mark the
             # copies beyond the first so metric hooks (span_metrics)
@@ -529,6 +551,7 @@ def plane_span_bridge(
                     jobs=stats.jobs,
                     lanes=stats.lanes,
                     closed_by=stats.window_closed_by,
+                    **window_attrs,
                     **dup,
                 )
             flush = record_span(

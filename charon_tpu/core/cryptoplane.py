@@ -61,6 +61,16 @@ What closes a window (`FlushStats.window_closed_by`, the
   * "timer" — the window ran its length: a wave came short (a peer's set
     missing or late) or a job carried no hint (the remote client, a
     quarantine coalescer, tools): exactly the behaviour before hints.
+    Such a close DOES feed the window controller below, and a short
+    wave of two or more sets counts there as load: a cluster with
+    operators down (fewer sets than the n its submitters expect, every
+    duty) waits out every verify window AND grows it x1.5 a wave toward
+    `window_max`, though no further set can come; its recombine
+    windows (one job expected, one seen) still close "complete" and
+    feed it nothing, so nothing decays it either. How short the wave
+    was is on the flush (`FlushStats.sets_expected` / `.sets_seen`, the
+    `cryptoplane.window` span, `tpu_plane_wave_sets_short_total`):
+    that tells a degraded cluster from unhinted traffic.
   * "deadline" / "pulled_earlier" — a submission carrying a duty
     deadline (core/deadline.SlotClock.duty_deadline) armed the window
     already capped, or pulled an armed one earlier, so near-deadline
@@ -179,6 +189,15 @@ class FlushStats:
     # "complete" (every hinted wave in it was whole: module docstring)
     window_span: tuple[float, float] | None = None
     window_closed_by: str = ""
+    # the two queues apart (`jobs` is their sum: one window can hold
+    # both), and the window's wave ledger summed over the verify waves
+    # it held: partial-signature sets its submitters expected, and sets
+    # that came. None where the window held no verify wave or a job in
+    # it carried no hint (the ledger is then not the whole story)
+    verify_jobs: int = 0
+    recombine_jobs: int = 0
+    sets_expected: int | None = None
+    sets_seen: int | None = None
     # (trace_id, span_id) captured from each submission's active span
     parents: tuple[tuple[str, str], ...] = ()
     # live lanes per submitting tenant (ISSUE 8): (tenant_id, lanes)
@@ -195,6 +214,8 @@ class _Window(NamedTuple):
     seconds: float = 0.0  # adaptive window in force when the flush armed
     span: tuple[float, float] | None = None  # wall clock: opened, closed
     closed_by: str = ""
+    sets_expected: int | None = None  # FlushStats, same names
+    sets_seen: int | None = None
 
 
 class PlaneConfigError(ValueError):
@@ -710,6 +731,22 @@ class SlotCoalescer:
             entry[0] += 1
             entry[1] = max(entry[1], expected)
 
+    def _verify_sets(self) -> tuple[int | None, int | None]:
+        """The armed window's ledger summed over its verify waves:
+        (sets expected, sets seen) — (None, None) where it held no
+        verify wave or a job without a hint."""
+        waves = [
+            entry
+            for (kind, _key), entry in self._waves.items()
+            if kind == "verify"
+        ]
+        if not waves or self._unhinted_jobs:
+            return None, None
+        return (
+            sum(expected for _seen, expected in waves),
+            sum(seen for seen, _expected in waves),
+        )
+
     def _window_whole(self) -> bool:
         """Nothing more can join the armed window: every job in it said
         which wave it belongs to, every such wave has all the jobs it
@@ -812,6 +849,7 @@ class SlotCoalescer:
         # decode/pack stages overlap this flush's device stage
         self._flush_task = None
         self._queue_deadline = None
+        sets_expected, sets_seen = self._verify_sets()
         self._waves = {}
         self._unhinted_jobs = 0
         if not vq and not rq:
@@ -824,7 +862,13 @@ class SlotCoalescer:
                 if not job.fut.done():
                     job.fut.set_exception(TblsError("crypto plane closed"))
             return
-        window_used = _Window(self._window_current, window_span, closed_by)
+        window_used = _Window(
+            self._window_current,
+            window_span,
+            closed_by,
+            sets_expected,
+            sets_seen,
+        )
         self.windows_closed[closed_by] = self.windows_closed.get(closed_by, 0) + 1
         if closed_by != "complete":
             # a wave that came whole is no evidence that waiting longer
@@ -1179,6 +1223,10 @@ class SlotCoalescer:
                 device_span=(w0, time.time()),  # lint: allow(monotonic-clock)
                 window_span=window_used.span,
                 window_closed_by=window_used.closed_by,
+                verify_jobs=len(vq),
+                recombine_jobs=len(rq),
+                sets_expected=window_used.sets_expected,
+                sets_seen=window_used.sets_seen,
                 parents=self._job_parents(vq, rq),
                 tenant_lanes=self._job_tenant_lanes(vq, rq),
             ),
@@ -1611,6 +1659,8 @@ class SlotCoalescer:
                 decode_python_lanes=python_n,
                 decode_spans=self._job_decode_spans(vq, rq),
                 device_span=(w0, time.time()),  # lint: allow(monotonic-clock)
+                verify_jobs=len(vq),
+                recombine_jobs=len(rq),
                 parents=self._job_parents(vq, rq),
                 tenant_lanes=self._job_tenant_lanes(vq, rq),
             ),

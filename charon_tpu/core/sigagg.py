@@ -64,6 +64,13 @@ class SigAgg:
     ) -> None:
         if not batch:
             return
+        # on the `sigagg.aggregate` span core/wire.tracing opened: how
+        # many partials each aggregate is made from. Beside the verify
+        # window's `sets_seen` it says what was to spare: equal is a
+        # cluster at bare quorum, one more silent operator costs the duty
+        from charon_tpu.app.tracer import annotate  # lazy: core !-> app
+
+        annotate("sigagg.aggregate", partials=self.threshold)
         epoch = duty.slot // self.slots_per_epoch
 
         excluded = (
