@@ -241,6 +241,16 @@ class ClusterMetrics:
             labels + ["cache"],
             registry=self.registry,
         )
+        self.point_cache_message_hashed = Gauge(
+            "tpu_point_cache_message_hashed",
+            "Cumulative misses of the message cache by the engine that "
+            "hashed the signing root to G2 (native = the C++ library, "
+            "GIL released; python = bigints with the GIL held: the "
+            "library is not built or does not agree with the "
+            "specification code)",
+            labels + ["engine"],
+            registry=self.registry,
+        )
         self.point_cache_size = Gauge(
             "tpu_point_cache_entries",
             "Current entries held by the tpu_impl point caches",
@@ -642,6 +652,8 @@ class ClusterMetrics:
             self.labels(self.point_cache_hits, name).set(info.hits)
             self.labels(self.point_cache_misses, name).set(info.misses)
             self.labels(self.point_cache_size, name).set(info.currsize)
+        for engine, hashed in impl._decode_msg_point.counts().items():
+            self.labels(self.point_cache_message_hashed, engine).set(hashed)
 
     def observe_dkg_verify(self, stage: str, path: str, lanes: int) -> None:
         """Record one ceremony verification wave: `lanes` checks of

@@ -16,6 +16,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import hashlib
+import itertools
 import json
 import os
 import secrets
@@ -440,16 +441,20 @@ def record_span(
     return s
 
 
-def _stretches(windows) -> list[tuple[float, float, int]]:
-    """Union of (start, end) windows as disjoint (start, end, n) stretches,
-    in time order; n = windows merged into the stretch."""
+def _stretches(windows, counts=()) -> list[tuple[float, float, int, int]]:
+    """Union of (start, end) windows as disjoint (start, end, n, count)
+    stretches, in time order; n = windows merged into the stretch, count
+    = the sum of their `counts` (one per window; a window without one
+    counts 0)."""
     out: list[list] = []
-    for start, end in sorted(windows):
+    weighted = itertools.zip_longest(windows, counts, fillvalue=0)
+    for (start, end), count in sorted(weighted):
         if out and start <= out[-1][1]:
             out[-1][1] = max(out[-1][1], end)
             out[-1][2] += 1
+            out[-1][3] += count
         else:
-            out.append([start, end, 1])
+            out.append([start, end, 1, count])
     return [tuple(w) for w in out]
 
 
@@ -484,9 +489,24 @@ def plane_span_bridge(
         # the jobs of a flush decode as they arrive, over the whole
         # window, and one span from the first chunk to the last would
         # cover the waiting in between
+        # msg_hashed: signing roots hashed to the curve in the stretch
+        # (misses of the message cache: the first job of a wave pays
+        # them all), and the engine that hashed them
+        engine = (
+            {"engine": stats.msg_hash_engine}
+            if stats.msg_hash_engine is not None
+            else {}
+        )
         stages = [
-            ("cryptoplane.decode", start, end, {"chunks": chunks})
-            for start, end, chunks in _stretches(stats.decode_spans)
+            (
+                "cryptoplane.decode",
+                start,
+                end,
+                {"chunks": chunks, "msg_hashed": hashed, **engine},
+            )
+            for start, end, chunks, hashed in _stretches(
+                stats.decode_spans, stats.decode_hashed
+            )
         ]
         if stats.pack_span is not None:
             stages.append(

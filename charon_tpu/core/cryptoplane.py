@@ -127,6 +127,7 @@ class _VerifyJob:
     fut: asyncio.Future
     decode_delays: tuple = ()  # decode-pool queue delay per chunk
     decode_spans: tuple = ()  # wall-clock (start, end) per decode chunk
+    decode_hashed: tuple = ()  # message-cache misses per decode chunk
     parent: tuple | None = None  # submitter's (trace_id, span_id)
     tenant: str | None = None  # submitting tenant (core/cryptosvc)
 
@@ -143,6 +144,7 @@ class _RecombineJob:
     fut: asyncio.Future
     decode_delays: tuple = ()
     decode_spans: tuple = ()
+    decode_hashed: tuple = ()
     parent: tuple | None = None
     tenant: str | None = None
 
@@ -179,6 +181,12 @@ class FlushStats:
     decode_python_lanes: int = 0
     # wall-clock stage windows of THIS flush's pipeline pass
     decode_spans: tuple[tuple[float, float], ...] = ()  # per decode chunk
+    # signing roots each of those chunks hashed to the curve (misses of
+    # the tpu_impl message cache on the chunk's thread), and the engine
+    # that hashes them in this process: "native" | "python"
+    # (tpu_impl.MsgHashEngine), None while no root has missed yet
+    decode_hashed: tuple[int, ...] = ()
+    msg_hash_engine: str | None = None
     pack_span: tuple[float, float] | None = None
     device_span: tuple[float, float] | None = None
     # the coalescing window itself: first job into the idle coalescer ->
@@ -273,6 +281,12 @@ def _msg_point(root: bytes):
     return _cached_msg_point(root)
 
 
+def _msg_hash_engine():
+    from charon_tpu.tbls.tpu_impl import _decode_msg_point
+
+    return _decode_msg_point
+
+
 def _decode_verify_lane(item):
     """(pk, root, sig) bytes -> decoded point triple, or None on any
     malformed encoding (the lane fails on host, never ships)."""
@@ -285,12 +299,20 @@ def _decode_verify_lane(item):
 
 def _parse_verify_lane(item):
     """decode_mode=device twin of _decode_verify_lane: the pubkey and
-    message still come from the host LRU caches (cache-hit dominated),
-    but the signature is only PARSED (flags + range checks, no field
-    arithmetic) — the Fp2 sqrt, sign selection, on-curve and subgroup
-    checks run batched on device inside the flush program. Lanes the
-    parse already rejects (malformed flags, x >= p, infinity) fail on
-    host and never ship."""
+    message still come from the host LRU caches, but the signature is
+    only PARSED (flags + range checks, no field arithmetic) — the Fp2
+    sqrt, sign selection, on-curve and subgroup checks run batched on
+    device inside the flush program. The pubkey always hits (the key
+    table is warmed at boot). The message cannot be warmed (a signing
+    root does not exist before its slot): the first job of a wave
+    misses once per distinct root (31-32 an attester wave), as does a
+    set that arrives while those are still being hashed, and pays
+    tpu_impl.MsgHashEngine for each — ~6 ms, most of it native code
+    with the GIL released, ~14 ms of GIL-held bigints on a host
+    without the library; every later set hits. That is the wave's one
+    wide `cryptoplane.decode` span (`msg_hashed` counts its misses). Lanes
+    the parse already rejects (malformed flags, x >= p, infinity) fail
+    on host and never ship."""
     pk, root, sig = item
     try:
         pk_pt, msg_pt = _decode_pubkey(pk), _msg_point(root)
@@ -518,20 +540,30 @@ class SlotCoalescer:
         """Apply `fn` per item with the bigint work OFF the event loop:
         items ship to the decode pool in DECODE_CHUNK chunks (batched
         submission — one executor hop per chunk, not per lane). Returns
-        (results, per-chunk queue delays, per-chunk wall-clock spans) —
-        both travel with the job so each flush's stats report ITS OWN
-        decode queueing/timing, not whatever the concurrent next window
-        happens to be decoding. With the pool disabled the map runs
-        inline on the caller — the pre-pipeline synchronous path
-        bench_hostplane.py baselines."""
+        (results, per-chunk queue delays, per-chunk wall-clock spans,
+        per-chunk message-cache misses) — the last three travel with the
+        job so each flush's stats report ITS OWN decode queueing, timing
+        and hashing, not whatever the concurrent next window happens to
+        be decoding. With the pool disabled the map runs inline on the
+        caller — the pre-pipeline synchronous path bench_hostplane.py
+        baselines."""
+        engine = _msg_hash_engine()
+
+        def hashing(chunk):
+            """fn over the chunk, and the roots it hashed on the way
+            (the engine counts per thread: chunks run side by side)."""
+            before = engine.on_thread()
+            out = [fn(it) for it in chunk]
+            return out, engine.on_thread() - before
+
         # closed: inline decode instead of resurrecting a pool nobody
         # will shut down (the flush fails these waiters fast anyway)
         if self.decode_workers <= 0 or self._closed:
             # stage spans are ATTRIBUTION: wall-clock windows bridged
             # into duty traces (tracer.plane_span_bridge), never math
             w0 = time.time()  # lint: allow(monotonic-clock)
-            out = [fn(it) for it in items]
-            return out, (), ((w0, time.time()),)  # lint: allow(monotonic-clock)
+            out, hashed = hashing(items)
+            return out, (), ((w0, time.time()),), (hashed,)  # lint: allow(monotonic-clock)
         loop = asyncio.get_running_loop()
         pool = self._pool()
         submitted = time.monotonic()
@@ -541,8 +573,8 @@ class SlotCoalescer:
             # wall span = trace attribution; the queue DELAY above it
             # stays on the monotonic base
             w0 = time.time()  # lint: allow(monotonic-clock)
-            out = [fn(it) for it in chunk]
-            return out, t0 - submitted, (w0, time.time())  # lint: allow(monotonic-clock)
+            out, hashed = hashing(chunk)
+            return out, t0 - submitted, (w0, time.time()), hashed  # lint: allow(monotonic-clock)
 
         chunks = [
             items[i : i + self.DECODE_CHUNK]
@@ -552,9 +584,10 @@ class SlotCoalescer:
             *(loop.run_in_executor(pool, run_chunk, c) for c in chunks)
         )
         return (
-            [lane for part, _, _ in parts for lane in part],
-            tuple(delay for _, delay, _ in parts),
-            tuple(span for _, _, span in parts),
+            [lane for part, _, _, _ in parts for lane in part],
+            tuple(delay for _, delay, _, _ in parts),
+            tuple(span for _, _, span, _ in parts),
+            tuple(hashed for _, _, _, hashed in parts),
         )
 
     # -- submission APIs (event-loop side) --------------------------------
@@ -600,7 +633,7 @@ class SlotCoalescer:
                 if self._decode_rung() == "device"
                 else _decode_verify_lane
             )
-            lanes, delays, spans = await self._map_offloop(
+            lanes, delays, spans, hashed = await self._map_offloop(
                 decode_fn, list(items)
             )
             job = _VerifyJob(
@@ -608,6 +641,7 @@ class SlotCoalescer:
                 fut=loop.create_future(),
                 decode_delays=delays,
                 decode_spans=spans,
+                decode_hashed=hashed,
                 parent=self._submit_ctx(),
                 tenant=tenant,
             )
@@ -680,7 +714,7 @@ class SlotCoalescer:
         ticket = loop.create_future()  # see verify() for the contract
         self._decode_tickets.add(ticket)
         try:
-            rows, delays, spans = await self._map_offloop(
+            rows, delays, spans, hashed = await self._map_offloop(
                 decode_row,
                 list(zip(pubshares, roots, partials, group_pks, indices)),
             )
@@ -697,6 +731,7 @@ class SlotCoalescer:
                 fut=loop.create_future(),
                 decode_delays=delays,
                 decode_spans=spans,
+                decode_hashed=hashed,
                 parent=self._submit_ctx(),
                 tenant=tenant,
             )
@@ -1219,6 +1254,8 @@ class SlotCoalescer:
                 decode_device_lanes=device_n,
                 decode_python_lanes=python_n,
                 decode_spans=self._job_decode_spans(vq, rq),
+                decode_hashed=self._job_decode_hashed(vq, rq),
+                msg_hash_engine=_msg_hash_engine().name,
                 pack_span=pack_span,
                 device_span=(w0, time.time()),  # lint: allow(monotonic-clock)
                 window_span=window_used.span,
@@ -1252,6 +1289,14 @@ class SlotCoalescer:
         """Wall-clock decode windows of exactly THIS flush's jobs."""
         return tuple(
             span for job in [*vq, *rq] for span in job.decode_spans
+        )
+
+    @staticmethod
+    def _job_decode_hashed(vq, rq) -> tuple[int, ...]:
+        """Message-cache misses of exactly THIS flush's jobs, one count
+        per decode chunk (parallel to _job_decode_spans)."""
+        return tuple(
+            n for job in [*vq, *rq] for n in job.decode_hashed
         )
 
     def _decode_breakdown(self, vq, rq) -> tuple[str, int, int, int]:
@@ -1658,6 +1703,8 @@ class SlotCoalescer:
                 decode_device_lanes=device_n,
                 decode_python_lanes=python_n,
                 decode_spans=self._job_decode_spans(vq, rq),
+                decode_hashed=self._job_decode_hashed(vq, rq),
+                msg_hash_engine=_msg_hash_engine().name,
                 device_span=(w0, time.time()),  # lint: allow(monotonic-clock)
                 verify_jobs=len(vq),
                 recombine_jobs=len(rq),

@@ -45,8 +45,86 @@ def _decode_pubkey_point(pubkey: bytes):
     return pt
 
 
-def _decode_msg_point(data: bytes):
-    return h2c.hash_to_g2(data)
+class MsgHashEngine:
+    """Hashes a message to G2 on a miss of the message cache.
+
+    Two engines compute the one function (RFC 9380, the same affine
+    point bit for bit): `native` — `ctpu_hash_to_g2` of
+    native/libcharon_native.so through ctypes, which drops the GIL for
+    the call, then the python rung's own G2 decompression of the 96
+    bytes (no subgroup check: a hash-to-curve output is in the subgroup
+    by construction) — and `python`, crypto/h2c in bigints, ~14 ms a
+    root with the GIL held. Which one serves is observed, not set: on
+    the first miss, under the lock, the library is loaded
+    (tbls/native_impl, `CHARON_NATIVE_LIB` as ever) and one fixed
+    message is hashed by both. Library or symbol missing, or answers
+    that differ: python, for the life of the process. That guarded
+    first call is also what makes the decode pool safe — the library's
+    `ensure_init()` is an unguarded `if (!INITED)`, and pool threads
+    must not be the first to enter it.
+
+    Counts every hash by engine (`counts`, the
+    `tpu_point_cache_message_hashed` family) and per calling thread
+    (`on_thread`: what a decode chunk reads before and after its lanes
+    to put `msg_hashed` on its `cryptoplane.decode` span)."""
+
+    PROBE = b"charon-tpu message hash engine probe"
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._hash = None  # resolved on the first miss
+        self.name: str | None = None  # "native" | "python" once resolved
+        self._counts = {"native": 0, "python": 0}
+        self._here = threading.local()
+
+    @staticmethod
+    def _native_hasher():
+        """The native engine's hasher, or None where the process has no
+        library that agrees with the specification code."""
+        try:
+            from charon_tpu.tbls.native_impl import NativeImpl
+        except (ImportError, OSError, AttributeError):
+            return None  # not built / not loadable / symbol missing
+        native = NativeImpl()
+
+        def hash_native(data: bytes):
+            return sig_to_point(
+                native.hash_to_g2_bytes(data), subgroup_check=False
+            )
+
+        try:
+            agrees = hash_native(MsgHashEngine.PROBE) == h2c.hash_to_g2(
+                MsgHashEngine.PROBE
+            )
+        except TblsError:
+            agrees = False
+        return hash_native if agrees else None
+
+    def __call__(self, data: bytes):
+        if self._hash is None:
+            with self._lock:
+                if self._hash is None:
+                    native = self._native_hasher()
+                    self.name = "native" if native else "python"
+                    self._hash = native or h2c.hash_to_g2
+        pt = self._hash(data)
+        with self._lock:
+            self._counts[self.name] += 1
+        self._here.n = self.on_thread() + 1
+        return pt
+
+    def counts(self) -> dict[str, int]:
+        with self._lock:
+            return dict(self._counts)
+
+    def on_thread(self) -> int:
+        """Messages hashed so far on the calling thread."""
+        return getattr(self._here, "n", 0)
+
+
+# the decoder behind _cached_msg_point (and the python rung of
+# warm_point_caches); process-wide, as the library and the caches are
+_decode_msg_point = MsgHashEngine()
 
 
 class _CacheInfo(NamedTuple):
@@ -129,7 +207,15 @@ def make_point_cache(decode, maxsize: int) -> PointCache:
 # Decompressed pubkeys cached by compressed bytes (cluster pubshares are
 # a small static set — ref: core/validatorapi pubshare maps), as are
 # hashed messages. Shared by this impl AND core/cryptoplane's decode
-# pool, and bulk-fed by the warm-up path below.
+# pool, and bulk-fed by the warm-up path below. The pubkeys are warmed
+# at boot; the messages cannot be — a signing root does not exist
+# before its slot — so the first job of every wave misses once per
+# distinct root (31-32 an attester wave); a job that arrives while
+# those are still being hashed misses them again (concurrent misses of
+# one key decode twice: PointCache), every later one hits. A miss costs
+# one MsgHashEngine call: ~3.5 ms of native code with the GIL released
+# plus ~2.5 ms of Python decompression on a host that has the library,
+# ~14 ms of GIL-held bigints on one that has not.
 _cached_pubkey_point = make_point_cache(_decode_pubkey_point, 65536)
 _cached_msg_point = make_point_cache(_decode_msg_point, 16384)
 
