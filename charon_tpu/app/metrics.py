@@ -153,16 +153,25 @@ class ClusterMetrics:
             "tpu_plane_windows_closed_total",
             "Coalescing windows closed, by cause: complete = every wave "
             "in it was whole (nothing waited out), timer = it ran its "
-            "length (a set was missing or late, or a job carried no "
-            "wave hint), deadline / pulled_earlier = a duty deadline "
-            "capped it",
+            "length (an awaited set was missing or late, or a job "
+            "carried no wave hint), deadline / pulled_earlier = a duty "
+            "deadline capped it",
             ["cause"],
+        )
+        self.plane_windows_closed_short = counter(
+            "tpu_plane_windows_closed_short_total",
+            "Of the windows closed complete: verify windows whole on "
+            "fewer sets than the cluster has operators, because the "
+            "operators that sent no set last slot were not awaited "
+            "(one a duty for as long as an operator's validator client "
+            "is down)",
         )
         self.plane_wave_sets_short = counter(
             "tpu_plane_wave_sets_short_total",
             "Partial-signature sets that verify windows expected and "
-            "closed without (each set says it is one of n): a steady "
-            "rise of k a duty is k operators not signing",
+            "closed without (each set says it is one of n, whoever was "
+            "awaited): a steady rise of k a duty is k operators not "
+            "signing",
         )
         # pipelined host plane (ISSUE 3): per-flush latency/occupancy,
         # decode-pool queueing, bucket-padding waste, device-lane depth

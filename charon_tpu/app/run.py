@@ -34,7 +34,12 @@ from charon_tpu.core.dutydb import DutyDB
 from charon_tpu.core.fetcher import Fetcher
 from charon_tpu.core.inclusion import InclusionChecker, InclusionReport
 from charon_tpu.core.parsigdb import ParSigDB
-from charon_tpu.core.parsigex import DutyGater, Eth2Verifier, ParSigEx
+from charon_tpu.core.parsigex import (
+    DutyGater,
+    Eth2Verifier,
+    ParSigEx,
+    WaveRoster,
+)
 from charon_tpu.core.scheduler import Scheduler
 from charon_tpu.core.sigagg import SigAgg
 from charon_tpu.core.tracker import Tracker, tracking
@@ -506,6 +511,8 @@ async def build_node(config: Config) -> Node:
                 metrics.labels(metrics.plane_wave_sets_short).inc(
                     max(0, s.sets_expected - s.sets_seen)
                 )
+            if s.window_closed_short:
+                metrics.labels(metrics.plane_windows_closed_short).inc()
             metrics.labels(metrics.plane_flush_seconds).observe(
                 s.flush_seconds
             )
@@ -877,6 +884,9 @@ async def build_node(config: Config) -> Node:
         # round changes are the consensus-stall signature a post-mortem
         # looks for first
         qbft_consensus.on_round_change = flightrec_mod.consensus_hook(flight)
+    # who sends partial-signature sets, learned from the last wave: the
+    # node's two submitters of sets hint the plane from ONE roster
+    roster = WaveRoster(pubshares_by_idx)
     vapi = ValidatorAPI(
         share_idx=share_idx,
         pubshares=pubshares_by_idx[share_idx],
@@ -884,7 +894,7 @@ async def build_node(config: Config) -> Node:
         slots_per_epoch=config.slots_per_epoch,
         plane=tenant_plane,
         tracer=node_tracer,
-        operators=len(pubshares_by_idx),
+        roster=roster,
     )
     verifier = Eth2Verifier(
         fork,
@@ -892,6 +902,7 @@ async def build_node(config: Config) -> Node:
         config.slots_per_epoch,
         plane=tenant_plane,
         clock=clock if tenant_plane else None,
+        roster=roster,
     )
     parsigex = ParSigEx(
         share_idx,

@@ -541,10 +541,13 @@ def plane_span_bridge(
         # the server
         window_span = stats.window_span
         # which queues the window held, and how short its verify waves
-        # came (sets the submitters expected against sets seen; absent
-        # where the window held no verify wave or a job without a
-        # hint): a `timer` close with sets_seen < sets_expected is
-        # operators down, not unhinted traffic
+        # came (sets the submitters expected — the cluster's n — against
+        # sets seen, and the sets the window awaited before it would
+        # close `complete`; absent where the window held no verify wave
+        # or a job without a hint): sets_seen < sets_expected is
+        # operators down, not unhinted traffic — on a `timer` close
+        # their first slot out (still awaited), on a `complete` one
+        # with sets_awaited < sets_expected every slot after it
         window_attrs = {
             "verify_jobs": stats.verify_jobs,
             "recombine_jobs": stats.recombine_jobs,
@@ -552,6 +555,7 @@ def plane_span_bridge(
         if stats.sets_expected is not None:
             window_attrs["sets_expected"] = stats.sets_expected
             window_attrs["sets_seen"] = stats.sets_seen
+            window_attrs["sets_awaited"] = stats.sets_awaited
         for i, (trace_id, parent_id) in enumerate(parents):
             # one flush -> one record per submitting span: mark the
             # copies beyond the first so metric hooks (span_metrics)

@@ -50,25 +50,45 @@ What closes a window (`FlushStats.window_closed_by`, the
 `cryptoplane.window` span's `closed_by`, `SlotCoalescer.windows_closed`):
 
   * "complete" — the wave is whole. A submission may say which waves it
-    belongs to and how many submissions each expects (`wave=((key,
-    expected), ...)`: a duty's partial-signature set is one of n, its
-    recombine job one of 1). The window keeps a ledger of the jobs it
-    holds per (kind, key); as soon as every wave seen in it has
-    `seen >= expected`, every job in it carried a hint and no
-    submission is still decoding, it closes at once: nothing more can
-    come, so waiting buys nothing. Never on fewer than `expected`: the
-    stragglers would flush alone on another bucket.
-  * "timer" — the window ran its length: a wave came short (a peer's set
-    missing or late) or a job carried no hint (the remote client, a
-    quarantine coalescer, tools): exactly the behaviour before hints.
-    Such a close DOES feed the window controller below, and a short
-    wave of two or more sets counts there as load: a cluster with
-    operators down (fewer sets than the n its submitters expect, every
-    duty) waits out every verify window AND grows it x1.5 a wave toward
-    `window_max`, though no further set can come; its recombine
-    windows (one job expected, one seen) still close "complete" and
-    feed it nothing, so nothing decays it either. How short the wave
-    was is on the flush (`FlushStats.sets_expected` / `.sets_seen`, the
+    belongs to and what each waits for (`wave=((key, expected), ...)`).
+    `expected` is a count of jobs (a duty's recombine job is one of 1),
+    or it NAMES SENDERS (core/parsigex.WaveSet: a duty's
+    partial-signature set says whose it is, `sender`; whose sets its
+    wave waits for, `awaited`, always with the sender itself; and the
+    cluster's operators, `n`). The submitters of one node take
+    `awaited` from one roster (core/parsigex.WaveRoster): the operators
+    whose set of the newest earlier slot of that duty type reached the
+    verifier, all n before any has. The window keeps a ledger per
+    (kind, key): jobs seen, the largest count hinted, the senders
+    present and the UNION of the senders awaited. A wave is whole when
+    it holds the jobs counted and a job of every awaited sender; the
+    set of a sender that was not awaited (an operator back from an
+    outage) rides along in the same flush if it is already there. As
+    soon as every wave seen in the window is whole, every job in it
+    carried a hint and no submission is still decoding, it closes at
+    once: nothing more is waited for, so waiting buys nothing. Never
+    on fewer than it awaits: the stragglers would flush alone on
+    another bucket. `sets_expected` stays the static n whatever was
+    awaited, so `sets_expected - sets_seen` still tells the outage;
+    `sets_awaited` beside it (`FlushStats`, the span) is what the
+    window waited for, and a window that closed "complete" on fewer
+    than n counts in `SlotCoalescer.windows_closed_short`
+    (`tpu_plane_windows_closed_short_total`).
+  * "timer" — the window ran its length: an AWAITED sender's set is
+    missing or late (the first slot of an operator's outage: it sent
+    last slot, so it is waited for), or a job carried no hint (the
+    remote client, a quarantine coalescer, tools): exactly the
+    behaviour before hints. Such a close DOES feed the window
+    controller below, and a short wave of two or more sets counts
+    there as load, so the window after the first slot of an outage is
+    x1.5 longer (toward `window_max`); from the second slot on the
+    silent operators are not awaited, the verify windows close
+    "complete" as the recombine windows do (one job expected, one
+    seen) and feed the controller nothing, so nothing decays it
+    either. A set that trails its wave's close flushes alone in the
+    next window, on its timer, as a set later than the timer always
+    did. How short the wave was is on the flush
+    (`FlushStats.sets_expected` / `.sets_seen`, the
     `cryptoplane.window` span, `tpu_plane_wave_sets_short_total`):
     that tells a degraded cluster from unhinted traffic.
   * "deadline" / "pulled_earlier" — a submission carrying a duty
@@ -97,7 +117,7 @@ from __future__ import annotations
 
 import asyncio
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import NamedTuple, Sequence
 
 from charon_tpu.crypto import g1g2
@@ -199,13 +219,19 @@ class FlushStats:
     window_closed_by: str = ""
     # the two queues apart (`jobs` is their sum: one window can hold
     # both), and the window's wave ledger summed over the verify waves
-    # it held: partial-signature sets its submitters expected, and sets
-    # that came. None where the window held no verify wave or a job in
-    # it carried no hint (the ledger is then not the whole story)
+    # it held: partial-signature sets its submitters expected (the
+    # cluster's n, whoever is silent), sets that came, and sets the
+    # window AWAITED before it would close "complete" (fewer than
+    # expected where the roster says operators sent nothing last slot).
+    # None where the window held no verify wave or a job in it carried
+    # no hint (the ledger is then not the whole story)
     verify_jobs: int = 0
     recombine_jobs: int = 0
     sets_expected: int | None = None
     sets_seen: int | None = None
+    sets_awaited: int | None = None
+    # closed "complete" with sets_awaited < sets_expected
+    window_closed_short: bool = False
     # (trace_id, span_id) captured from each submission's active span
     parents: tuple[tuple[str, str], ...] = ()
     # live lanes per submitting tenant (ISSUE 8): (tenant_id, lanes)
@@ -224,6 +250,51 @@ class _Window(NamedTuple):
     closed_by: str = ""
     sets_expected: int | None = None  # FlushStats, same names
     sets_seen: int | None = None
+    sets_awaited: int | None = None
+
+    @property
+    def closed_short(self) -> bool:
+        """Whole on fewer sets than the cluster has operators."""
+        return (
+            self.closed_by == "complete"
+            and self.sets_awaited is not None
+            and self.sets_awaited < self.sets_expected
+        )
+
+
+@dataclass
+class _Wave:
+    """One (kind, key) of the armed window's ledger (module docstring
+    "What closes a window")."""
+
+    seen: int = 0  # jobs in the window
+    expected: int = 0  # jobs its submitters expect at most: a count, or n
+    counted: int = 0  # the largest plain count hinted
+    present: set = field(default_factory=set)  # senders whose job is in
+    awaited: set = field(default_factory=set)  # union over the hints
+
+    def enter(self, expected) -> None:
+        """One more job, hinted `expected`: a count, or naming senders
+        (`sender`, `awaited`, `n`: core/parsigex.WaveSet). Hints that
+        disagree keep the larger count and the union of the awaited: a
+        window must never close on less than any submitter awaits."""
+        self.seen += 1
+        if isinstance(expected, int):
+            self.counted = max(self.counted, expected)
+            self.expected = max(self.expected, expected)
+        else:
+            self.present.add(expected.sender)
+            self.awaited |= expected.awaited
+            self.expected = max(self.expected, expected.n)
+
+    @property
+    def awaits(self) -> int:
+        """Jobs the window waits for before this wave is whole."""
+        return max(self.counted, len(self.awaited))
+
+    @property
+    def whole(self) -> bool:
+        return self.seen >= self.counted and self.awaited <= self.present
 
 
 class PlaneConfigError(ValueError):
@@ -346,14 +417,14 @@ class SlotCoalescer:
     window_min. The wait ends early when the window's waves are whole
     (`wave=` on verify / recombine; module docstring "What closes a
     window"): such a close leaves the controller as it was. Without
-    hints, or a set short, the timer closes it as before.
+    hints, or an awaited set short, the timer closes it as before.
     decode_workers: decode/pack pool size; 0 disables the pipeline
     entirely (decode runs synchronously on the caller — the pre-pipeline
     path, kept for A/B benching). The pool is created lazily on first
     use, so an idle or disabled plane owns no threads.
     flushes / coalesced_flushes / lanes_flushed / windows_closed (by
-    cause): observability counters (exported as node metrics by
-    app/run.py).
+    cause) / windows_closed_short: observability counters (exported as
+    node metrics by app/run.py).
     """
 
     # submitters may pass `wave=` (TenantPlane says the same; the remote
@@ -425,9 +496,9 @@ class SlotCoalescer:
         self._wall_offset = 0.0  # wall->monotonic, snapshotted per window
         # submissions mid-decode (closing windows wait for these)
         self._decode_tickets: set[asyncio.Future] = set()
-        # the armed window's wave ledger: (kind, key) -> [jobs seen,
-        # jobs expected], and how many of its jobs carried no hint
-        self._waves: dict[tuple, list[int]] = {}
+        # the armed window's wave ledger by (kind, key), and how many
+        # of its jobs carried no hint
+        self._waves: dict[tuple, _Wave] = {}
         self._unhinted_jobs = 0
         self._window_current = window
         # first-dispatch gate (app/run.py wires the autotune tune_done
@@ -450,6 +521,9 @@ class SlotCoalescer:
         self._decode_pool: concurrent.futures.ThreadPoolExecutor | None = None
         self.flushes = 0
         self.windows_closed: dict[str, int] = {}  # by `closed_by` cause
+        # of the "complete" ones: verify windows whole on fewer sets
+        # than the cluster has operators (the roster awaited fewer)
+        self.windows_closed_short = 0
         self.coalesced_flushes = 0  # flushes that merged >= 2 jobs
         self.lanes_flushed = 0
         self.host_fallback_flushes = 0  # served by the python-spec rung
@@ -614,10 +688,12 @@ class SlotCoalescer:
         — pulls the flush earlier when the window would overshoot it.
         tenant: optional tenant id (core/cryptosvc) for per-flush
         attribution in FlushStats/metrics/span attrs.
-        wave: optional ((key, expected), ...) — this job is one of
-        `expected` verify jobs of wave `key` (a duty's partial-signature
-        set is one of n); a window whose waves are all whole closes
-        without waiting out its timer (module docstring)."""
+        wave: optional ((key, expected), ...) — this job belongs to the
+        verify wave `key`, which waits for `expected`: a count of jobs,
+        or named senders (a duty's partial-signature set says whose it
+        is and whose sets its wave awaits: core/parsigex.WaveSet); a
+        window whose waves are all whole closes without waiting out its
+        timer (module docstring)."""
         if not items:
             return []
         loop = asyncio.get_running_loop()
@@ -755,42 +831,39 @@ class SlotCoalescer:
     # -- flush machinery ---------------------------------------------------
 
     def _count_wave(self, kind: str, wave) -> None:
-        """Enter the job just appended into the window's wave ledger.
-        Hints that disagree on a wave's size keep the larger: a window
-        must never close on fewer jobs than any submitter expects."""
+        """Enter the job just appended into the window's wave ledger."""
         if not wave:
             self._unhinted_jobs += 1
             return
         for key, expected in wave:
-            entry = self._waves.setdefault((kind, key), [0, 0])
-            entry[0] += 1
-            entry[1] = max(entry[1], expected)
+            self._waves.setdefault((kind, key), _Wave()).enter(expected)
 
-    def _verify_sets(self) -> tuple[int | None, int | None]:
+    def _verify_sets(self) -> tuple[int | None, int | None, int | None]:
         """The armed window's ledger summed over its verify waves:
-        (sets expected, sets seen) — (None, None) where it held no
-        verify wave or a job without a hint."""
+        (sets expected, sets seen, sets awaited) — Nones where it held
+        no verify wave or a job without a hint."""
         waves = [
-            entry
-            for (kind, _key), entry in self._waves.items()
+            wave
+            for (kind, _key), wave in self._waves.items()
             if kind == "verify"
         ]
         if not waves or self._unhinted_jobs:
-            return None, None
+            return None, None, None
         return (
-            sum(expected for _seen, expected in waves),
-            sum(seen for seen, _expected in waves),
+            sum(w.expected for w in waves),
+            sum(w.seen for w in waves),
+            sum(w.awaits for w in waves),
         )
 
     def _window_whole(self) -> bool:
-        """Nothing more can join the armed window: every job in it said
-        which wave it belongs to, every such wave has all the jobs it
-        expects, and no submission is still decoding."""
+        """Nothing more is waited for in the armed window: every job in
+        it said which wave it belongs to, every such wave holds what it
+        awaits, and no submission is still decoding."""
         return bool(
             self._waves
             and not self._unhinted_jobs
             and not self._decode_tickets
-            and all(seen >= expected for seen, expected in self._waves.values())
+            and all(w.whole for w in self._waves.values())
         )
 
     def _close_if_whole(self) -> None:
@@ -884,7 +957,7 @@ class SlotCoalescer:
         # decode/pack stages overlap this flush's device stage
         self._flush_task = None
         self._queue_deadline = None
-        sets_expected, sets_seen = self._verify_sets()
+        sets = self._verify_sets()
         self._waves = {}
         self._unhinted_jobs = 0
         if not vq and not rq:
@@ -898,13 +971,11 @@ class SlotCoalescer:
                     job.fut.set_exception(TblsError("crypto plane closed"))
             return
         window_used = _Window(
-            self._window_current,
-            window_span,
-            closed_by,
-            sets_expected,
-            sets_seen,
+            self._window_current, window_span, closed_by, *sets
         )
         self.windows_closed[closed_by] = self.windows_closed.get(closed_by, 0) + 1
+        if window_used.closed_short:
+            self.windows_closed_short += 1
         if closed_by != "complete":
             # a wave that came whole is no evidence that waiting longer
             # catches more (nor that traffic thinned): controller untouched
@@ -1264,6 +1335,8 @@ class SlotCoalescer:
                 recombine_jobs=len(rq),
                 sets_expected=window_used.sets_expected,
                 sets_seen=window_used.sets_seen,
+                sets_awaited=window_used.sets_awaited,
+                window_closed_short=window_used.closed_short,
                 parents=self._job_parents(vq, rq),
                 tenant_lanes=self._job_tenant_lanes(vq, rq),
             ),

@@ -181,8 +181,10 @@ class _Entry:
     ctx: contextvars.Context
     parent: tuple[str, str] | None  # submitter's (trace_id, span_id)
     admitted: float  # wall clock: into the tenant's queue
-    # the submitter's wave hint, keys namespaced by tenant: one tenant's
-    # jobs never make another tenant's wave whole (core/cryptoplane
+    # the submitter's wave hint, keys namespaced by tenant and what each
+    # wave waits for (a count, or named senders) passed on as it came:
+    # one tenant's jobs never make another tenant's wave whole, and share
+    # indices are a tenant's own (core/cryptoplane
     # "What closes a window")
     wave: tuple | None = None
 
@@ -391,7 +393,7 @@ class CryptoPlaneService:
             parent=parent,
             admitted=admitted,
             wave=(
-                tuple(((tenant_id, key), n) for key, n in wave)
+                tuple(((tenant_id, key), awaits) for key, awaits in wave)
                 if wave
                 else None
             ),

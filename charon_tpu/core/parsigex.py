@@ -13,7 +13,7 @@ from __future__ import annotations
 import asyncio
 import time
 from collections import defaultdict
-from typing import Awaitable, Callable
+from typing import Awaitable, Callable, Iterable, NamedTuple
 
 from charon_tpu import tbls
 from charon_tpu.core.cryptosvc import PlaneOverloadError
@@ -78,6 +78,55 @@ class DutyGater:
         return duty.slot >= current - LATE_FACTOR
 
 
+class WaveSet(NamedTuple):
+    """What the submitter of a partial-signature set tells the crypto
+    plane of the set's wave (the `expected` of a `wave=((key, expected),
+    ...)` hint; core/cryptoplane "What closes a window")."""
+
+    sender: int  # share index of the operator whose set this is
+    awaited: frozenset[int]  # senders the wave waits for, `sender` among them
+    n: int  # the cluster's operators: the sets a whole cluster sends
+
+
+class WaveRoster:
+    """Who sends partial-signature sets: per duty type, the share indices
+    whose set for the NEWEST EARLIER slot of that type reached this
+    node's verifiers — the peers' through ParSigEx, the node's own VC's
+    through the ValidatorAPI under the node's own index, verified or
+    not. Before any wave of a type: all n. One object per node, shared
+    by its submitters, so that their hints agree.
+
+    An operator whose validator client is down sends nothing though its
+    node votes in QBFT and answers pings, so liveness cannot tell; its
+    traffic can. It is not awaited from the slot after the first it
+    missed, and awaited again from the slot after the first it sent:
+    one slot of memory, nothing to tune."""
+
+    def __init__(self, operators: Iterable[int]) -> None:
+        # the cluster's share indices, read on every hint: the node's
+        # live pubshare registry grows in place when an operator joins
+        self._operators = operators
+        # duty type -> (newest slot seen, its senders so far, the senders
+        # of the newest slot before it)
+        self._types: dict[DutyType, tuple[int, set, frozenset]] = {}
+
+    def hint(self, duty: Duty, sender: int) -> WaveSet:
+        """`sender`'s set for `duty` has reached a verifier: enter it,
+        and say what its wave waits for."""
+        everyone = frozenset(self._operators)
+        slot, senders, before = self._types.get(
+            duty.type, (duty.slot, set(), everyone)
+        )
+        if duty.slot < slot:
+            # a set of an older slot: what came before it is forgotten
+            return WaveSet(sender, everyone | {sender}, len(everyone))
+        if duty.slot > slot:
+            slot, senders, before = duty.slot, set(), frozenset(senders)
+        senders.add(sender)
+        self._types[duty.type] = (slot, senders, before)
+        return WaveSet(sender, before | {sender}, len(everyone))
+
+
 class Eth2Verifier:
     """Verifies peer partial signatures against the sender's pubshares,
     batched (ref: core/parsigex/parsigex.go:146-170 NewEth2Verifier)."""
@@ -89,12 +138,16 @@ class Eth2Verifier:
         slots_per_epoch: int = 32,
         plane: object | None = None,  # core.cryptoplane.SlotCoalescer
         clock: SlotClock | None = None,  # duty deadlines for the plane
+        roster: WaveRoster | None = None,  # the node's; None = its own
     ) -> None:
         self.fork = fork
         self.pubshares_by_idx = pubshares_by_idx
         self.slots_per_epoch = slots_per_epoch
         self.plane = plane
         self.clock = clock
+        self.roster = (
+            roster if roster is not None else WaveRoster(pubshares_by_idx)
+        )
 
     def _items(self, duty: Duty, signed_set: dict[PubKey, ParSignedData]):
         items = []
@@ -133,15 +186,18 @@ class Eth2Verifier:
             # near-deadline sets shrink the coalescing window instead of
             # waiting out a load-grown one (core/cryptoplane adaptive)
             kwargs["deadline"] = self.clock.duty_deadline(duty)
-        if getattr(self.plane, "wave_hints", False):
-            # this set is one of n for its duty and validators (n - 1
-            # peers' and the node's own VC's): the window closes when
-            # the wave is whole instead of waiting out its timer
-            # (core/cryptoplane). Sets cut differently by their senders
-            # share no key and fall to the timer.
-            kwargs["wave"] = (
-                ((duty, frozenset(signed_set)), len(self.pubshares_by_idx)),
-            )
+        if items and getattr(self.plane, "wave_hints", False):
+            # this set is one of its wave (the peers' and the node's
+            # own VC's for this duty and these validators): the window
+            # closes when the sets the roster awaits are in instead of
+            # waiting out its timer (core/cryptoplane). Sets cut
+            # differently by their senders share no key and fall to the
+            # timer.
+            sender = next(iter(signed_set.values())).share_idx
+            kwargs["wave"] = ((
+                (duty, frozenset(signed_set)),
+                self.roster.hint(duty, sender),
+            ),)
         try:
             return all(await self.plane.verify(items, **kwargs))
         except PlaneOverloadError:

@@ -46,11 +46,13 @@ class ValidatorAPI:
     # from concurrent VC submissions merge into one sharded device program
     plane: object | None = None
     tracer: object | None = None  # app/tracer.Tracer; None = process-global
-    # cluster size n: the VC's set of a duty is one of the n sets the
+    # core/parsigex.WaveRoster, the ONE the node's ParSigEx verifier
+    # holds: the VC's set of a duty is this node's set of the wave the
     # plane verifies for it (the peers' come through ParSigEx), which a
-    # submission tells the plane so that the coalescing window closes
-    # when the wave is whole; 0 = unknown, no hint (core/cryptoplane)
-    operators: int = 0
+    # submission tells the plane, with the senders the roster awaits, so
+    # that the coalescing window closes when the wave is whole; None =
+    # no hint (core/cryptoplane)
+    roster: object | None = None
 
     def __post_init__(self) -> None:
         self._subs: list = []
@@ -273,17 +275,22 @@ class ValidatorAPI:
         (ref: validatorapi.go:1213 one herumi call per signature). With a
         crypto plane installed, concurrent submissions coalesce into one
         sharded device program. `sets`: (duty, its validators in this
-        request) per duty the request spans — for each, this batch is one
-        of the `operators` sets of that wave (the peers' come through
-        ParSigEx under the same key)."""
+        request) per duty the request spans — for each, this batch is
+        this node's set of that wave (the peers' come through ParSigEx
+        under the same key)."""
         if self.plane is not None:
             import asyncio
 
             from charon_tpu.core.cryptosvc import PlaneOverloadError
 
             kwargs = {}
-            if self.operators and getattr(self.plane, "wave_hints", False):
-                kwargs["wave"] = tuple((key, self.operators) for key in sets)
+            if self.roster is not None and getattr(
+                self.plane, "wave_hints", False
+            ):
+                kwargs["wave"] = tuple(
+                    (key, self.roster.hint(key[0], self.share_idx))
+                    for key in sets
+                )
             try:
                 ok = await self.plane.verify(items, **kwargs)
             except PlaneOverloadError:

@@ -678,8 +678,8 @@ def test_vc_submission_and_peer_sets_make_one_wave(clock):
     share, sig = g1g2.g1_to_bytes(g1g2.G1_GEN), g1g2.g2_to_bytes(g1g2.G2_GEN)
     n = 4
     pubshares_by_idx = {i: {pk: share} for i in range(1, n + 1)}
-    vapi = ValidatorAPI(1, pubshares_by_idx[1], FORK, plane=plane, operators=n)
     verifier = Eth2Verifier(FORK, pubshares_by_idx, plane=plane)
+    vapi = ValidatorAPI(1, pubshares_by_idx[1], FORK, plane=plane, roster=verifier.roster)
     duty = Duty(64, DutyType.RANDAO)
 
     def peer_set(idx):
