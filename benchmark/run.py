@@ -37,7 +37,7 @@ COMPILING_TOTAL = 1150.0
 PHASE = {
     "cluster": 60, "node": 60, "programs_warm": 240, "programs_compile": 900,
     "peers": 30, "warmup": 120, "align": 20, "drain": 15, "teardown": 30,
-    "reference": 60, "trace_stop": 150,
+    "reference": 60, "trace_stop": 275,
 }
 
 
@@ -115,7 +115,7 @@ def emit(**obj) -> None:
     print(json.dumps(obj, sort_keys=True), flush=True)
 
 
-async def serve(args, cell, plan, wd, jax, cache, cache_log, events, dev, root,
+async def serve(args, cell, plan, wd, jax, cache, cache_log, events, dev,
                 rehearsal: Rehearsal) -> dict:
     from benchmark import check, serve as servelib, tracered
 
@@ -203,31 +203,31 @@ async def serve(args, cell, plan, wd, jax, cache, cache_log, events, dev, root,
     server.in_window = True
     requests_before = events.total_requests
     # --trace 1: the window's FIRST wave runs under the profiler, from its
-    # slot's start until the verify program has ended. Ending a trace takes
-    # 40 s for that one program's 0.87 million device events on an idle
-    # host, 57 s more for the recombine program's 1.28 million, and inside
-    # this process 108-133 s for the verify program alone (my chip runs,
-    # PR 25): a whole slot's trace cannot end inside a run's 360 s. It ends
-    # on a thread of its own while the window's other waves are served, the
-    # node is torn down and the reference is run (the later it starts, the
-    # later the run ends), and is awaited last.
+    # slot's start until the verify program has ended. The trace ends on a
+    # thread of its own while the window's other waves are served (what
+    # ending it costs, and why: README.md "--trace 1"), and is awaited last.
     trace_handle = trace_task = None
+
+    def end_trace():
+        nonlocal trace_task
+        trace_handle["stop_from"] = time.monotonic()
+        trace_task = asyncio.create_task(
+            asyncio.to_thread(tracered.stop, trace_handle, wd.note))
+
     for k, slot in enumerate(run.slots):
         end = start + (k + 1) * plan.slot_duration
         if args.trace and k == 0:
-            trace_handle = tracered.start(jax, root)
+            trace_handle = tracered.start(jax)
             run.traced_slot = slot
         with wd.phase(f"slot {k + 1}/{slots} (slot {slot})", plan.slot_duration + 3):
             while trace_handle is not None and trace_task is None and time.time() < end:
                 if any(f.startswith("verify") and t >= trace_handle["wall"]
                        for f, _s, _l, t in run.programs):
-                    trace_task = asyncio.create_task(
-                        asyncio.to_thread(tracered.stop, jax, trace_handle, wd.note))
+                    end_trace()
                 await asyncio.sleep(0.02)
             await asyncio.sleep(max(0.0, end - time.time()))
         if trace_handle is not None and trace_task is None:  # no verify flush came
-            trace_task = asyncio.create_task(
-                asyncio.to_thread(tracered.stop, jax, trace_handle, wd.note))
+            end_trace()
     with wd.phase("drain", PHASE["drain"]):
         # a duty not at the beacon by the end of its own slot has FAILED;
         # these seconds only tell a late aggregate (compared like any
@@ -259,8 +259,21 @@ async def serve(args, cell, plan, wd, jax, cache, cache_log, events, dev, root,
             counters["events"], rejected, expected_forged, compiles_in_window)
         reference_s = time.monotonic() - t0
     if trace_task is not None:
-        with wd.phase("trace_stop", PHASE["trace_stop"]):
+        # the budget is the stop's own, from the second it began: what is
+        # left of it here is what the run may still wait
+        left = trace_handle["stop_from"] + PHASE["trace_stop"] - time.monotonic()
+        with wd.phase("trace_stop", max(1.0, left)):
             run.trace = await trace_task
+    run_wall_s = time.time() - T_PROCESS
+    budget = {  # how near its budget the run came (README.md "--trace 1")
+        "run_wall_s": round(run_wall_s, 2), "window_opened_s": round(setup_s, 2),
+        "traced_tail_s": round(run_wall_s - setup_s, 2) if args.trace else None,
+        "stop_trace_s": getattr(run.trace, "stop_s", None),
+        "trace_events": getattr(run.trace, "events", None),
+        "trace_bytes": getattr(run.trace, "bytes", None),
+        "trace_planes": getattr(run.trace, "planes", None),
+    }
+    wd.note("budget: " + ", ".join(f"{k} {v}" for k, v in budget.items()))
     info = {
         "cell": cell.name, "seed": args.seed, "device": dev, "versions": vers,
         "routing": route, "cache_dir": str(cache), "cache_marker": warm,
@@ -297,6 +310,7 @@ async def serve(args, cell, plan, wd, jax, cache, cache_log, events, dev, root,
                         "rejected": rejected},
         "counters": counters["info"], "reference_seconds": round(reference_s, 3),
         "phases": {n: round(t, 2) for n, _s, t in wd.phases},
+        **budget,
         "patched": getattr(rehearsal.patch, "__name__", None),
     }
     return {"run": run, "checks": checks, "info": info}
@@ -386,7 +400,7 @@ def main(argv=None, root: Path = ROOT, exit_fn=os._exit,
 
     try:
         out = asyncio.run(serve(args, cell, plan, wd, jax, cache, cache_log, events, dev,
-                                root, rehearsal))
+                                rehearsal))
     except Exception as e:  # noqa: BLE001 — the boundary: report and fail
         import traceback
 

@@ -8,10 +8,13 @@ from __future__ import annotations
 import asyncio
 import json
 import shutil
+import time
 from pathlib import Path
 
 REPO = Path(__file__).resolve().parents[2]
 DATA_DIRS = ("configs", "mixes", "metrics", "readers")
+RECORDED = REPO / "benchmark/tests/data/tiny.xplane.pb"  # by record_trace.py, on a v5e
+RECORDED_WINDOW_S = 0.8027191162109375  # the session's start to its stop, in that recording
 
 REHEARSAL = {
     "name": "rehearsal",
@@ -45,6 +48,16 @@ def make_root(tmp: Path, rehearsal: bool = False) -> Path:
             m["workloads"] = m["workloads"] + ["rehearsal.attest-slot"]
     (tmp / "BENCHMARK.json").write_text(json.dumps(manifest))
     return tmp
+
+
+def fake_trace() -> None:
+    """The recorded trace stands in for the profiler, which has no device
+    plane on the CPU: a traced run then reads it as its own window's."""
+    from benchmark import tracered
+
+    tracered.start = lambda jax: {"wall": time.time()}
+    tracered.stop = lambda handle, note=None: tracered.reduce_file(
+        str(RECORDED), handle["wall"], RECORDED_WINDOW_S)
 
 
 # -- patches: each takes the run's Server once its node is built ------------

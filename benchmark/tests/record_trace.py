@@ -1,23 +1,27 @@
 #!/usr/bin/env python3
 """Record the small device trace the reducer's test reads
-(data/tiny.xplane.pb beside this file): two named jits, a few calls each, with idle
-gaps between them. Run on the chip; prints the trace's structure."""
+(data/tiny.xplane.pb beside this file): two named jits, three calls each, with idle
+gaps between them and a longer one at the end, under the options and through the session of
+benchmark/tracered.py, as a traced run records its window. Run on the chip;
+prints the trace's structure and what ending it took."""
 
 from __future__ import annotations
 
-import glob
 import json
-import os
-import shutil
 import sys
 import time
 from pathlib import Path
+
+REPO = Path(__file__).resolve().parents[2]
+sys.path.insert(0, str(REPO))
 
 
 def main() -> int:
     out = Path(sys.argv[1] if len(sys.argv) > 1 else "chiprun_out/tiny_trace")
     import jax
     import jax.numpy as jnp
+
+    from benchmark import tracered
 
     def verify_like(x):
         return jnp.tanh(x @ x).sum()
@@ -30,33 +34,26 @@ def main() -> int:
     x = jnp.ones((512, 512), jnp.float32)
     f(x).block_until_ready()
     g(x).block_until_ready()
-    tmp = out / "raw"
-    shutil.rmtree(out, ignore_errors=True)
-    tmp.mkdir(parents=True)
-    opts = jax.profiler.ProfileOptions()
-    opts.python_tracer_level = 0
-    opts.host_tracer_level = 1
-    t_start = time.time_ns()
-    jax.profiler.start_trace(str(tmp), profiler_options=opts)
+    out.mkdir(parents=True, exist_ok=True)
+    handle = tracered.start(jax)
     marks = []
-    for i in range(3):
-        t0 = time.time_ns()
-        with jax.profiler.TraceAnnotation("bench_span", i=i):
-            f(x).block_until_ready()
-        t1 = time.time_ns()
+    for _ in range(3):
+        t0 = time.time()
+        f(x).block_until_ready()
+        t1 = time.time()
         time.sleep(0.05)
         g(x).block_until_ready()
-        t2 = time.time_ns()
-        marks.append((t0, t1, t2))
+        t2 = time.time()
+        marks.append([t - handle["wall"] for t in (t0, t1, t2)])
         time.sleep(0.1)
-    jax.profiler.stop_trace()
-    t_stop = time.time_ns()
-    pb = glob.glob(str(tmp / "plugins/profile/*/*.xplane.pb"))[0]
-    shutil.copyfile(pb, out / "tiny.xplane.pb")
-    shutil.rmtree(tmp)
+    time.sleep(0.3)  # the longest idle gap is the one after the last call
+    stopped = time.time()
+    blob = tracered.stop_bytes(handle)
+    stop_s = time.time() - stopped
+    (out / "tiny.xplane.pb").write_bytes(blob)
     data = jax.profiler.ProfileData.from_file(str(out / "tiny.xplane.pb"))
-    info = {"bytes": os.path.getsize(out / "tiny.xplane.pb"), "t_start_ns": t_start,
-            "t_stop_ns": t_stop, "marks": marks, "planes": []}
+    info = {"bytes": len(blob), "window_s": stopped - handle["wall"], "stop_s": stop_s,
+            "marks_s": marks, "planes": []}
     for plane in data.planes:
         p = {"name": plane.name, "lines": []}
         for line in plane.lines:

@@ -17,7 +17,7 @@ sys.path.insert(0, str(REPO))
 
 
 def main(argv) -> int:
-    from benchmark import run, tracered
+    from benchmark import run
     from benchmark.tests import helpers
 
     argv, patch = list(argv), None
@@ -27,10 +27,7 @@ def main(argv) -> int:
         del argv[i:i + 2]
     if "--fake-trace" in argv:
         argv.remove("--fake-trace")
-        recorded = str(REPO / "benchmark/tests/data/tiny.xplane.pb")
-        tracered.start = lambda jax, root: {"wall": __import__("time").time()}
-        tracered.stop = lambda jax, handle, note=None: tracered.reduce_file(
-            recorded, handle["wall"], 0.786)
+        helpers.fake_trace()
     with tempfile.TemporaryDirectory(prefix="bench_rehearse_") as tmp:
         root = helpers.make_root(Path(tmp), rehearsal=True)
         args = ["--workload", "rehearsal.attest-slot", "--seed", "3000000007",

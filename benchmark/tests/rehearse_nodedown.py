@@ -16,7 +16,6 @@ from __future__ import annotations
 import json
 import sys
 import tempfile
-import time
 from pathlib import Path
 
 REPO = Path(__file__).resolve().parents[2]
@@ -50,8 +49,8 @@ def make_root(tmp: Path) -> Path:
 
 
 def main(argv) -> int:
-    from benchmark import run, tracered
-    from benchmark.tests import planepatch
+    from benchmark import run
+    from benchmark.tests import helpers, planepatch
     from charon_tpu.app import tracer
 
     class Hinted(planepatch.Checked):
@@ -76,10 +75,7 @@ def main(argv) -> int:
         planepatch.host_plane(server)
         built["run"] = server.run
 
-    recorded = str(REPO / "benchmark/tests/data/tiny.xplane.pb")
-    tracered.start = lambda jax, root: {"wall": time.time()}
-    tracered.stop = lambda jax, handle, note=None: tracered.reduce_file(
-        recorded, handle["wall"], 0.786)
+    helpers.fake_trace()
     with tempfile.TemporaryDirectory(prefix="bench_nodedown_") as tmp:
         args = ["--workload", CELL, "--seed", "3000000007", "--seconds", "6",
                 "--trace", "1", *argv]

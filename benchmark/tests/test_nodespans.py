@@ -19,6 +19,7 @@ REPO = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(REPO))
 
 from benchmark import manifest as M, nodespans, tracered  # noqa: E402
+from benchmark.tests import helpers  # noqa: E402
 from charon_tpu.app import tracer  # noqa: E402
 
 TRACE = "c" * 32
@@ -149,15 +150,18 @@ def test_an_idle_instant_gets_the_cause_nearest_the_device(node):
 def test_the_idle_causes_sum_to_window_minus_busy(node):
     run = Run()
     run.trace = tracered.reduce_file(
-        str(REPO / "benchmark/tests/data/tiny.xplane.pb"), T0 + 4.1, 0.786)
+        str(helpers.RECORDED), T0 + 4.1, helpers.RECORDED_WINDOW_S)
     total = nodespans.idle_seconds(run, "attester")
     assert set(total) == set(nodespans.ORDER + nodespans.NO_SPAN)
     assert sum(total.values()) == pytest.approx(run.trace.window_s - run.trace.busy_s, abs=1e-9)
-    # the 0.786 s from 4.1: consensus to 4.2, other to 4.25, waiting to 4.4, ...
+    # the recorded 0.7-0.9 s from 4.1: consensus to 4.2, other to 4.25, waiting
+    # to 4.4, ..., the window from 4.8 to 4.9 and the pack from there
+    w = helpers.RECORDED_WINDOW_S
+    assert 0.7 < w < 0.9
     assert total["consensus"] == pytest.approx(0.1, abs=2e-3)
     assert total["awaiting_input"] == pytest.approx(0.15, abs=2e-3)
-    assert total["pack"] == pytest.approx(0.15 + 0.1, abs=2e-3)
-    assert total["window"] == pytest.approx(0.03 + 0.1 + 0.086, abs=2e-3)
+    assert total["pack"] == pytest.approx(0.15 + 0.1 + max(0.0, w - 0.8), abs=2e-3)
+    assert total["window"] == pytest.approx(0.03 + 0.1 + min(w, 0.8) - 0.7, abs=2e-3)
     assert total["pre_trigger"] == 0.0
     for name in ("consensus", "awaiting_input", "entry", "window", "pack"):
         assert read(f"idle_s.{name}", run) == total[name]
@@ -183,10 +187,75 @@ def test_a_ring_that_is_not_one_whole_nodes_is_not_read(monkeypatch, node, state
     assert nodespans.node_spans() is None
     run = Run()
     run.trace = tracered.reduce_file(
-        str(REPO / "benchmark/tests/data/tiny.xplane.pb"), T0 + 4.1, 0.786)
+        str(helpers.RECORDED), T0 + 4.1, helpers.RECORDED_WINDOW_S)
     for name in ("entry_self_s", "qbft_decide_s", "svc_queue_s", "window_wait_s",
                  "agg_bcast_self_s", "idle_s.window"):
         assert read(name, run) is None
+
+
+def test_the_recorded_trace_keeps_the_structure_the_reducer_reads():
+    """tests/data/tiny.xplane.pb, as tests/record_trace.py records it on a
+    v5e through tracered's own session and options (PR 34): the device
+    plane with its `XLA Modules` and `XLA Ops` lines, modules named
+    jit_<function>(<id>), operations by their instruction text (which is the
+    program's own, not the HLO proto's: none rides along)."""
+    import dataclasses
+
+    from jax.profiler import ProfileData
+
+    blob = helpers.RECORDED.read_bytes()
+    planes = {p.name: p for p in ProfileData.from_serialized_xspace(blob).planes}
+    # libtpu names a `/host:metadata` plane whatever the options say; under
+    # these it is empty (with HLO protos the two jits' made the file 18,528 bytes)
+    assert len(blob) < 15000
+    assert [n for n in planes if n.startswith("/device:TPU")] == ["/device:TPU:0"]
+    lines = {ln.name: list(ln.events) for ln in planes["/device:TPU:0"].lines}
+    assert {tracered.MODULE_LINE, tracered.OPS_LINE} <= set(lines)
+    modules = sorted(lines[tracered.MODULE_LINE], key=lambda e: e.start_ns)
+    assert [e.name.split("(")[0] for e in modules] == [
+        "jit_verify_like", "jit_recombine_like"] * 3
+    assert all(e.name.split("(")[1].rstrip(")").isdigit() for e in modules)
+    ops = lines[tracered.OPS_LINE]
+    assert len(ops) == 12 and all(e.name.startswith("%") and " = " in e.name for e in ops)
+    assert all(e.duration_ns >= 0 and e.start_ns > 0 for e in ops + modules)
+    # every operation lies inside a module, and inside the recorded window
+    spans = [(m.start_ns, m.start_ns + m.duration_ns) for m in modules]
+    assert all(any(a <= e.start_ns and e.start_ns + e.duration_ns <= b + 1 for a, b in spans)
+               for e in ops)
+    assert spans[-1][1] * 1e-9 < helpers.RECORDED_WINDOW_S
+    # what a run reads from memory is what the file holds
+    read = tracered.reduce_bytes(blob, T0, helpers.RECORDED_WINDOW_S)
+    filed = tracered.reduce_file(str(helpers.RECORDED), T0, helpers.RECORDED_WINDOW_S)
+    assert dataclasses.asdict(read) == dataclasses.asdict(filed)
+    assert read.events == 12 and read.devices == 1 and read.stop_s is None
+    assert read.planes == {"/device:TPU:0": {  # every line that holds anything
+        tracered.MODULE_LINE: 6, tracered.OPS_LINE: 12, "Async XLA Ops": 3}}
+
+
+def test_a_session_of_the_harness_ends_in_bytes_and_leaves_no_file(tmp_path, monkeypatch):
+    """tracered.start / stop_bytes on the CPU: the profiler's own session
+    under the one set of options (python and host tracing off, no HLO
+    proto), ended into a serialised XSpace that holds no `/host:metadata`
+    plane and — there being no device here — nothing the reducer reads;
+    nothing is written under the working directory."""
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = tracered.options(jax)
+    assert (opts.python_tracer_level, opts.host_tracer_level, opts.enable_hlo_proto) == (
+        0, 0, False)
+    assert dict(opts.advanced_configuration) == {}
+    monkeypatch.chdir(tmp_path)
+    handle = tracered.start(jax)
+    assert handle["wall"] > 0
+    jax.numpy.ones((8, 8)).sum().block_until_ready()
+    blob = tracered.stop_bytes(handle)
+    assert isinstance(blob, bytes) and "session" not in handle
+    names = [p.name for p in ProfileData.from_serialized_xspace(blob).planes]
+    assert "/host:metadata" not in names and not any(n.startswith("/device:") for n in names)
+    with pytest.raises(RuntimeError, match="no device operation"):
+        tracered.reduce_bytes(blob, handle["wall"], 1.0)
+    assert list(tmp_path.iterdir()) == []
 
 
 def test_the_new_metrics_are_files_and_entries_like_the_old_ones():
@@ -195,7 +264,10 @@ def test_the_new_metrics_are_files_and_entries_like_the_old_ones():
     new = [m for m in man["per_layer"] if m["source"] in ("program_span", "device_trace")
            and (m["name"].startswith("idle_s.") or m["layer"] in ("Entry", "Tenant service")
                 or m["name"] in ("qbft_decide_s", "agg_bcast_self_s", "window_wait_s"))]
-    assert len(new) == 10 and man["per_layer"][-10:] == new  # appended, in one block
+    # appended in one block, in this order; what later PRs append comes after it
+    first = man["per_layer"].index(new[0])
+    assert len(new) == 10 and man["per_layer"][first:first + 10] == new
+    assert first >= 7  # after PR 25's seven
     cells = [w["name"] for w in man["workloads"]]
     for m in new:
         assert m["moves"] == "duty_p50_s" and m["workloads"] == cells

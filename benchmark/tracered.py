@@ -1,51 +1,61 @@
-"""From a profiler trace (.xplane.pb) to numbers: device busy seconds, the
-device-side duration of each XLA module, the operations that took most
-time, the longest idle gaps. Read with nothing but jax's ProfileData.
+"""From a profiler trace to numbers: device busy seconds, the device-side
+duration of each XLA module, the operations that took most time, the
+longest idle gaps. Read with nothing but jax's ProfileData.
 
-Event times in the file are nanoseconds from the start of the trace; the
-harness notes the wall clock at start_trace, so its own spans (wall clock)
-and the device's events share one axis."""
+Event times in the trace are nanoseconds from the start of the trace; the
+harness notes the wall clock when the session starts, so its own spans (wall
+clock) and the device's events share one axis.
+
+Ending a trace is the dearest thing a traced run does, and what
+`jax.profiler.stop_trace` adds to it nothing here reads (my chip runs,
+PR 34; README.md "--trace 1"): a `.xplane.pb` on disk and every event once
+more as `<host>.trace.json.gz`. So the session is the profiler's own
+(`jax._src.lib._profiler.ProfilerSession`, what `start_trace` wraps),
+stopped into bytes and read from memory: no file is written."""
 
 from __future__ import annotations
 
 import dataclasses
-import glob
-import os
-import shutil
 import time
-from pathlib import Path
 
 MODULE_LINE = "XLA Modules"
 OPS_LINE = "XLA Ops"
 
 
-def start(jax, root: Path) -> dict:
-    """Begin tracing into a directory of the checkout's cache (removed
-    again by stop); python and host tracing off: they slow the host that is
-    timed and lengthen the end of the trace."""
-    out = root / "benchmark" / ".cache" / "trace"
-    shutil.rmtree(out, ignore_errors=True)
-    out.mkdir(parents=True)
+def options(jax):
+    """The one builder of the profiler's options (tests/record_trace.py
+    records under them too): python and host tracing off — they slow the
+    host that is timed, and the reducer reads device planes alone; no HLO
+    proto — it rides along for every module compiled in the process, which
+    in a cell's compiling run is the pairing programs."""
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
-    opts.host_tracer_level = 0  # device planes are all the reducer reads
+    opts.host_tracer_level = 0
+    opts.enable_hlo_proto = False
+    return opts
+
+
+def start(jax) -> dict:
+    from jax._src.lib import _profiler
+
+    jax.devices()  # the backend is up before the session looks for its tracers
     wall = time.time()
-    jax.profiler.start_trace(str(out), profiler_options=opts)
-    return {"dir": out, "wall": wall}
+    return {"session": _profiler.ProfilerSession(options(jax)), "wall": wall}
 
 
-def stop(jax, handle: dict, note=lambda text: None) -> "TraceSummary":
+def stop_bytes(handle: dict) -> bytes:
+    """End the session: the serialised XSpace, an `.xplane.pb`'s bytes."""
+    return handle.pop("session").stop()
+
+
+def stop(handle: dict, note=lambda text: None) -> "TraceSummary":
     stopped = time.time()
-    jax.profiler.stop_trace()
-    note(f"trace: stop_trace took {time.time() - stopped:.1f} s")
-    files = glob.glob(str(handle["dir"] / "plugins" / "profile" / "*" / "*.xplane.pb"))
-    if not files:
-        raise RuntimeError(f"the profiler left no .xplane.pb under {handle['dir']}")
-    t0 = time.time()
-    summary = reduce_file(files[0], handle["wall"], stopped - handle["wall"])
-    note(f"trace: {os.path.getsize(files[0])} bytes, {summary.events} device events, "
-         f"reduced in {time.time() - t0:.1f} s")
-    shutil.rmtree(handle["dir"], ignore_errors=True)
+    blob = stop_bytes(handle)
+    ended = time.time()
+    summary = reduce_bytes(blob, handle["wall"], stopped - handle["wall"])
+    summary.stop_s, summary.bytes = ended - stopped, len(blob)
+    note(f"trace: ended in {summary.stop_s:.1f} s, {len(blob)} bytes, {summary.events} "
+         f"device events, reduced in {time.time() - ended:.1f} s")
     return summary
 
 
@@ -70,6 +80,9 @@ class TraceSummary:
     modules: list  # device 0: (name, start, seconds), in time order
     op_seconds: list  # device 0: (name, seconds, calls), most time first
     events: int
+    planes: dict  # {plane: {line: events}} of every line that holds any
+    stop_s: float | None = None  # what ending the session took; None: read from a file
+    bytes: int | None = None  # the serialised trace
 
     def idle_gaps(self) -> list[tuple[float, float]]:
         """Device 0's idle intervals inside the window, longest first."""
@@ -114,16 +127,28 @@ def short_name(hlo: str) -> str:
 def reduce_file(path: str, wall_start: float, window_s: float) -> TraceSummary:
     from jax.profiler import ProfileData
 
-    data = ProfileData.from_file(path)
-    per_device = []
+    return reduce_data(ProfileData.from_file(path), wall_start, window_s)
+
+
+def reduce_bytes(blob: bytes, wall_start: float, window_s: float) -> TraceSummary:
+    from jax.profiler import ProfileData
+
+    return reduce_data(ProfileData.from_serialized_xspace(blob), wall_start, window_s)
+
+
+def reduce_data(data, wall_start: float, window_s: float) -> TraceSummary:
+    per_device, planes = [], {}
     for plane in data.planes:
-        if not plane.name.startswith("/device:"):
-            continue
         lines = {line.name: line for line in plane.lines}
-        ops_line = lines.get(OPS_LINE) or lines.get(MODULE_LINE)
-        if ops_line is None:
-            continue
-        ops = [(e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9) for e in ops_line.events]
+        ops_line = None
+        if plane.name.startswith("/device:"):
+            ops_line = lines.get(OPS_LINE) or lines.get(MODULE_LINE)
+        ops = [] if ops_line is None else [
+            (e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9) for e in ops_line.events]
+        held = {name: len(ops) if line is ops_line else sum(1 for _ in line.events)
+                for name, line in lines.items()}
+        if any(held.values()):
+            planes[plane.name] = {name: n for name, n in held.items() if n}
         if not ops:
             continue
         mods = []
@@ -150,5 +175,5 @@ def reduce_file(path: str, wall_start: float, window_s: float) -> TraceSummary:
     return TraceSummary(
         wall_start=wall_start, window_s=window_s, devices=len(per_device),
         busy_s=busy_s, busy=busies[0], modules=mods0, op_seconds=op_seconds,
-        events=sum(len(ops) for _p, ops, _m in per_device),
+        events=sum(len(ops) for _p, ops, _m in per_device), planes=planes,
     )

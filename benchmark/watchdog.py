@@ -69,8 +69,21 @@ class Watchdog:
             except Exception:  # noqa: BLE001 — evidence only
                 traceback.print_exc(file=self.err)
         self.err.flush()
+        extra.setdefault("phase_seconds", self.phase_seconds())
         print(failing_line(reason, self.device, **extra), file=self.out, flush=True)
         self._exit(self.exit_code)
+
+    def phase_seconds(self) -> dict:
+        """What every finished phase took, the open one so far (`open:`
+        before its name) and the run as a whole (`run`): on a failing last
+        line they say WHICH second ran out."""
+        now = time.monotonic()
+        out = {name: round(took, 2) for name, _start, took in self.phases}
+        open_ = self._open
+        if open_ is not None:
+            out[f"open:{open_[0]}"] = round(now - open_[1], 2)
+        out["run"] = round(now - self.t0, 2)
+        return out
 
     def close(self) -> None:
         self._stop.set()
