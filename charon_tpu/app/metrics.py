@@ -173,6 +173,19 @@ class ClusterMetrics:
             "awaited): a steady rise of k a duty is k operators not "
             "signing",
         )
+        self.plane_flushes_attributed = counter(
+            "tpu_plane_flushes_attributed_total",
+            "Verify flushes whose RLC tier failed and whose lanes were "
+            "re-dispatched through the per-lane program: some lane was "
+            "well formed and did not verify (0 on an honest cluster; "
+            "one a duty while a peer sends forged partials)",
+        )
+        self.plane_lanes_invalid = counter(
+            "tpu_plane_lanes_invalid_total",
+            "Verify lanes the plane answered False, by either tier or "
+            "by the host's parse; the set holding one is dropped whole "
+            "and billed in byzantine_evidence_total{kind=parsig_invalid}",
+        )
         # pipelined host plane (ISSUE 3): per-flush latency/occupancy,
         # decode-pool queueing, bucket-padding waste, device-lane depth
         self.plane_flush_seconds = Histogram(

@@ -513,6 +513,12 @@ async def build_node(config: Config) -> Node:
                 )
             if s.window_closed_short:
                 metrics.labels(metrics.plane_windows_closed_short).inc()
+            if s.attributed:
+                metrics.labels(metrics.plane_flushes_attributed).inc()
+            if s.lanes_invalid:
+                metrics.labels(metrics.plane_lanes_invalid).inc(
+                    s.lanes_invalid
+                )
             metrics.labels(metrics.plane_flush_seconds).observe(
                 s.flush_seconds
             )

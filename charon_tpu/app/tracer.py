@@ -467,6 +467,10 @@ def plane_span_bridge(
     `programs()` names the compiled programs dispatched inside the
     flush's device stage ("family@bucket", from the plane profiler's
     samples); they ride on `cryptoplane.device` as its `programs` attr.
+    A flush whose RLC verify tier failed says `attributed` on
+    `cryptoplane.flush` and has one more span under its device stage,
+    `cryptoplane.attribute`: the per-lane program's dispatch, with the
+    lanes it was given and the lanes and sets it found invalid.
 
     A flush coalesces submissions from several spans of several duties;
     `stats.parents` carries each submission's captured span context, and
@@ -519,6 +523,9 @@ def plane_span_bridge(
             stages.append(
                 ("cryptoplane.device", *stats.device_span, device_attrs)
             )
+        # the per-lane verify dispatch of a flush whose RLC tier failed
+        # (a child of the device stage; absent on every healthy flush)
+        attribute = getattr(stats, "attribute_span", None)
         start = min((s for _, s, _, _ in stages), default=0.0)
         end = max((e for _, _, e, _ in stages), default=0.0)
         flush_attrs = {
@@ -527,6 +534,7 @@ def plane_span_bridge(
             "window": stats.window,
             "inflight": stats.inflight,
             "fallback": stats.fallback,
+            "attributed": getattr(stats, "attributed", False),
         }
         if stats.padded_lanes:
             flush_attrs["bucket"] = stats.padded_lanes
@@ -589,7 +597,7 @@ def plane_span_bridge(
                 **dup,
             )
             for name, s, e, attrs in stages:
-                record_span(
+                stage = record_span(
                     name,
                     trace_id,
                     flush.span_id,
@@ -599,6 +607,18 @@ def plane_span_bridge(
                     **attrs,
                     **dup,
                 )
+                if attribute is not None and name == "cryptoplane.device":
+                    record_span(
+                        "cryptoplane.attribute",
+                        trace_id,
+                        stage.span_id,
+                        *attribute,
+                        tracer=t,
+                        lanes=stats.attribute_lanes,
+                        lanes_invalid=stats.lanes_invalid,
+                        sets_invalid=stats.sets_invalid,
+                        **dup,
+                    )
         if inner_hook is not None:
             inner_hook(stats)
 

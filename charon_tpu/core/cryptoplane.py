@@ -232,6 +232,20 @@ class FlushStats:
     sets_awaited: int | None = None
     # closed "complete" with sets_awaited < sets_expected
     window_closed_short: bool = False
+    # the verify tiers: `attributed` where the RLC product over the
+    # flush's lanes failed and the plane re-dispatched them through its
+    # per-lane program (one pairing check a lane: `attribute_span` is
+    # that dispatch's wall-clock window, `attribute_lanes` what it was
+    # given). A lane that does not DECODE is answered by the RLC
+    # program's own mask and attributes nothing. `lanes_invalid` are the
+    # verify lanes of this flush answered False, by whichever tier or
+    # by the host's parse; `sets_invalid` the jobs (partial-signature
+    # sets) holding at least one: each is dropped whole by its submitter
+    attributed: bool = False
+    lanes_invalid: int = 0
+    sets_invalid: int = 0
+    attribute_span: tuple[float, float] | None = None
+    attribute_lanes: int = 0
     # (trace_id, span_id) captured from each submission's active span
     parents: tuple[tuple[str, str], ...] = ()
     # live lanes per submitting tenant (ISSUE 8): (tenant_id, lanes)
@@ -239,6 +253,11 @@ class FlushStats:
     # attribution the tenant-labeled metric families and the span
     # bridge's tenant attrs are built from
     tenant_lanes: tuple[tuple[str, int], ...] = ()
+
+
+# the plane's per-lane verify programs (parallel/mesh `on_program`
+# families): one dispatched inside a flush says the RLC tier failed
+_ATTRIBUTION_FAMILIES = frozenset({"mesh/verify", "mesh/verify_dec"})
 
 
 class _Window(NamedTuple):
@@ -423,8 +442,8 @@ class SlotCoalescer:
     path, kept for A/B benching). The pool is created lazily on first
     use, so an idle or disabled plane owns no threads.
     flushes / coalesced_flushes / lanes_flushed / windows_closed (by
-    cause) / windows_closed_short: observability counters (exported as
-    node metrics by app/run.py).
+    cause) / windows_closed_short / flushes_attributed / lanes_invalid:
+    observability counters (exported as node metrics by app/run.py).
     """
 
     # submitters may pass `wave=` (TenantPlane says the same; the remote
@@ -459,7 +478,11 @@ class SlotCoalescer:
     ):
         import concurrent.futures
 
-        self.plane = plane
+        # per-lane verify dispatches of the flush now on the device
+        # lane: (wall-clock start, end, lanes), filled by _listen's hook
+        # and emptied as the next flush's verify stage begins
+        self._attributions: list[tuple[float, float, int]] = []
+        self.plane = self._listen(plane)
         self.window = window
         self.window_min = min(window_min, window)
         self.window_max = max(window_max, window)
@@ -526,6 +549,8 @@ class SlotCoalescer:
         self.windows_closed_short = 0
         self.coalesced_flushes = 0  # flushes that merged >= 2 jobs
         self.lanes_flushed = 0
+        self.flushes_attributed = 0  # fell to the per-lane verify tier
+        self.lanes_invalid = 0  # verify lanes answered False
         self.host_fallback_flushes = 0  # served by the python-spec rung
         self.pack_fallbacks = 0  # pack-stage failures (single-stage flush)
         self.pad_lanes_flushed = 0  # bucket-padding lanes shipped
@@ -544,6 +569,25 @@ class SlotCoalescer:
         self.warmup_hook = None
         self.warmups = 0
         self.warmup_lanes = 0
+
+    def _listen(self, plane):
+        """Stand in front of the plane's program hook (a plane that has
+        one: parallel/mesh `on_program`), so that a flush can say which
+        verify tier answered it; whoever held the hook is still called,
+        and whoever takes it later chains to this."""
+        if not hasattr(plane, "on_program"):
+            return plane
+        inner = plane.on_program
+
+        def hook(family: str, seconds: float, lanes: int) -> None:
+            if family in _ATTRIBUTION_FAMILIES:
+                end = time.time()  # lint: allow(monotonic-clock) — a span's wall clock
+                self._attributions.append((end - seconds, end, lanes))
+            if inner is not None:
+                inner(family, seconds, lanes)
+
+        plane.on_program = hook
+        return plane
 
     @property
     def t(self) -> int:
@@ -1223,6 +1267,7 @@ class SlotCoalescer:
         lanes = 0
         pad_lanes = padded_lanes = 0 if packed is not None else None
         vres: list[list[bool]] = []
+        self._attributions.clear()
         if vq:
             if vpack is not None:
                 # flat lane count came with the pack — don't re-flatten
@@ -1337,11 +1382,26 @@ class SlotCoalescer:
                 sets_seen=window_used.sets_seen,
                 sets_awaited=window_used.sets_awaited,
                 window_closed_short=window_used.closed_short,
+                **self._verify_verdicts(vres, self._attributions),
                 parents=self._job_parents(vq, rq),
                 tenant_lanes=self._job_tenant_lanes(vq, rq),
             ),
         )
         return vres, rres
+
+    @staticmethod
+    def _verify_verdicts(vres: list[list[bool]], ran=()) -> dict:
+        """FlushStats' verify-tier fields, from the verdicts a flush is
+        about to fan back to its jobs and the per-lane dispatches
+        (start, end, lanes) the plane reported while it made them."""
+        bad = [sum(1 for ok in job if not ok) for job in vres]
+        return {
+            "attributed": bool(ran),
+            "lanes_invalid": sum(bad),
+            "sets_invalid": sum(1 for b in bad if b),
+            "attribute_span": (ran[0][0], ran[-1][1]) if ran else None,
+            "attribute_lanes": sum(lanes for _s, _e, lanes in ran),
+        }
 
     @staticmethod
     def _packed_lane_count(arrays) -> int:
@@ -1438,6 +1498,8 @@ class SlotCoalescer:
     def _account_flush(self, vq, rq, lanes: int, stats: FlushStats) -> None:
         self.lanes_flushed += lanes
         self.flushes += 1
+        self.flushes_attributed += 1 if stats.attributed else 0
+        self.lanes_invalid += stats.lanes_invalid
         if stats.pad_lanes:
             self.pad_lanes_flushed += stats.pad_lanes
         if len(vq) + len(rq) >= 2:
@@ -1548,7 +1610,7 @@ class SlotCoalescer:
             # worker thread, NOT the event loop: the factory touches
             # jax.devices()/compilation, which blocks for minutes (a
             # pairing program is minutes of compile)
-            self.plane = self._plane_factory()
+            self.plane = self._listen(self._plane_factory())
             return self._run_device(vq, rq, None, window_used, inflight)
 
         try:
@@ -1781,6 +1843,7 @@ class SlotCoalescer:
                 device_span=(w0, time.time()),  # lint: allow(monotonic-clock)
                 verify_jobs=len(vq),
                 recombine_jobs=len(rq),
+                **self._verify_verdicts(vres),
                 parents=self._job_parents(vq, rq),
                 tenant_lanes=self._job_tenant_lanes(vq, rq),
             ),

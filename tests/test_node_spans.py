@@ -22,34 +22,11 @@ REPO = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(REPO))
 
 from benchmark.tests.test_nodespans import *  # noqa: E402,F401,F403 — the readers' tests
-from benchmark import manifest as M  # noqa: E402
 from charon_tpu.app import tracer  # noqa: E402
 
 NEW = ("entry_self_s", "qbft_decide_s", "agg_bcast_self_s", "svc_queue_s", "window_wait_s",
        "idle_s.consensus", "idle_s.awaiting_input", "idle_s.entry", "idle_s.window",
        "idle_s.pack")
-
-
-_readers_test = test_the_new_metrics_are_files_and_entries_like_the_old_ones  # noqa: F405
-
-
-def test_the_new_metrics_are_files_and_entries_like_the_old_ones(monkeypatch):  # noqa: F811
-    """The readers' test of this name, run as it stands on `per_layer` as
-    far as PR 26's ten reach. It holds them to be the LAST ten, and the
-    benchmark's contract has every later PR append its entries at the end
-    (ISSUE 28's two are there); that file is the benchmark's, for a
-    `benchmark` PR to repair. So: whatever follows the ten is a later PR's,
-    is valid and lists its own cells, and the list up to there passes the
-    readers' test unchanged."""
-    man = M.load_manifest(REPO)
-    assert M.validate(man) == []
-    end = [m["name"] for m in man["per_layer"]].index(NEW[-1]) + 1
-    cells = {w["name"] for w in man["workloads"]}
-    for later in man["per_layer"][end:]:
-        assert later["name"] not in NEW and set(later["workloads"]) <= cells
-    monkeypatch.setattr(M, "load_manifest",
-                        lambda root: {**man, "per_layer": man["per_layer"][:end]})
-    _readers_test()
 
 
 @pytest.fixture(scope="module")
