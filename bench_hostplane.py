@@ -107,7 +107,7 @@ class SimPlane:
     def pack_verify_inputs(self, pks, msgs, sigs):
         return ("v", np.empty(len(pks)))
 
-    def pack_verify_inputs_parsed(self, pks, msgs, parsed):
+    def pack_verify_inputs_parsed(self, pks, msgs, parsed, sets=None):
         return ("vp", np.empty(len(pks)))
 
     def make_lane_rand(self, n: int, rng=None):

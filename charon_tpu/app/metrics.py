@@ -175,10 +175,20 @@ class ClusterMetrics:
         )
         self.plane_flushes_attributed = counter(
             "tpu_plane_flushes_attributed_total",
-            "Verify flushes whose RLC tier failed and whose lanes were "
-            "re-dispatched through the per-lane program: some lane was "
-            "well formed and did not verify (0 on an honest cluster; "
-            "one a duty while a peer sends forged partials)",
+            "Verify flushes whose RLC tier failed in a segment holding "
+            "more than one set, and whose lanes were re-dispatched "
+            "through the per-lane program (0 on an honest cluster, and "
+            "while a flush holds no more sets than the RLC program has "
+            "segments: tpu_plane_flushes_set_resolved_total counts "
+            "those)",
+        )
+        self.plane_flushes_set_resolved = counter(
+            "tpu_plane_flushes_set_resolved_total",
+            "Verify flushes whose RLC tier refused at least one set and "
+            "answered for it whole, at no further dispatch: some lane "
+            "of the set was well formed and did not verify (0 on an "
+            "honest cluster; one a duty while a peer sends forged "
+            "partials)",
         )
         self.plane_lanes_invalid = counter(
             "tpu_plane_lanes_invalid_total",

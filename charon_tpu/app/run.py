@@ -515,6 +515,8 @@ async def build_node(config: Config) -> Node:
                 metrics.labels(metrics.plane_windows_closed_short).inc()
             if s.attributed:
                 metrics.labels(metrics.plane_flushes_attributed).inc()
+            if s.set_resolved:
+                metrics.labels(metrics.plane_flushes_set_resolved).inc()
             if s.lanes_invalid:
                 metrics.labels(metrics.plane_lanes_invalid).inc(
                     s.lanes_invalid

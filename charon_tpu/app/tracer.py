@@ -470,7 +470,9 @@ def plane_span_bridge(
     A flush whose RLC verify tier failed says `attributed` on
     `cryptoplane.flush` and has one more span under its device stage,
     `cryptoplane.attribute`: the per-lane program's dispatch, with the
-    lanes it was given and the lanes and sets it found invalid.
+    lanes it was given and the lanes and sets it found invalid. One
+    whose RLC tier refused a set and answered for it whole — no
+    per-lane dispatch, no such span — says `set_resolved`.
 
     A flush coalesces submissions from several spans of several duties;
     `stats.parents` carries each submission's captured span context, and
@@ -535,6 +537,7 @@ def plane_span_bridge(
             "inflight": stats.inflight,
             "fallback": stats.fallback,
             "attributed": getattr(stats, "attributed", False),
+            "set_resolved": getattr(stats, "set_resolved", False),
         }
         if stats.padded_lanes:
             flush_attrs["bucket"] = stats.padded_lanes

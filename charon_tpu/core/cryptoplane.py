@@ -237,11 +237,19 @@ class FlushStats:
     # per-lane program (one pairing check a lane: `attribute_span` is
     # that dispatch's wall-clock window, `attribute_lanes` what it was
     # given). A lane that does not DECODE is answered by the RLC
-    # program's own mask and attributes nothing. `lanes_invalid` are the
-    # verify lanes of this flush answered False, by whichever tier or
-    # by the host's parse; `sets_invalid` the jobs (partial-signature
-    # sets) holding at least one: each is dropped whole by its submitter
+    # program's own mask and attributes nothing. `set_resolved` where
+    # the RLC tier refused at least one set and NO per-lane dispatch
+    # followed: the parsed program takes its product per set (job), and
+    # a failing set alone in its segment is answered False whole — what
+    # its submitter does with it anyway — at no further dispatch.
+    # `lanes_invalid` are the verify lanes of this flush answered False
+    # or None, by whichever tier or by the host's parse (on a
+    # set-resolved flush every lane of the refused set, honest or not:
+    # None, not judged apart); `sets_invalid` the
+    # jobs (partial-signature sets) holding at least one: each is
+    # dropped whole by its submitter
     attributed: bool = False
+    set_resolved: bool = False
     lanes_invalid: int = 0
     sets_invalid: int = 0
     attribute_span: tuple[float, float] | None = None
@@ -442,7 +450,8 @@ class SlotCoalescer:
     path, kept for A/B benching). The pool is created lazily on first
     use, so an idle or disabled plane owns no threads.
     flushes / coalesced_flushes / lanes_flushed / windows_closed (by
-    cause) / windows_closed_short / flushes_attributed / lanes_invalid:
+    cause) / windows_closed_short / flushes_attributed /
+    flushes_set_resolved / lanes_invalid:
     observability counters (exported as node metrics by app/run.py).
     """
 
@@ -550,6 +559,8 @@ class SlotCoalescer:
         self.coalesced_flushes = 0  # flushes that merged >= 2 jobs
         self.lanes_flushed = 0
         self.flushes_attributed = 0  # fell to the per-lane verify tier
+        # the RLC tier refused a set and answered for it: no such fall
+        self.flushes_set_resolved = 0
         self.lanes_invalid = 0  # verify lanes answered False
         self.host_fallback_flushes = 0  # served by the python-spec rung
         self.pack_fallbacks = 0  # pack-stage failures (single-stage flush)
@@ -725,9 +736,15 @@ class SlotCoalescer:
         deadline: float | None = None,
         tenant: str | None = None,
         wave: Sequence[tuple[object, int]] | None = None,
-    ) -> list[bool]:
+    ) -> list[bool | None]:
         """Batch-verify (pubkey_bytes, signing_root, sig_bytes) lanes.
-        Returns per-lane validity; malformed encodings are False.
+        Returns per-lane validity; malformed encodings are False. One
+        call is one SET, accepted or dropped whole by its submitter:
+        where the plane's RLC tier judges per set (parsed lanes), the
+        lanes of a call holding a well-formed signature that does not
+        verify are None — falsy: refused with their set, the honest
+        ones beside the forged one too, and not judged apart
+        (FlushStats.set_resolved); False is a lane known bad.
         deadline: optional absolute wall-clock (time.time) duty deadline
         — pulls the flush earlier when the window would overshoot it.
         tenant: optional tenant id (core/cryptosvc) for per-flush
@@ -1151,6 +1168,18 @@ class SlotCoalescer:
     def _flat_verify_lanes(vq: list[_VerifyJob]) -> list:
         return [lane for job in vq for lane in job.lanes if lane is not None]
 
+    @staticmethod
+    def _flat_verify_sets(vq: list[_VerifyJob]) -> list[int]:
+        """Which job each of _flat_verify_lanes' lanes came from: a job
+        is one partial-signature set, accepted or dropped whole, and the
+        parsed verify program judges its lanes as one."""
+        return [
+            k
+            for k, job in enumerate(vq)
+            for lane in job.lanes
+            if lane is not None
+        ]
+
     def _normalize_jobs(self, vq, rq) -> bool:
         """One flush, one lane representation (worker thread). Returns
         True when the flush ships PARSED signature lanes to the
@@ -1219,17 +1248,13 @@ class SlotCoalescer:
         flat = self._flat_verify_lanes(vq)
         if flat:
             pks, msgs, sigs = zip(*flat)
-            pack = (
-                plane.pack_verify_inputs_parsed
-                if parsed
-                else plane.pack_verify_inputs
-            )
-            vpack = (
-                pack(pks, msgs, sigs),
-                plane.make_lane_rand(len(flat)),
-                len(flat),
-                parsed,
-            )
+            if parsed:
+                arrays = plane.pack_verify_inputs_parsed(
+                    pks, msgs, sigs, self._flat_verify_sets(vq)
+                )
+            else:
+                arrays = plane.pack_verify_inputs(pks, msgs, sigs)
+            vpack = (arrays, plane.make_lane_rand(len(flat)), len(flat), parsed)
         rpack = None
         ps, msg, sig, gpk, idx = self._live_recombine_rows(rq)
         if msg:
@@ -1288,7 +1313,7 @@ class SlotCoalescer:
                 if flat and parsed:
                     pks, msgs, sigs = zip(*flat)
                     arrays = self.plane.pack_verify_inputs_parsed(
-                        pks, msgs, sigs
+                        pks, msgs, sigs, self._flat_verify_sets(vq)
                     )
                     oks = iter(
                         self.plane.verify_packed_parsed(
@@ -1390,13 +1415,16 @@ class SlotCoalescer:
         return vres, rres
 
     @staticmethod
-    def _verify_verdicts(vres: list[list[bool]], ran=()) -> dict:
+    def _verify_verdicts(vres: list[list[bool | None]], ran=()) -> dict:
         """FlushStats' verify-tier fields, from the verdicts a flush is
-        about to fan back to its jobs and the per-lane dispatches
-        (start, end, lanes) the plane reported while it made them."""
+        about to fan back to its jobs (None: a lane refused with its
+        set by the RLC tier, which only a flush with no per-lane
+        dispatch answers) and the per-lane dispatches (start, end,
+        lanes) the plane reported while it made them."""
         bad = [sum(1 for ok in job if not ok) for job in vres]
         return {
             "attributed": bool(ran),
+            "set_resolved": any(ok is None for job in vres for ok in job),
             "lanes_invalid": sum(bad),
             "sets_invalid": sum(1 for b in bad if b),
             "attribute_span": (ran[0][0], ran[-1][1]) if ran else None,
@@ -1499,6 +1527,7 @@ class SlotCoalescer:
         self.lanes_flushed += lanes
         self.flushes += 1
         self.flushes_attributed += 1 if stats.attributed else 0
+        self.flushes_set_resolved += 1 if stats.set_resolved else 0
         self.lanes_invalid += stats.lanes_invalid
         if stats.pad_lanes:
             self.pad_lanes_flushed += stats.pad_lanes

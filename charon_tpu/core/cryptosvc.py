@@ -550,7 +550,9 @@ class CryptoPlaneService:
             if entry.kind == "verify":
                 res = await coal.verify(entry.args[0], **kwargs)
                 ok = sum(1 for r in res if r)
-                failed = len(res) - ok
+                # None: lanes refused with their set and not judged
+                # apart (cryptoplane.verify) — at least one is bad
+                failed = sum(1 for r in res if r is False) + (None in res)
             else:
                 res = await coal.recombine(*entry.args, **kwargs)
                 oks = res[1]
@@ -571,7 +573,7 @@ class CryptoPlaneService:
         ten.breaker.record(ok, failed)
         self._observe(
             "complete", ten.id,
-            lanes=ok + failed, failed=failed,
+            lanes=entry.lanes, failed=failed,
             seconds=time.monotonic() - t0, quarantined=quarantined,
         )
         if not entry.fut.done():

@@ -582,3 +582,56 @@ def batched_verify_rlc(
     f_tot = _fp12_prod_tree(ctx, f_lanes)
     e = final_exp(ctx, f_tot)
     return T.fp12_is_one(ctx, e)
+
+
+def batched_verify_rlc_sets(
+    ctx: ModCtx, fr_ctx: ModCtx, pk, msg, sig, rand, seg, n_sets: int,
+    nbits: int = 64,
+):
+    """batched_verify_rlc with the product taken per SET: `seg` is an
+    int32 [N] segment id per lane (0 <= seg < n_sets, n_sets static) and
+    the answer a bool [n_sets] — set s passes iff
+
+        prod_{i : seg_i == s} (e(pk_i, H(m_i)) * e(-G1, sig_i))^(r_i) == 1
+
+    Same stacked scalar mul and Miller stage as batched_verify_rlc
+    (which the recombine programs keep calling, left as it is); then
+    `n_sets` masked product trees over the lanes' Miller values and ONE
+    final exponentiation over the [n_sets] batch. Each set's product is
+    its own Schwartz-Zippel check at 2^-nbits under the lanes' own
+    independent exponents, and the whole-batch answer is the AND of the
+    sets'. An empty segment's product is 1 and reads True; a lane with
+    exponent 0 (padding, undecodable) is neutral in whichever segment it
+    rides. A partial-signature set is dropped whole on one bad lane, so
+    a verdict per set is the verdict the protocol needs — on a failing
+    batch this saves the per-lane program's dispatch.
+    """
+    from charon_tpu.ops import curve as C
+
+    g1f = C.g1_ops(ctx)
+    batch_shape = pk[0].shape[:-1]
+    neg_g = neg_g1_gen(ctx, batch_shape)
+    pts = jax.tree_util.tree_map(
+        lambda a, b: jnp.stack(jnp.broadcast_arrays(a, b)), pk, neg_g
+    )
+    rand2 = jnp.stack(jnp.broadcast_arrays(rand, rand))
+    scaled = C.point_scalar_mul(
+        g1f, fr_ctx, C.affine_to_point(g1f, pts), rand2, nbits=nbits
+    )
+    aff = C.point_to_affine(g1f, scaled)
+    pk_r = jax.tree_util.tree_map(lambda a: a[0], aff)
+    negg_r = jax.tree_util.tree_map(lambda a: a[1], aff)
+
+    f_lanes = miller_loop(ctx, [(pk_r, msg), (negg_r, sig)])  # [N] fp12
+    # [N, n_sets]: lane i's value in its own segment's column, 1 in the
+    # others; the halving tree over axis 0 then takes every set's
+    # product at once
+    in_set = seg[:, None] == jnp.arange(n_sets, dtype=seg.dtype)[None, :]
+    ones = T.fp12_one(ctx, (n_sets,))
+    f_sets = jax.tree_util.tree_map(
+        lambda a, o: jnp.where(in_set[..., None], a[:, None, :], o),
+        f_lanes,
+        ones,
+    )
+    e = final_exp(ctx, _fp12_prod_tree(ctx, f_sets))  # [n_sets]
+    return T.fp12_is_one(ctx, e)

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import functools
 import time
+from typing import NamedTuple
 
 import numpy as np
 import jax
@@ -75,6 +76,16 @@ def make_mesh_2d(
     return Mesh(devices.reshape(n_hosts, -1), axes)
 
 
+class LaneSets(NamedTuple):
+    """Which segment of the set-wise RLC product each lane of a packed
+    verify batch rides, and which segments hold more than one set (a
+    failure there is one the RLC tier cannot bill to a set). Host
+    arrays: they travel with the pack to the device stage."""
+
+    seg: np.ndarray  # int32 [N_padded], < SlotCryptoPlane.VERIFY_SETS
+    mixed: np.ndarray  # bool [VERIFY_SETS]
+
+
 class SlotCryptoPlane:
     """The per-slot batched crypto program, sharded over a mesh.
 
@@ -91,6 +102,11 @@ class SlotCryptoPlane:
       total_ok   []   int32     — cluster-wide count of fully-valid lanes
                                   (psum over shards)
     """
+
+    # segments of the parsed RLC verify's product: part of the traced
+    # shape, not an option. 8 holds one partial-signature set per sender
+    # plus the validator client's for every cluster of n <= 7
+    VERIFY_SETS = 8
 
     def __init__(self, mesh: Mesh, t: int, ctx: ModCtx | None = None, fr_ctx: ModCtx | None = None):
         self.mesh = mesh
@@ -356,20 +372,27 @@ class SlotCryptoPlane:
         return jax.jit(sharded)
 
     def _build_verify_rlc_dec(self):
-        """RLC verify on PARSED signature lanes. Undecodable lanes get
-        exponent 0 (neutral in the shared product) and come back False
-        in the per-lane mask output; all_ok therefore means 'every lane
-        that DECODED verified' — the host resolves per-lane results as
-        decode_mask on the fast path."""
+        """RLC verify on PARSED signature lanes, one verdict per SET:
+        `seg` names each lane's segment (< VERIFY_SETS) and the RLC
+        product is taken per segment (ops/pairing.batched_verify_rlc_sets)
+        — each shard judges its own lanes of a set under its own
+        exponents, the cross-device op is a psum of per-segment failure
+        counts. Undecodable lanes get exponent 0 (neutral in their set's
+        product) and come back False in the per-lane mask output; a set's
+        verdict therefore means 'every lane of it that DECODED verified'
+        — the host resolves a lane as decode_mask AND its set's verdict."""
         ctx, fr_ctx, axis = self.ctx, self.fr_ctx, self.axis
+        n_sets = self.VERIFY_SETS
 
-        def local(pk, msg, sx0, sx1, sign, live, rand):
+        def local(pk, msg, sx0, sx1, sign, live, rand, seg):
             sig, dec_ok = DEC.decompress_g2_graph(
                 ctx, fr_ctx, (sx0, sx1), sign
             )
             lane_ok = jnp.logical_and(dec_ok, live)
             rand_live = jnp.where(lane_ok[:, None], rand, 0)
-            ok = DP.batched_verify_rlc(ctx, fr_ctx, pk, msg, sig, rand_live)
+            ok = DP.batched_verify_rlc_sets(
+                ctx, fr_ctx, pk, msg, sig, rand_live, seg, n_sets
+            )
             bad = jax.lax.psum(jnp.logical_not(ok).astype(jnp.int32), axis)
             return bad == 0, lane_ok
 
@@ -378,7 +401,7 @@ class SlotCryptoPlane:
             mesh=self.mesh,
             in_specs=(
                 P(axis), P(axis), P(axis), P(axis), P(axis), P(axis),
-                P(axis),
+                P(axis), P(axis),
             ),
             out_specs=(P(), P(axis)),
         )
@@ -673,12 +696,16 @@ class SlotCryptoPlane:
             )
         )
 
-    def pack_verify_inputs_parsed(self, pks, msgs, parsed):
+    def pack_verify_inputs_parsed(self, pks, msgs, parsed, sets=None):
         """Decode-mode-device pack: pk/msg POINTS (host-cached decodes)
         plus PARSED compressed signature lanes
         (ops/decompress.ParsedPoint, host-valid and finite — the
         coalescer prefails the rest). Same bucket padding and trailing
-        live mask as pack_verify_inputs."""
+        live mask as pack_verify_inputs; in front of the arrays ride the
+        lanes' sets (LaneSets): `sets` is one label per lane, equal
+        labels for the lanes of one partial-signature set (the
+        coalescer's verify jobs), None for a caller that names none —
+        then the batch is one segment and judged as a whole."""
         n = len(pks)
         pad = self.bucket_lanes(n) - n
         if pad:
@@ -689,24 +716,50 @@ class SlotCryptoPlane:
         msg = C.g2_pack(self.ctx, msgs)
         sx0, sx1, sign, _inf, _ok = DEC.pack_parsed_g2(self.ctx, parsed)
         live = jnp.asarray(np.arange(n + pad) < n)
-        return pk, msg, sx0, sx1, sign, live
+        return self._lane_sets(sets, n, pad), pk, msg, sx0, sx1, sign, live
 
-    def verify_packed_parsed(self, arrays, rand, n: int) -> list[bool]:
+    def _lane_sets(self, sets, n: int, pad: int) -> LaneSets:
+        """Fold a batch's sets into the program's VERIFY_SETS segments:
+        one segment a set while they fit, neighbouring sets sharing a
+        segment beyond that. Padding lanes ride segment 0 (their
+        exponent is 0)."""
+        s = self.VERIFY_SETS
+        seg = np.zeros(n + pad, np.int32)
+        if sets is None:
+            # whatever sets segment 0 holds, nobody named them
+            return LaneSets(seg, np.arange(s) == 0)
+        ids = np.unique(np.asarray(sets), return_inverse=True)[1]
+        count = int(ids.max()) + 1
+        segment_of = np.arange(count) * s // max(count, s)  # set -> segment
+        seg[:n] = segment_of[ids]
+        return LaneSets(seg, np.bincount(segment_of, minlength=s) > 1)
+
+    def verify_packed_parsed(self, arrays, rand, n: int) -> list[bool | None]:
         """Device stage for a parsed verify batch: decompression is fused
-        into the verify program (no separate decode dispatch). Lanes that
-        fail decompression on device come back False; the RLC fast path's
-        per-lane answer is exactly the decode mask."""
-        pk, msg, sx0, sx1, sign, live = arrays
+        into the verify program (no separate decode dispatch), and the
+        RLC tier answers per SET: a lane is True iff it decoded AND its
+        set's product is 1. A lane that decoded in a set whose product
+        is not 1 is None — refused with its set, not judged apart: the
+        RLC tier cannot say which lane of a failing set is at fault, and
+        a set is dropped whole by its submitter on one bad lane, so
+        nobody needs it said. False is a lane KNOWN bad: it did not
+        decode, or the per-lane program refused it — the tier behind
+        this one, for a failing segment that holds more than one set
+        (more sets than VERIFY_SETS, or none named)."""
+        sets, pk, msg, sx0, sx1, sign, live = arrays
 
         def fast():
-            all_ok, lane_ok = self._verify_rlc_dec(
-                pk, msg, sx0, sx1, sign, live, rand
+            set_ok, lane_ok = self._verify_rlc_dec(
+                pk, msg, sx0, sx1, sign, live, rand, sets.seg
             )
-            return bool(all_ok), lane_ok
+            return np.asarray(set_ok), np.asarray(lane_ok)
 
-        all_ok, lane_ok = self._timed("verify_rlc_dec", n, fast)
-        if all_ok:
-            return [bool(b) for b in np.asarray(lane_ok)[:n]]
+        set_ok, lane_ok = self._timed("verify_rlc_dec", n, fast)
+        if not np.any(~set_ok & sets.mixed):
+            return [
+                False if not decoded else True if passed else None
+                for decoded, passed in zip(lane_ok[:n], set_ok[sets.seg[:n]])
+            ]
         ok = self._timed(
             "verify_dec",
             n,
@@ -899,18 +952,18 @@ class SlotCryptoPlane:
             return DEC.parse_g2_lane(g2_to_bytes(G2_GEN))
 
         def _verify_dec():
-            args = self.pack_verify_inputs_parsed(
+            _sets, *args = self.pack_verify_inputs_parsed(
                 [G1_GEN] * n, [G2_GEN] * n, [_parsed()] * n
             )
-            return spec(self._verify_dec, args)
+            return spec(self._verify_dec, tuple(args))
 
         def _verify_rlc_dec():
-            args = self.pack_verify_inputs_parsed(
+            sets, *args = self.pack_verify_inputs_parsed(
                 [G1_GEN] * n, [G2_GEN] * n, [_parsed()] * n
             )
             return spec(
                 self._verify_rlc_dec,
-                (*args, self.make_lane_rand(n, rng=rng)),
+                (*args, self.make_lane_rand(n, rng=rng), sets.seg),
             )
 
         def _parsed_points():
@@ -1017,12 +1070,12 @@ class SlotCryptoPlane:
             )
 
         def verify_dec_args(n):
-            return (
-                *self.pack_verify_inputs_parsed(
-                    [G1_GEN] * n, [G2_GEN] * n, [gen_parsed] * n
-                ),
-                self.make_lane_rand(n),
+            # what a live flush dispatches (verify_packed_parsed), to
+            # the dtype: the arrays, the exponents, the segment ids
+            sets, *arrays = self.pack_verify_inputs_parsed(
+                [G1_GEN] * n, [G2_GEN] * n, [gen_parsed] * n
             )
+            return (*arrays, self.make_lane_rand(n), sets.seg)
 
         def step_args(v):
             return (
@@ -1048,43 +1101,42 @@ class SlotCryptoPlane:
                 self.make_rand(v),
             )
 
-        # (kind, lanes, args builder, [(family, program, takes rand)]):
-        # each shape compiles BOTH tiers — the RLC fast path AND the
-        # per-lane attribution program
+        # (kind, lanes, args builder, [(family, program, trailing
+        # arguments it does not take)]): each shape compiles BOTH tiers
+        # — the RLC fast path AND the per-lane attribution program,
+        # which takes no exponents (nor segment ids)
         groups = [
             ("verify", verify_lanes, verify_args,
-             [("verify_rlc", self._verify_rlc, True),
-              ("verify", self._verify, False)]),
+             [("verify_rlc", self._verify_rlc, 0),
+              ("verify", self._verify, 1)]),
             ("recombine", recombine_lanes, step_args,
-             [("step_rlc", self._step_rlc, True),
-              ("step", self._step, False)]),
+             [("step_rlc", self._step_rlc, 0),
+              ("step", self._step, 1)]),
         ]
         if decompress:
             # decode-fused programs (decode_mode device): same buckets
             groups += [
                 ("verify-dec", verify_lanes, verify_dec_args,
-                 [("verify_rlc_dec", self._verify_rlc_dec, True),
-                  ("verify_dec", self._verify_dec, False)]),
+                 [("verify_rlc_dec", self._verify_rlc_dec, 0),
+                  ("verify_dec", self._verify_dec, 2)]),
                 ("recombine-dec", recombine_lanes, step_dec_args,
-                 [("step_rlc_dec", self._step_rlc_dec, True),
-                  ("step_dec", self._step_dec, False)]),
+                 [("step_rlc_dec", self._step_rlc_dec, 0),
+                  ("step_dec", self._step_dec, 1)]),
             ]
 
-        def runner(build, prog, with_rand, n):
+        def runner(build, prog, drop, n):
             def run():
                 args = build(n)
-                jax.block_until_ready(
-                    prog(*(args if with_rand else args[:-1]))
-                )
+                jax.block_until_ready(prog(*args[: len(args) - drop]))
 
             return run
 
         return [
             (kind, family, self.bucket_lanes(n),
-             runner(build, prog, with_rand, n))
+             runner(build, prog, drop, n))
             for kind, lanes, build, tiers in groups
             for n in lanes
-            for family, prog, with_rand in tiers
+            for family, prog, drop in tiers
         ]
 
     def prewarm(

@@ -29,9 +29,9 @@ _jc.configure(jax, cpu=True)
 """
 
 
-def run_isolated(script: str, marker: str, timeout: float = 1500) -> None:
+def run_isolated(script: str, marker: str, timeout: float = 1500) -> str:
     """Run `script` (usually ISOLATED_HEADER + body) in a fresh python;
-    assert exit 0 and that `marker` was printed."""
+    assert exit 0 and that `marker` was printed. Returns its stdout."""
     proc = subprocess.run(
         [sys.executable, "-c", script],
         capture_output=True,
@@ -50,3 +50,4 @@ def run_isolated(script: str, marker: str, timeout: float = 1500) -> None:
         f"{proc.stdout[-2000:]}\n{proc.stderr[-2000:]}"
     )
     assert marker in proc.stdout
+    return proc.stdout

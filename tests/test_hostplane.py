@@ -411,7 +411,7 @@ class ParsedFakePlane(FakePlane):
 
         return ("v", np.empty(len(pks)))
 
-    def pack_verify_inputs_parsed(self, pks, msgs, parsed):
+    def pack_verify_inputs_parsed(self, pks, msgs, parsed, sets=None):
         import numpy as np
 
         from charon_tpu.ops import decompress as DEC
