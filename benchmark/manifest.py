@@ -1,11 +1,12 @@
 """BENCHMARK.json and the data files it names. The harness finds a
-configuration, a traffic mix, a metric and its reader BY NAME: a later PR
-adds a cell, a configuration or a per-layer metric by adding files and
-one manifest entry, and edits nothing here."""
+configuration, a traffic mix, a duty kind, a metric and its reader BY NAME:
+a later PR adds a cell, a configuration, a kind of duty or a per-layer
+metric by adding files and one manifest entry, and edits nothing here."""
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import re
@@ -104,15 +105,35 @@ def load_cell(root: Path, name: str, manifest: dict | None = None) -> Cell:
     )
 
 
+def _module(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
 def load_reader(root: Path, manifest: dict, reader: str):
     """benchmark/readers/<reader>.py -> its `read(run, **params)`."""
     path = bench_dir(root, manifest) / "readers" / f"{reader}.py"
     if not path.exists():
         raise ManifestError(f"no reader {reader!r} at {path}")
-    spec = importlib.util.spec_from_file_location(f"bench_reader_{reader}", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return _module(path, f"bench_reader_{reader}").read
+
+
+def load_duty(kind: str, bdir: Path | None = None):
+    """duties/<kind>.py of the benchmark's directory (default: the one this
+    file is in) -> the module a mix's `duties` names; README.md, "Adding
+    things", says what it gives. One module a kind and a directory, however
+    many plans ask for it."""
+    return _load_duty(str(kind), Path(bdir or Path(__file__).parent).resolve())
+
+
+@functools.lru_cache(maxsize=None)
+def _load_duty(kind: str, bdir: Path):
+    path = bdir / "duties" / f"{kind}.py"
+    if not NAME.match(kind) or not path.exists():
+        raise ManifestError(f"no duty kind {kind!r} at {path}")
+    return _module(path, f"bench_duty_{kind}")
 
 
 def validate(manifest: dict) -> list[str]:

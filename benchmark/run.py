@@ -3,8 +3,9 @@
 
 One process, one TPU chip, no CPU mode. Boots one real charon-tpu node and
 its host-only peers, warms exactly the programs the cell's configuration
-lists, serves `--seconds` of attester slots at the real slot cadence, checks
-every broadcast aggregate against the plain reference, and prints the
+lists, serves `--seconds` of slots at the real slot cadence (the duties of the
+kinds the cell's mix names), checks every broadcast aggregate against the
+plain reference, and prints the
 contract's result as the last stdout line. See README.md beside this file.
 """
 
@@ -14,11 +15,13 @@ T_PROCESS = __import__("time").time()  # process start, as near as Python gets
 
 import argparse
 import asyncio
+import concurrent.futures
 import dataclasses
 import gc
 import json
 import os
 import sys
+import threading
 import time
 from pathlib import Path
 
@@ -39,7 +42,19 @@ PHASE = {
     "peers": 30, "warmup": 120, "align": 20, "drain": 15, "teardown": 30,
     "reference": 60, "trace_stop": 275,
 }
-
+# --trace 1: the wave of the window that runs under the profiler (an index
+# into the window's slots), the program family whose end closes the trace,
+# and the seconds that program's verdicts are held back once the end of the
+# session has been started. The FIRST wave, to the end of its verify
+# program, so that the session ends beside the rest of the window. The end
+# is started from the plane's own hook, the instant the program returns, and
+# the hold keeps the wave's next program off the device until the device
+# tracer has stopped (it needs 15-19 ms; a recombine program dispatched
+# inside them makes the trace 60 MB larger and its end 60 s longer: my chip
+# runs, PRs 34-37, README.md "--trace 1"). tests/tracelab.py sets others.
+TRACED_WAVE = 0
+TRACE_UNTIL = "verify"
+TRACE_HOLD = 0.025
 
 @dataclasses.dataclass
 class Rehearsal:
@@ -202,31 +217,50 @@ async def serve(args, cell, plan, wd, jax, cache, cache_log, events, dev,
     watch_gc(run.spans)
     server.in_window = True
     requests_before = events.total_requests
-    # --trace 1: the window's FIRST wave runs under the profiler, from its
-    # slot's start until the verify program has ended. The trace ends on a
-    # thread of its own while the window's other waves are served (what
-    # ending it costs, and why: README.md "--trace 1"), and is awaited last.
-    trace_handle = trace_task = None
+    # --trace 1: ONE wave of the window runs under the profiler (TRACED_WAVE),
+    # from its slot's start until the program TRACE_UNTIL names has ended.
+    # The trace ends on a thread of its own beside what is left of the
+    # window, the teardown and the reference (what ending it costs, and
+    # why: README.md "--trace 1"), and is awaited last.
+    trace_handle = trace_future = None
+    trace_lock = threading.Lock()
+    traced_k = TRACED_WAVE % slots
 
-    def end_trace():
-        nonlocal trace_task
-        trace_handle["stop_from"] = time.monotonic()
-        trace_task = asyncio.create_task(
-            asyncio.to_thread(tracered.stop, trace_handle, wd.note))
+    def end_trace(hold: float = 0.0) -> None:
+        """Start the end of the session, once, from whichever thread asks."""
+        nonlocal trace_future
+        with trace_lock:
+            if trace_handle is None or trace_future is not None:
+                return
+            trace_future = future = concurrent.futures.Future()
+        trace_handle["stop_from"], trace_handle["stop_wall"] = time.monotonic(), time.time()
 
+        def work():
+            try:
+                future.set_result(tracered.stop(trace_handle, wd.note))
+            except BaseException as e:  # noqa: BLE001 — handed to the awaiting loop
+                future.set_exception(e)
+
+        threading.Thread(target=work, name="bench-trace-stop", daemon=True).start()
+        if hold:
+            time.sleep(hold)
+
+    def program_ended(family: str, at: float) -> None:
+        # on the plane's dispatch thread, before the program's caller has its result
+        if (trace_handle is not None and trace_future is None
+                and family.startswith(TRACE_UNTIL) and at >= trace_handle["wall"]):
+            end_trace(TRACE_HOLD)
+
+    if args.trace:
+        server.on_program_end = program_ended
     for k, slot in enumerate(run.slots):
         end = start + (k + 1) * plan.slot_duration
-        if args.trace and k == 0:
+        if args.trace and k == traced_k:
             trace_handle = tracered.start(jax)
             run.traced_slot = slot
         with wd.phase(f"slot {k + 1}/{slots} (slot {slot})", plan.slot_duration + 3):
-            while trace_handle is not None and trace_task is None and time.time() < end:
-                if any(f.startswith("verify") and t >= trace_handle["wall"]
-                       for f, _s, _l, t in run.programs):
-                    end_trace()
-                await asyncio.sleep(0.02)
             await asyncio.sleep(max(0.0, end - time.time()))
-        if trace_handle is not None and trace_task is None:  # no verify flush came
+        if trace_handle is not None and trace_future is None:  # no such program came
             end_trace()
     with wd.phase("drain", PHASE["drain"]):
         # a duty not at the beacon by the end of its own slot has FAILED;
@@ -258,22 +292,29 @@ async def serve(args, cell, plan, wd, jax, cache, cache_log, events, dev,
             (bytes(fork.fork_version), bytes(fork.genesis_validators_root)),
             counters["events"], rejected, expected_forged, compiles_in_window)
         reference_s = time.monotonic() - t0
-    if trace_task is not None:
+    trace_lead_s = None
+    if trace_future is not None:
         # the budget is the stop's own, from the second it began: what is
         # left of it here is what the run may still wait
         left = trace_handle["stop_from"] + PHASE["trace_stop"] - time.monotonic()
         with wd.phase("trace_stop", max(1.0, left)):
-            run.trace = await trace_task
+            run.trace = await asyncio.wrap_future(trace_future)
+        # the next program's dispatch after the end was started: under
+        # ~0.02 s it rides into the trace (README.md "--trace 1")
+        later = [t - sec for _f, sec, _l, t in run.programs if t - sec > trace_handle["stop_wall"]]
+        trace_lead_s = round(min(later) - trace_handle["stop_wall"], 4) if later else None
     run_wall_s = time.time() - T_PROCESS
     budget = {  # how near its budget the run came (README.md "--trace 1")
         "run_wall_s": round(run_wall_s, 2), "window_opened_s": round(setup_s, 2),
         "traced_tail_s": round(run_wall_s - setup_s, 2) if args.trace else None,
         "stop_trace_s": getattr(run.trace, "stop_s", None),
+        "trace_stop_lead_s": trace_lead_s,
         "trace_events": getattr(run.trace, "events", None),
         "trace_bytes": getattr(run.trace, "bytes", None),
         "trace_planes": getattr(run.trace, "planes", None),
     }
     wd.note("budget: " + ", ".join(f"{k} {v}" for k, v in budget.items()))
+    vc_spans = {name for kind in plan.kinds for name in kind.VC_SPANS}
     info = {
         "cell": cell.name, "seed": args.seed, "device": dev, "versions": vers,
         "routing": route, "cache_dir": str(cache), "cache_marker": warm,
@@ -301,8 +342,7 @@ async def serve(args, cell, plan, wd, jax, cache, cache_log, events, dev,
         "qbft_decided_at_s": sorted({round(a - run.window[0], 2) for n, a, _b in run.spans
                                      if n == "qbft_decided"}),
         "vc_spans_s": [[n, round(a - run.window[0], 3), round(b - a, 3)]
-                       for n, a, b in run.spans if n in ("vc_attestation_data", "vc_sign",
-                                                         "http_submit")],
+                       for n, a, b in run.spans if n in vc_spans],
         "gc_pauses_s": [round(b - a, 3) for n, a, b in run.spans
                         if n == "gc_gen2" and run.in_window(a)],
         "compiles_in_window": compiles_in_window,
@@ -354,7 +394,8 @@ def main(argv=None, root: Path = ROOT, exit_fn=os._exit,
     try:
         manifest = manifestlib.load_manifest(root)
         cell = manifestlib.load_cell(root, args.workload, manifest)
-        plan = trafficlib.make_plan(cell.config, cell.traffic, args.seed)
+        plan = trafficlib.make_plan(cell.config, cell.traffic, args.seed,
+                                    manifestlib.bench_dir(root, manifest))
         trafficlib.check_programs(plan, cell.config)
         slots = args.seconds / plan.slot_duration
         if slots < 1 or abs(slots - round(slots)) > 1e-9:

@@ -5,13 +5,19 @@ Copied from chip_smoke.py (PR 22) and made into a window: the node is
 the ValidatorAPI HTTP router, its n-1 peers (host-only: real P2PNode, QBFT,
 scheduler; the harness's signer) send theirs through ParSigEx over TCP, and a
 duty is done when the node's beacon holds the broadcast aggregate. Every
-duty is timed from the instant its trigger was DUE on the slot clock."""
+duty is timed from the instant its trigger was DUE on the slot clock.
+
+What belongs to ONE kind of duty — who holds it when, what the beacon
+answers, what is signed and submitted where — is the kind's module
+(duties/<kind>.py, found by the name in the mix); what is here serves
+whatever kinds the plan carries."""
 
 from __future__ import annotations
 
 import asyncio
 import concurrent.futures
 import dataclasses
+import functools
 import socket
 import tempfile
 import threading
@@ -21,17 +27,16 @@ from pathlib import Path
 from benchmark import cluster as clusterlib, reference, signer
 from benchmark.traffic import Plan
 
-ATTESTER_OFFSET = 1.0 / 3.0  # the attester trigger's place in the slot
-
 
 @dataclasses.dataclass
 class DutyRecord:
+    kind: str  # the duty module's NAME
     slot: int
     vidx: int
     pubkey: str
-    due: float  # wall clock: slot start + 1/3 slot
+    due: float  # wall clock: slot start + the kind's place in the slot
     root: bytes | None = None  # the signing root the VC signed (the program's SSZ)
-    data: tuple | None = None  # raw fields of the attestation the beacon received
+    data: tuple | None = None  # raw fields of the object the beacon received
     done: float | None = None  # wall clock: the node's beacon got it
     signature: bytes | None = None
     broadcasts: int = 0
@@ -62,7 +67,7 @@ class RunData:
             ds = [d for d in self.duties if d.slot == slot]
             done = [d.done for d in ds if d.done is not None]
             out.append({
-                "slot": slot, "duties": len(ds), "due": ds[0].due if ds else 0.0,
+                "slot": slot, "duties": len(ds), "due": min(d.due for d in ds) if ds else 0.0,
                 "last_done": max(done) if len(done) == len(ds) and ds else None,
             })
         return out
@@ -100,101 +105,61 @@ class SlotMemo:
     the six peers stand for six other machines, and in one interpreter
     their repeated SSZ hashing would sit on the node's own event loop."""
 
-    def __init__(self, plan: Plan):
-        self.plan = plan
-        self._data: dict = {}
-        self._roots: dict = {}
+    def __init__(self) -> None:
+        self._made: dict = {}
 
-    def attestation_data(self, slot: int, committee_index: int):
-        from charon_tpu.core.eth2data import AttestationData, Checkpoint
-
-        key = (slot, committee_index)
-        if key not in self._data:
-            _s, _i, block, s_epoch, s_root, t_epoch, t_root = (
-                self.plan.attestation_fields(slot, committee_index))
-            data = AttestationData(
-                slot=slot,
-                index=committee_index,
-                beacon_block_root=block,
-                source=Checkpoint(s_epoch, s_root),
-                target=Checkpoint(t_epoch, t_root),
-            )
-            self._data[key] = (data, data.hash_tree_root())
-        return self._data[key]
-
-    def signing_root(self, fork, data, bits) -> bytes:
-        from charon_tpu.core.eth2data import Attestation, SignedData
-
-        key = (data.slot, data.index, bits)
-        if key not in self._roots:
-            self._roots[key] = SignedData("attestation", Attestation(bits, data)).signing_root(
-                fork, data.slot // self.plan.slots_per_epoch
-            )
-        return self._roots[key]
+    def once(self, key, make):
+        if key not in self._made:
+            self._made[key] = make()
+        return self._made[key]
 
 
-def make_beacon(plan: Plan, cluster, genesis: float, memo: SlotMemo):
-    """The repo's BeaconMock with mainnet-shaped duties: each validator
-    attests ONCE an epoch, in the slot the plan's seeded order gives it,
-    and no proposer or sync-committee duty is scheduled. Every attester
-    sits in a committee of its own (configuration: committees_per_slot).
-    Block roots come from the seed, the same on every operator's beacon."""
+@dataclasses.dataclass
+class Scene:
+    """What every actor of a run shares, and a kind's functions are given."""
+
+    plan: Plan
+    cluster: object
+    memo: SlotMemo = dataclasses.field(default_factory=SlotMemo)
+
+    @functools.cached_property
+    def fork(self):
+        """Once a run: the lock derives it anew on every call (milliseconds
+        at 1,000 validators), and a signer asks for it on every signing root
+        it has not met — 31-32 a wave, inside the VC's round."""
+        return self.cluster.lock.fork_info()
+
+
+def kinds_by_type(plan: Plan) -> dict:
+    from charon_tpu.core.types import DutyType
+
+    return {DutyType[kind.DUTY_TYPE]: kind for kind in plan.kinds}
+
+
+# the BeaconMock's own schedules (every validator, every slot): none of them
+# unless a kind of the mix answers it
+SCHEDULES = ("attester_duties", "proposer_duties", "sync_duties")
+
+
+def make_beacon(scene: Scene, genesis: float):
+    """The repo's BeaconMock with the duties of the plan's kinds and no
+    others: each kind's module gives the answers to the scheduler's and
+    the fetcher's queries for it. Block roots come from the seed, the
+    same on every operator's beacon."""
     from charon_tpu.testutil.beaconmock import BeaconMock
 
-    spe = plan.slots_per_epoch
-    by_pos = {p: plan.members(p) for p in range(spe)}
+    async def none(self, epoch, vals):
+        return []
 
-    class MainnetShapeBeacon(BeaconMock):
-        async def attester_duties(self, epoch, vals):
-            return [
-                dict(
-                    slot=epoch * spe + pos,
-                    pubkey=cluster.pubkeys[vidx],
-                    validator_index=vals[cluster.pubkeys[vidx]],
-                    committee_index=ci,
-                    committee_length=1,
-                    committees_at_slot=len(members),
-                    validator_committee_index=0,
-                )
-                for pos, members in sorted(by_pos.items())
-                for ci, vidx in enumerate(members)
-                if cluster.pubkeys[vidx] in vals
-            ]
-
-        async def proposer_duties(self, epoch, vals):
-            return []
-
-        async def sync_duties(self, epoch, vals):
-            return []
-
-        async def attestation_data(self, slot, committee_index):
-            data, root = memo.attestation_data(slot, committee_index)
-            self._att_data_by_root[root] = data
-            return data
-
-    return MainnetShapeBeacon(
-        validators=dict(cluster.validators),
+    methods = dict.fromkeys(SCHEDULES, none)
+    for kind in scene.plan.kinds:
+        methods.update(kind.beacon(scene))
+    return type("HarnessBeacon", (BeaconMock,), methods)(
+        validators=dict(scene.cluster.validators),
         genesis_time=genesis,
-        slot_duration=plan.slot_duration,
-        slots_per_epoch=spe,
+        slot_duration=scene.plan.slot_duration,
+        slots_per_epoch=scene.plan.slots_per_epoch,
     )
-
-
-def sign_attestations(fork, memo, share_keys, duties, roots=None):
-    """duties: pubkey -> (AttestationData, committee_length, position)
-    -> {pubkey: Attestation} signed with the share keys by the harness's
-    signer (C++ through ctypes: the GIL is released while it signs);
-    `roots` collects pubkey -> signing root."""
-    from charon_tpu.core.eth2data import Attestation
-
-    out = {}
-    for pubkey, (data, length, pos) in duties.items():
-        bits = tuple(i == pos for i in range(length))
-        root = memo.signing_root(fork, data, bits)
-        if roots is not None:
-            roots[pubkey] = root
-        out[pubkey] = Attestation(bits, data, signer.sign(share_keys[pubkey], root))
-    return out
 
 
 class HostPeer:
@@ -205,10 +170,10 @@ class HostPeer:
     decided (after the plan's jitter). It verifies and aggregates nothing:
     tbls is process-global and belongs to the chip-backed node."""
 
-    def __init__(self, plan: Plan, cluster, index, ports, genesis, gate, spans, memo):
-        self.plan, self.cluster, self.index = plan, cluster, index
+    def __init__(self, scene: Scene, index, ports, genesis, gate, spans):
+        self.scene, self.plan, self.cluster, self.index = scene, scene.plan, scene.cluster, index
         self.ports, self.genesis, self.gate = ports, genesis, gate
-        self.spans, self.memo = spans, memo
+        self.spans, self.kinds = spans, kinds_by_type(scene.plan)
         self.sent_sets = 0
         self.forged_sets = 0
         self._sends: set = set()
@@ -243,7 +208,7 @@ class HostPeer:
             gater=gater,
         )
         self.parsigex = ParSigEx(self.index + 1, TcpParSigTransport(self.p2p), gater=gater)
-        beacon = make_beacon(plan, self.cluster, self.genesis, self.memo)
+        beacon = make_beacon(self.scene, self.genesis)
         self._fetcher = Fetcher(beacon)
         self._fetcher.register_consensus(self.qbft.propose)
         self.scheduler = Scheduler(
@@ -254,16 +219,12 @@ class HostPeer:
         self._task = asyncio.create_task(self.scheduler.run())
 
     async def _fetch(self, duty, defs) -> None:
-        from charon_tpu.core.types import DutyType
-
-        if duty.type == DutyType.ATTESTER:
+        if duty.type in self.kinds:
             await self._fetcher.fetch(duty, defs)
 
     async def _decided(self, duty, unsigned_set) -> None:
-        from charon_tpu.core.types import DutyType
-
         share_idx = self.index + 1
-        if duty.type != DutyType.ATTESTER or not self.gate.open(duty.slot):
+        if duty.type not in self.kinds or not self.gate.open(duty.slot):
             return
         self.spans.append(("qbft_decided", time.time(), time.time()))
         if share_idx in self.plan.silent:
@@ -273,26 +234,19 @@ class HostPeer:
         task.add_done_callback(self._sends.discard)
 
     async def _send(self, duty, unsigned_set, share_idx) -> None:
-        from charon_tpu.core.eth2data import ParSignedData, SignedData
+        from charon_tpu.core.eth2data import ParSignedData
 
         plan = self.plan
         await asyncio.sleep(plan.jitter(share_idx, duty.slot))
-        forge = plan.forged(duty.slot, share_idx, self.gate.last)
-        # on a thread: this operator is another machine, and its 31-32
+        forge = plan.forged(duty.slot, share_idx, self.gate.last, self.kinds[duty.type].NAME)
+        # on a thread: this operator is another machine, and its
         # signatures may not hold the node's event loop
-        atts = await asyncio.to_thread(
-            sign_attestations,
-            self.cluster.lock.fork_info(),
-            self.memo,
-            self.cluster.share_keys[self.index],
-            {
-                pk: (d.data, d.committee_length, d.validator_committee_index)
-                for pk, d in unsigned_set.items()
-            },
-        )
+        signed = await asyncio.to_thread(
+            self.kinds[duty.type].sign, self.scene, self.cluster.share_keys[self.index],
+            duty, unsigned_set)
         signed_set = {}
-        for n, (pk, att) in enumerate(atts.items()):
-            sig = att.signature
+        for n, (pk, obj) in enumerate(signed.items()):
+            sig = obj.signature
             if forge and n < plan.fault.partials:
                 if plan.fault.kind == "flip_byte":
                     sig = sig[:10] + bytes([sig[10] ^ 0x40]) + sig[11:]
@@ -301,7 +255,7 @@ class HostPeer:
                         reference.seeded_scalar("forger", plan.seed, n).to_bytes(32, "big"),
                         b"forged" + bytes(26),
                     )
-            signed_set[pk] = ParSignedData(SignedData("attestation", att, sig), share_idx)
+            signed_set[pk] = ParSignedData(obj.with_signature(sig), share_idx)
         await self.parsigex.broadcast(duty, signed_set)
         self.sent_sets += 1
         self.forged_sets += 1 if forge else 0
@@ -330,7 +284,11 @@ class Server:
         self.gate = Gate()
         self.in_window = False
         self.warm_stats: list[dict] = []
-        self._records: dict[tuple[int, str], DutyRecord] = {}
+        # callable(family, wall clock) | None: called on the plane's dispatch
+        # thread the instant a compiled program has returned, before its
+        # caller has the result (run.py starts the end of the trace there)
+        self.on_program_end = None
+        self._records: dict[tuple[str, int, int], DutyRecord] = {}
         self._tmp = None
         self.client = None
         self.life = None
@@ -357,8 +315,9 @@ class Server:
         plan, cfg = self.plan, self.cell.config
         self.ports = free_ports(plan.operators)
         self.genesis = time.time()
-        self.memo = SlotMemo(plan)
-        self.beacon = make_beacon(plan, self.cluster, self.genesis, self.memo)
+        self.scene = Scene(plan, self.cluster)
+        self.kinds = kinds_by_type(plan)
+        self.beacon = make_beacon(self.scene, self.genesis)
         self._hook_beacon()
         self.node = await build_node(
             Config(
@@ -379,62 +338,39 @@ class Server:
             raise RuntimeError("build_node installed no crypto plane")
         self.node.scheduler.subscribe_duties(self._vc_on_duty)
 
-    def _hook_beacon(self) -> None:
-        """Stamp every aggregate the node broadcasts, where it lands."""
-        inner = self.beacon.submit_attestation
-        records = self._records
+    def record(self, kind: str, slot: int, vidx: int) -> DutyRecord | None:
+        return self._records.get((kind, slot, vidx))
 
-        async def submit(att):
+    def _hook_beacon(self) -> None:
+        """Stamp every aggregate the node broadcasts, where it lands: each
+        kind names the beacon's `submit_*` it ends at and reads the
+        submitted object back into (slot, validator, signature, raw fields)."""
+        for kind in self.plan.kinds:
+            setattr(self.beacon, kind.SUBMIT,
+                    self._stamped(kind, getattr(self.beacon, kind.SUBMIT)))
+
+    def _stamped(self, kind, inner):
+        async def submit(*args):
             now = time.time()
-            slot = att.data.slot
-            members = self.plan.members(slot)
-            if 0 <= att.data.index < len(members):
-                rec = records.get((slot, members[att.data.index]))
+            found = kind.submitted(self.plan, *args)
+            if found is not None:
+                slot, vidx, signature, data = found
+                rec = self.record(kind.NAME, slot, vidx)
                 if rec is not None:
                     rec.broadcasts += 1
                     if rec.done is None:
-                        rec.done, rec.signature = now, att.signature
-                        d = att.data
-                        rec.data = (d.slot, d.index, d.beacon_block_root, d.source.epoch,
-                                    d.source.root, d.target.epoch, d.target.root)
-            await inner(att)
+                        rec.done, rec.signature, rec.data = now, signature, data
+            await inner(*args)
 
-        self.beacon.submit_attestation = submit
+        return submit
 
     async def _vc_on_duty(self, duty, defs) -> None:
-        """This node's validator client: HTTP against the ValidatorAPI."""
-        from charon_tpu.core.types import DutyType
-
-        if duty.type != DutyType.ATTESTER or not self.gate.open(duty.slot):
+        """This node's validator client: HTTP against the ValidatorAPI,
+        each kind's own round."""
+        kind = self.kinds.get(duty.type)
+        if kind is None or not self.gate.open(duty.slot):
             return
-        t0 = time.time()
-        plan = self.plan
-        duties, data_by_committee = {}, {}
-        for pk, d in defs.items():
-            if d.committee_index not in data_by_committee:
-                data_by_committee[d.committee_index] = (
-                    await self.client.attestation_data(duty.slot, d.committee_index)
-                )
-            duties[pk] = (
-                data_by_committee[d.committee_index],
-                d.committee_length,
-                d.validator_committee_index,
-            )
-        t1 = time.time()
-        roots: dict = {}
-        atts = sign_attestations(
-            self.cluster.lock.fork_info(), self.memo, self.cluster.share_keys[0],
-            duties, roots,
-        )
-        for pk, root in roots.items():
-            rec = self._records.get((duty.slot, self.cluster.validators[pk]))
-            if rec is not None:
-                rec.root = root
-        t2 = time.time()
-        await self.client.submit_attestations(list(atts.values()))
-        t3 = time.time()
-        self.run.spans += [("vc_attestation_data", t0, t1), ("vc_sign", t1, t2),
-                           ("http_submit", t2, t3)]
+        self.run.spans += await kind.vc_round(self, duty, defs)
 
     # -- phase: programs ----------------------------------------------------
 
@@ -460,7 +396,10 @@ class Server:
 
         def program_hook(family, seconds, lanes):
             name = f"{family.split('/', 1)[-1]}@{plane.bucket_lanes(lanes)}"
-            run.programs.append((family.split("/", 1)[-1], seconds, lanes, time.time()))
+            short, now = family.split("/", 1)[-1], time.time()
+            run.programs.append((short, seconds, lanes, now))
+            if self.on_program_end is not None:
+                self.on_program_end(short, now)
             if name not in self.allowed:
                 self.wd.fail(
                     f"a flush left the compiled set: {name} ({lanes} lanes) is not "
@@ -479,19 +418,19 @@ class Server:
         the other in the list's order. Blocking: call it in a thread."""
         cfg = self.cell.config
         plane = self.coalescer.plane
-        want = {}
+        want = []  # (family, bucket): a family may be listed at several buckets
         for item in cfg["programs"]:
             family, bucket = item.split("@")
             if family != "g1dec":  # compiled by the node's own warm-up
-                want[family] = int(bucket)
+                want.append((family, int(bucket)))
         dec = self.coalescer._decode_rung() == "device"
-        verify = [b for f, b in want.items() if f.startswith("verify")]
-        step = [b for f, b in want.items() if f.startswith("step")]
+        verify = sorted({b for f, b in want if f.startswith("verify")})
+        step = sorted({b for f, b in want if f.startswith("step")})
         entries = plane.prewarm_programs(
             verify_lanes=tuple(verify), recombine_lanes=tuple(step), decompress=dec
         )
         todo = [(family, bucket, fn) for _k, family, bucket, fn in entries
-                if want.get(family) == bucket]
+                if (family, bucket) in want]
         if len(todo) != len(want):
             raise RuntimeError(
                 f"prewarm_programs offers {[(f, b) for f, b, _ in todo]}, "
@@ -551,8 +490,7 @@ class Server:
         from charon_tpu.testutil.vapiclient import HttpVapiClient
 
         self.peers = [
-            HostPeer(self.plan, self.cluster, i, self.ports, self.genesis, self.gate,
-                     self.run.spans, self.memo)
+            HostPeer(self.scene, i, self.ports, self.genesis, self.gate, self.run.spans)
             for i in range(1, self.plan.operators)
         ]
         for p in self.peers:
@@ -584,18 +522,20 @@ class Server:
         run.slots = list(range(first, first + slots))
         run.window = (start, start + slots * plan.slot_duration)
         for slot in run.slots:
-            due = clock.slot_start(slot) + ATTESTER_OFFSET * plan.slot_duration
-            for vidx in plan.members(slot):
-                rec = DutyRecord(slot, vidx, self.cluster.pubkeys[vidx], due)
-                self._records[(slot, vidx)] = rec
-                run.duties.append(rec)
+            for kind in plan.kinds:
+                due = clock.slot_start(slot) + kind.OFFSET * plan.slot_duration
+                for vidx in kind.members(plan, slot):
+                    rec = DutyRecord(kind.NAME, slot, vidx, self.cluster.pubkeys[vidx], due)
+                    self._records[(kind.NAME, slot, vidx)] = rec
+                    run.duties.append(rec)
         return start
 
     def expected_forged_sets(self) -> int:
         return sum(
-            1 for slot in self.run.slots
+            1 for slot in self.run.slots for kind in self.plan.kinds
             for idx in range(2, self.plan.operators + 1)
-            if self.plan.forged(slot, idx, self.gate.last) and idx not in self.plan.silent
+            if self.plan.forged(slot, idx, self.gate.last, kind.NAME)
+            and idx not in self.plan.silent
         )
 
     # -- phase: teardown ----------------------------------------------------

@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
-"""The per-lane verdicts of an attributed flush against the plain reference,
-on the chip at a cell's own size:
+"""The verdicts of a flush that holds a forged partial against the plain
+reference, set for set, on the chip at a cell's own size:
 
     python3 benchmark/tests/attribution.py --workload <cell> --seed <n> --seconds <s> --trace 0
 
@@ -10,13 +10,17 @@ SigAgg) reach the crypto plane remembers every verify job beside the answers
 it was given, and every recombine row. After the run's last line — outside
 the timed window, the node torn down — every lane of the window's FIRST wave
 (the forged lane, the rest of the forger's set, the other sets) is verified
-by benchmark/reference_verify.py in plain Python and compared with what the
-served flush answered; then ONE more stdout line says how many lanes were
-compared, how many differ, how long the reference took, and the window's
-dispatch record: per wave the programs in order, each flush's verify-tier
-fields, and the share indices of its recombine rows. Exit code 0 where the
-run reached its end and every lane agrees. The benchmark's own runs never
-come here."""
+by benchmark/reference_verify.py in plain Python. A SET's verdict is the AND
+over its lanes, on both sides (tests/test_set_verdicts.py): since PR 36 the
+RLC program refuses a set whole, and the honest lanes of a refused set come
+back `None` — not judged apart — where the per-lane tier said True. So every
+set is compared, and beside it every lane that WAS judged apart (True or
+False); then ONE more stdout line says how many sets and lanes were compared,
+which differ, how many lanes were refused with their set, how long the
+reference took, and the window's dispatch record: per wave the programs in
+order, each flush's verify-tier fields, and the share indices of its
+recombine rows. Exit code 0 where the run reached its end and every set and
+every judged lane agrees. The benchmark's own runs never come here."""
 
 from __future__ import annotations
 
@@ -97,11 +101,17 @@ def main(argv, root=None, cpu=False, before=None) -> int:
         return int((ts - data.window[0]) // data.slot_duration)
 
     first = [s for s in handle.sets if wave_of(s["at"]) == 0]
-    t0, compared, differ = time.monotonic(), 0, []
+    t0, compared, differ, sets_differ, unjudged = time.monotonic(), 0, [], [], 0
     for s in first:
-        for pos, (lane, answer) in enumerate(zip(s["lanes"], s["answers"])):
+        sound = [reference_verify.verify(*lane) for lane in s["lanes"]]
+        if all(sound) != all(s["answers"]):
+            sets_differ.append({"sender": s["sender"], "served": all(s["answers"])})
+        for pos, (want, answer) in enumerate(zip(sound, s["answers"])):
+            if answer is None:  # refused with its set, not judged apart
+                unjudged += 1
+                continue
             compared += 1
-            if reference_verify.verify(*lane) != answer:
+            if want != answer:
                 differ.append({"sender": s["sender"], "lane": pos, "served": answer})
     waves = []
     programs = sorted(data.programs, key=lambda p: p[3])
@@ -117,20 +127,24 @@ def main(argv, root=None, cpu=False, before=None) -> int:
                 "sets_invalid", "attribute_lanes")} for ts, st in spans.window_flushes(data)
                 if inside(ts)],
             "sets": [{"sender": s["sender"], "lanes": len(s["lanes"]),
-                      "invalid": s["answers"].count(False)}
+                      "invalid": s["answers"].count(False), "accepted": all(s["answers"])}
                      for s in handle.sets if inside(s["at"])],
             "row_indices": sorted({tuple(r["indices"]) for r in handle.rows if inside(r["at"])}),
             "rows": sum(1 for r in handle.rows if inside(r["at"])),
         })
     coalescer = server.coalescer
     print(json.dumps({"attribution": {
-        "wave_slot": data.slots[0], "sets_of_the_wave": len(first), "lanes_compared": compared,
+        "wave_slot": data.slots[0], "sets_of_the_wave": len(first),
+        "sets_served_invalid": sum(1 for s in first if not all(s["answers"])),
+        "sets_that_differ": sets_differ, "lanes_compared": compared,
+        "lanes_refused_with_their_set": unjudged,
         "lanes_served_invalid": sum(s["answers"].count(False) for s in first),
         "lanes_that_differ": differ, "reference_seconds": round(time.monotonic() - t0, 2),
         "flushes_attributed": getattr(coalescer, "flushes_attributed", None),
+        "flushes_set_resolved": getattr(coalescer, "flushes_set_resolved", None),
         "lanes_invalid": getattr(coalescer, "lanes_invalid", None), "waves": waves}}),
         flush=True)
-    return 0 if code == 0 and compared and not differ else 1
+    return 0 if code == 0 and first and not sets_differ and not differ else 1
 
 
 if __name__ == "__main__":

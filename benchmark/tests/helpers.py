@@ -12,7 +12,7 @@ import time
 from pathlib import Path
 
 REPO = Path(__file__).resolve().parents[2]
-DATA_DIRS = ("configs", "mixes", "metrics", "readers")
+DATA_DIRS = ("configs", "mixes", "metrics", "readers", "duties")
 RECORDED = REPO / "benchmark/tests/data/tiny.xplane.pb"  # by record_trace.py, on a v5e
 RECORDED_WINDOW_S = 0.8027191162109375  # the session's start to its stop, in that recording
 

@@ -75,11 +75,29 @@ class Checked:
         return [a and b for a, b in zip(sound, rode)]
 
 
-def host_plane(server) -> None:
+class Hinted(Checked):
+    """Checked says nothing of `wave_hints`, so its submitters send none and
+    every window waits out its timer; this one takes the hint and passes it
+    on, as the tenant's own handle does on the chip."""
+
+    wave_hints = True
+
+    async def verify(self, items, deadline=None, wave=None):
+        from charon_tpu import tbls
+
+        sound = tbls.verify_batch(list(items))
+        rode = await self._tenant.verify(items, deadline=deadline, wave=wave)
+        return [a and b for a, b in zip(sound, rode)]
+
+
+def host_plane(server, handle=None, window=0.05, window_max=0.2, plane=None) -> None:
     """Wire the node's submitters (ValidatorAPI, the ParSigEx verifier,
     SigAgg) to a tenant of a CryptoPlaneService over SleepPlane, bridged
     into the node's own tracer as app/run.build_node does for a real
-    plane. `server.coalescer` stays None: run.py loads no program."""
+    plane. `server.coalescer` stays None: run.py loads no program.
+    `handle` is the class the submitters hold the tenant through (Checked;
+    Hinted passes the wave hints on), `plane` the plane's (SleepPlane); the
+    windows default to 50 / 200 ms."""
     from charon_tpu.app import tracer
     from charon_tpu.core.cryptoplane import SlotCoalescer
     from charon_tpu.core.cryptosvc import CryptoPlaneService
@@ -93,10 +111,10 @@ def host_plane(server) -> None:
         bridge(stats)
 
     coalescer = SlotCoalescer(
-        SleepPlane(server.plan.threshold), window=0.05, window_max=0.2,
+        (plane or SleepPlane)(server.plan.threshold), window=window, window_max=window_max,
         decode_workers=2, stats_hook=stats_hook)
     service = CryptoPlaneService(coalescer, tracer=node.tracer)
-    plane = Checked(service.register("rehearsal"))
+    plane = (handle or Checked)(service.register("rehearsal"))
     parsigex = node.p2p._handlers[PARSIGEX_PROTOCOL].__self__.local
     node.vapi.plane = parsigex.verifier.plane = node.sigagg.plane = plane
     node.sigagg.pubshares_by_idx = node.pubshares_by_idx

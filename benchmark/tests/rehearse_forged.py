@@ -9,7 +9,11 @@ failure every lane alone, each announced through `on_program`: `python
 benchmark/tests/rehearse_forged.py [run.py's own options]`. Every wave's
 verify flush then fails its first tier and is attributed; operator 2's set is
 dropped whole and billed, the other three pass; every duty is made from
-exactly t partials on share indices 1, 3, 4. After the run's last line it
+exactly t partials on share indices 1, 3, 4. (The plane's own parsed program
+has answered per SET inside the first tier since PR 36, and the chip's cell
+no longer reaches the second; the two-tier path rehearsed here is what a
+failing segment of more than one set, and the unparsed twin, still take.)
+After the run's last line it
 prints ONE more stdout line, for the tests: the node's own spans, every
 flush's FlushStats fields, what was billed to whom, every verify job's lanes
 beside the answers it got, and every recombine row beside its aggregate."""
@@ -29,16 +33,13 @@ CELL = "rehearsal-byz.attest-forged"
 
 
 def make_root(tmp: Path) -> Path:
-    """helpers.make_root's tiny 3-of-4 configuration with the per-lane
-    program on its list (`check_programs` refuses the mix without it), under
-    the benchmark's own `attest-forged` mix, reporting every per-layer metric
-    the manifest lists for the chip's cell."""
+    """helpers.make_root's tiny 3-of-4 configuration under the benchmark's
+    own `attest-forged` mix, reporting every per-layer metric the manifest
+    lists for the chip's cell."""
     from benchmark.tests import helpers
 
     root = helpers.make_root(tmp, rehearsal=True)
-    config = dict(helpers.REHEARSAL, name="rehearsal-byz",
-                  programs=["verify_rlc_dec@16", "step_rlc_dec@4", "verify_dec@16",
-                            "g1dec@512"])
+    config = dict(helpers.REHEARSAL, name="rehearsal-byz")
     (root / "benchmark" / "configs" / "rehearsal-byz.json").write_text(json.dumps(config))
     manifest = json.loads((root / "BENCHMARK.json").read_text())
     manifest["configs"].append({
@@ -59,7 +60,6 @@ def main(argv) -> int:
     from benchmark.tests import attribution, helpers, planepatch
     from charon_tpu import tbls
     from charon_tpu.app import tracer
-    from charon_tpu.core import cryptoplane
     from charon_tpu.crypto import g1g2
 
     verdicts: dict[bytes, bool] = {}  # signature -> what the process's tbls says
@@ -104,26 +104,17 @@ def main(argv) -> int:
 
     built = {}
 
-    class CellWindows(cryptoplane.SlotCoalescer):
-        """planepatch arms windows of 50 / 200 ms, which a loaded CPU's
-        decode outlasts (a wave then splits, and only the flush holding the
-        forged set is attributed); the cell's configuration arms 0.3 / 0.6 s.
-        A whole wave closes its window at once either way."""
-
-        def __init__(self, plane, **kw):
-            super().__init__(plane, **dict(kw, window=0.3, window_max=0.6))
-
     def host_plane(server):
         def handle(tenant):  # what the node's submitters will hold
             built["handle"] = attribution.Recorded(Hinted(tenant))
             return built["handle"]
 
-        planepatch.Checked, planepatch.SleepPlane = handle, TieredPlane
-        cryptoplane.SlotCoalescer = CellWindows
-        try:
-            planepatch.host_plane(server)
-        finally:
-            cryptoplane.SlotCoalescer = CellWindows.__base__
+        # planepatch's default windows of 50 / 200 ms are outlasted by a
+        # loaded CPU's decode (a wave then splits, and only the flush holding
+        # the forged set is attributed); the cell's configuration arms
+        # 0.3 / 0.6 s. A whole wave closes its window at once either way.
+        planepatch.host_plane(server, handle=handle, window=0.3, window_max=0.6,
+                              plane=TieredPlane)
         built["run"], built["node"] = server.run, server.node
 
     helpers.fake_trace()

@@ -53,26 +53,10 @@ def main(argv) -> int:
     from benchmark.tests import helpers, planepatch
     from charon_tpu.app import tracer
 
-    class Hinted(planepatch.Checked):
-        """planepatch.Checked says nothing of `wave_hints`, so its
-        submitters send none and every window waits out its timer; this
-        one takes the hint and passes it on, as the tenant's own handle
-        does on the chip."""
-
-        wave_hints = True
-
-        async def verify(self, items, deadline=None, wave=None):
-            from charon_tpu import tbls
-
-            sound = tbls.verify_batch(list(items))
-            rode = await self._tenant.verify(items, deadline=deadline, wave=wave)
-            return [a and b for a, b in zip(sound, rode)]
-
     built = {}
 
     def host_plane(server):
-        planepatch.Checked = Hinted
-        planepatch.host_plane(server)
+        planepatch.host_plane(server, handle=planepatch.Hinted)
         built["run"] = server.run
 
     helpers.fake_trace()

@@ -118,7 +118,7 @@ def test_the_bucket_precheck_rejects_a_33_duty_slot():
     cell = M.load_cell(REPO, CELLS[0])
     cfg = dict(cell.config, validators=1056)  # 33 duties in every slot
     plan = T.make_plan(cfg, cell.traffic, 5)
-    with pytest.raises(T.TrafficError, match=r"step_rlc_dec@64.*duties a slot \[33\]"):
+    with pytest.raises(T.TrafficError, match=r"step_rlc_dec@64.*duties a slot \{'attester': \[33\]\}"):
         T.check_programs(plan, cfg)
 
 
@@ -127,7 +127,7 @@ def test_the_bucket_precheck_rejects_a_3of4_wave_of_129_lanes():
     cfg = dict(cell.config, validators=43 * 32)
     mix = dict(cell.traffic, silent_operators=[2], fault={"kind": "none"})
     plan = T.make_plan(cfg, mix, 5)  # three sets a wave, 43 duties: 129 lanes
-    with pytest.raises(T.TrafficError, match=r"verify_rlc_dec@256.*lanes \[129\]"):
+    with pytest.raises(T.TrafficError, match=r"verify_rlc_dec@256.*lanes \{'attester': \[129\]\}"):
         T.check_programs(plan, cfg)
 
 
@@ -431,3 +431,35 @@ def test_a_window_that_is_no_whole_number_of_slots_is_refused_before_boot():
         capture_output=True, text=True, timeout=60, cwd=str(REPO))
     assert proc.returncode != 0
     assert "whole number" in _last_line(proc.stdout)["error"]
+
+
+def test_the_end_of_the_trace_is_started_where_the_program_returns():
+    """serve.hook_plane's program hook tells `on_program_end` at once, on the
+    dispatching thread and before the node's own hook: run.py starts the end
+    of the profiler's session there, so that the wave's next program finds
+    the device tracer stopped (README.md "--trace 1")."""
+    import types
+
+    from benchmark import run as runlib, serve as servelib
+
+    order = []
+    plane = types.SimpleNamespace(
+        on_program=lambda family, seconds, lanes: order.append(("node", family)),
+        bucket_lanes=lambda lanes: 16)
+    fake = types.SimpleNamespace(
+        coalescer=types.SimpleNamespace(plane=plane, warmup_hook=None, stats_hook=None),
+        run=types.SimpleNamespace(programs=[], flushes=[]), warm_stats=[],
+        allowed={"verify_rlc_dec@16"}, wd=None,
+        on_program_end=lambda family, at: order.append(("harness", family, at)))
+    servelib.Server.hook_plane(fake)
+    before = time.time()
+    plane.on_program("mesh/verify_rlc_dec", 0.7, 12)
+    assert [o[:2] for o in order] == [("harness", "verify_rlc_dec"), ("node", "mesh/verify_rlc_dec")]
+    assert before <= order[0][2] <= time.time()
+    assert fake.run.programs == [("verify_rlc_dec", 0.7, 12, order[0][2])]
+    fake.on_program_end = None  # an untraced run: nobody listens
+    plane.on_program("mesh/verify_rlc_dec", 0.7, 12)
+    assert len(order) == 3 and len(fake.run.programs) == 2
+    # the first wave, to its verify program, held longer than the device tracer needs
+    assert (runlib.TRACED_WAVE, runlib.TRACE_UNTIL) == (0, "verify")
+    assert 0.02 <= runlib.TRACE_HOLD <= 0.05

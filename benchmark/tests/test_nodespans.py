@@ -20,6 +20,9 @@ sys.path.insert(0, str(REPO))
 
 from benchmark import manifest as M, nodespans, tracered  # noqa: E402
 from benchmark.tests import helpers  # noqa: E402
+# the duty kinds' tests: tier-1 collects tests/ alone, and this file's tests
+# come in there through tests/test_node_spans.py's `import *` — so do these
+from benchmark.tests.test_duties import *  # noqa: E402,F401,F403
 from charon_tpu.app import tracer  # noqa: E402
 
 TRACE = "c" * 32

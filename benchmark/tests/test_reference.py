@@ -177,10 +177,11 @@ def test_cross_check_the_programs_ssz_agrees_on_a_plans_attestations():
 
     cell = manifest.load_cell(REPO, "dv-4of7-1k.attest-slot")
     plan = traffic.make_plan(cell.config, cell.traffic, 2147483659)
+    attester = manifest.load_duty("attester")
     fork = ForkInfo(genesis_validators_root=hashlib.sha256(b"gvr").digest(),
                     fork_version=bytes(4), genesis_fork_version=bytes(4))
     for slot, ci in ((0, 0), (33, 5), (1000, 30)):
-        f = plan.attestation_fields(slot, ci)
+        f = attester.fields(plan, slot, ci)
         data = AttestationData(slot=f[0], index=f[1], beacon_block_root=f[2],
                                source=Checkpoint(f[3], f[4]), target=Checkpoint(f[5], f[6]))
         assert data.hash_tree_root() == R.attestation_data_root(f)

@@ -1,18 +1,22 @@
 """The one general traffic generator: a mix is a data file
 (mixes/<name>.json), this turns it and --seed into a plan.
 
-The plan fixes, before anything boots: which validators attest in which
-slot of the epoch (a seeded permutation; every validator once an epoch,
-spread evenly, so a slot has floor(v/spe) or ceil(v/spe) duties for every
-seed), the per-peer send jitter, the fault (none / flip_byte / wrong_key)
-and the silent operators — and from those every (family, bucket) a whole
-wave's flushes can land on, which must be the configuration's programs."""
+The plan fixes, before anything boots: the kinds of duty the mix names
+(each a module, duties/<kind>.py, found by name), the validators' seeded
+order (a permutation: a slot's share of it has floor(v/spe) or ceil(v/spe)
+validators for every seed), from which every kind draws who holds its duty
+in which slot, the per-peer send jitter, the fault (none / flip_byte /
+wrong_key) and the silent operators — and from those every (family, bucket)
+a whole wave's flushes can land on, which must be the configuration's
+programs."""
 
 from __future__ import annotations
 
 import dataclasses
 import hashlib
 import random
+
+from benchmark import manifest
 
 
 class TrafficError(ValueError):
@@ -31,6 +35,7 @@ class Fault:
     operator: int  # 1-based share index of the forging peer (0: none)
     slots: str  # last | all
     partials: int  # forged partials per forged set
+    duties: tuple[str, ...] = ()  # the kinds whose sets are forged (none named: all)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -46,9 +51,14 @@ class Plan:
     fault: Fault
     silent: tuple[int, ...]  # 1-based share indices that never send
     rank: tuple[int, ...]  # validator index -> rank in the seeded order
+    # the mix's duty modules, in its order, and the configuration file (a
+    # kind may read sizes of its own there); both follow from the fields above
+    kinds: tuple = dataclasses.field(default=(), compare=False, repr=False)
+    sizes: dict = dataclasses.field(default_factory=dict, compare=False, repr=False)
 
     def members(self, slot: int) -> list[int]:
-        """Validator indices attesting in `slot`, in committee order."""
+        """The validators of `slot`'s share of the seeded order, every
+        validator in one slot of the epoch, in the order's order."""
         pos = slot % self.slots_per_epoch
         chosen = [(r, v) for v, r in enumerate(self.rank)
                   if r % self.slots_per_epoch == pos]
@@ -67,48 +77,46 @@ class Plan:
         return self.jitter_s * int.from_bytes(digest[:8], "big") / 2**64
 
     def block_root(self, *parts) -> bytes:
+        """A root of the seeded chain, the same on every operator's beacon."""
         return hashlib.sha256(
             ("att/%d/" % self.seed + "/".join(str(p) for p in parts)).encode()
         ).digest()
 
-    def attestation_fields(self, slot: int, committee_index: int) -> tuple:
-        """The raw fields of the slot's AttestationData, the same on every
-        operator's beacon: (slot, index, beacon block root, source epoch,
-        source root, target epoch, target root). The program's objects
-        and the plain reference's signing root are both made from these."""
-        epoch = slot // self.slots_per_epoch
-        return (slot, committee_index, self.block_root("block", slot),
-                max(0, epoch - 1), self.block_root("cp", epoch - 1),
-                epoch, self.block_root("cp", epoch))
-
-    def forged(self, slot: int, share_idx: int, last_slot: int) -> bool:
+    def forged(self, slot: int, share_idx: int, last_slot: int, kind: str | None = None) -> bool:
         if self.fault.kind == "none" or share_idx != self.fault.operator:
+            return False
+        if self.fault.duties and kind not in self.fault.duties:
             return False
         return self.fault.slots == "all" or slot == last_slot
 
+    def wave_shapes(self, duties: int) -> set[str]:
+        """The `family@bucket` of a whole wave of `duties` duties, one
+        partial each from every speaking operator: one verify flush of
+        their sets, one recombine flush of the duties (decode on the
+        device). A forged partial, flipped or well formed, is answered
+        inside the verify program (per set since PR 36): no further shape."""
+        if duties == 0:
+            return set()
+        return {f"verify_rlc_dec@{bucket_lanes(duties * self.senders())}",
+                f"step_rlc_dec@{bucket_lanes(duties)}"}
+
     def flush_shapes(self) -> set[str]:
-        """Every `family@bucket` a WHOLE wave's flushes land on: one
-        verify flush of every speaking operator's set, one recombine
-        flush of the slot's duties (decode on the device)."""
-        shapes = set()
-        for pos in range(self.slots_per_epoch):
-            d = self.duties_in(pos)
-            if d == 0:
-                continue
-            shapes.add(f"verify_rlc_dec@{bucket_lanes(d * self.senders())}")
-            shapes.add(f"step_rlc_dec@{bucket_lanes(d)}")
-            if self.fault.kind == "wrong_key":
-                # a well-formed forgery fails the RLC tier: attribution
-                shapes.add(f"verify_dec@{bucket_lanes(d * self.senders())}")
-        return shapes
+        """Every `family@bucket` a WHOLE wave lands on, of each of the
+        mix's kinds alone. Two kinds whose flushes merge in one window
+        land outside this set, and the run then fails fast."""
+        return set().union(*(kind.shapes(self) for kind in self.kinds))
 
 
-def make_plan(config: dict, traffic: dict, seed: int) -> Plan:
+def make_plan(config: dict, traffic: dict, seed: int, bdir=None) -> Plan:
+    """`bdir` is the benchmark's directory, where duties/<kind>.py are
+    looked for (default: beside this file)."""
     duties = tuple(traffic.get("duties", ()))
-    if duties != ("attester",):
-        raise TrafficError(
-            f"traffic {traffic.get('name')!r}: duties {list(duties)} — only "
-            "the attester wave is generated yet")
+    if not duties or len(set(duties)) != len(duties):
+        raise TrafficError(f"traffic {traffic.get('name')!r}: duties {list(duties)}")
+    try:
+        kinds = tuple(manifest.load_duty(name, bdir) for name in duties)
+    except manifest.ManifestError as e:
+        raise TrafficError(f"traffic {traffic.get('name')!r}: {e}") from e
     n, t = int(config["operators"]), int(config["threshold"])
     v, spe = int(config["validators"]), int(config["slots_per_epoch"])
     f = traffic.get("fault") or {"kind": "none"}
@@ -122,6 +130,8 @@ def make_plan(config: dict, traffic: dict, seed: int) -> Plan:
     silent = tuple(sorted(int(i) for i in traffic.get("silent_operators", ())))
     if any(not 2 <= i <= n for i in silent) or operator in silent:
         raise TrafficError(f"silent operators {silent}: peers are 2..{n}")
+    if not set(f.get("duties", ())) <= set(duties):
+        raise TrafficError(f"fault duties {f['duties']}: the mix's are {list(duties)}")
     forgers = 1 if kind != "none" else 0
     if n - len(silent) - forgers < t:
         raise TrafficError("fewer than t honest operators speak: no duty completes")
@@ -134,8 +144,9 @@ def make_plan(config: dict, traffic: dict, seed: int) -> Plan:
         seed=seed, operators=n, threshold=t, validators=v, slots_per_epoch=spe,
         slot_duration=float(config["slot_duration_s"]), duties=duties,
         jitter_s=float(traffic.get("send_jitter_ms", 0)) / 1000.0,
-        fault=Fault(kind, operator, f.get("slots", "last"), int(f.get("partials", 1))),
-        silent=silent, rank=tuple(rank),
+        fault=Fault(kind, operator, f.get("slots", "last"), int(f.get("partials", 1)),
+                    tuple(f.get("duties", ()))),
+        silent=silent, rank=tuple(rank), kinds=kinds, sizes=config,
     )
 
 
@@ -146,9 +157,11 @@ def check_programs(plan: Plan, config: dict) -> None:
     wave_listed = {p for p in listed if not p.startswith("g1dec@")}
     shapes = plan.flush_shapes()
     if shapes != wave_listed:
-        per_slot = sorted({plan.duties_in(p) for p in range(plan.slots_per_epoch)})
+        per_slot = {kind.NAME: sorted({len(kind.members(plan, p))
+                                       for p in range(plan.slots_per_epoch)})
+                    for kind in plan.kinds}
+        lanes = {name: [d * plan.senders() for d in sizes] for name, sizes in per_slot.items()}
         raise TrafficError(
             f"the traffic's whole waves land on {sorted(shapes)} but the "
             f"configuration compiles {sorted(wave_listed)}: duties a slot "
-            f"{per_slot}, {plan.senders()} sets a wave "
-            f"(lanes {[d * plan.senders() for d in per_slot]})")
+            f"{per_slot}, {plan.senders()} sets a wave (lanes {lanes})")
