@@ -139,7 +139,10 @@ class ClusterMetrics:
         )
         self.plane_flushes = counter(
             "tpu_plane_flushes_total",
-            "Crypto-plane coalescer flushes (device program launches)",
+            "Crypto-plane coalescer flushes (device program launches), "
+            "by the kind of duty whose jobs the flush held (a flush "
+            "holds one kind; none = jobs that named no duty)",
+            ["duty_type"],
         )
         self.plane_coalesced = counter(
             "tpu_plane_coalesced_flushes_total",
@@ -147,7 +150,22 @@ class ClusterMetrics:
         )
         self.plane_lanes = counter(
             "tpu_plane_lanes_total",
-            "Crypto lanes executed through the coalesced plane",
+            "Crypto lanes executed through the coalesced plane, by the "
+            "kind of duty",
+            ["duty_type"],
+        )
+        self.plane_window_parts = counter(
+            "tpu_plane_window_parts_total",
+            "Flushes that left their coalescing window beside another "
+            "kind of duty's flush, closed in the same instant (each on "
+            "its own bucket); 0 while the kinds close apart",
+        )
+        self.plane_lane_yielded = counter(
+            "tpu_plane_lane_yielded_seconds_total",
+            "Seconds packed flushes yielded their device turn to a more "
+            "urgent kind of duty still collecting or being packed, by the "
+            "yielding flush's kind and the kind it yielded to",
+            ["duty_type", "to"],
         )
         self.plane_windows_closed = counter(
             "tpu_plane_windows_closed_total",
@@ -200,8 +218,9 @@ class ClusterMetrics:
         # decode-pool queueing, bucket-padding waste, device-lane depth
         self.plane_flush_seconds = Histogram(
             "tpu_plane_flush_seconds",
-            "Device-lane wall clock per coalescer flush (pack excluded)",
-            labels,
+            "Device-lane wall clock per coalescer flush (pack excluded), "
+            "by the kind of duty",
+            labels + ["duty_type"],
             registry=self.registry,
             buckets=(0.001, 0.005, 0.02, 0.05, 0.1, 0.5, 2.0, 10.0, 60.0),
         )

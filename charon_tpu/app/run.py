@@ -499,10 +499,22 @@ async def build_node(config: Config) -> Node:
         # one rich per-flush stats hook (runs on the device worker
         # thread — prometheus client objects are thread-safe)
         def _plane_stats(s) -> None:  # chained behind the span bridge
-            metrics.labels(metrics.plane_flushes).inc()
+            # the kind of duty the flush held (core/cryptoplane "One
+            # kind a flush"): a program's seconds can be given to a kind
+            kind = "+".join(s.duty_types) or "none"
+            metrics.labels(metrics.plane_flushes, kind).inc()
             if s.jobs >= 2:
                 metrics.labels(metrics.plane_coalesced).inc()
-            metrics.labels(metrics.plane_lanes).inc(s.lanes)
+            metrics.labels(metrics.plane_lanes, kind).inc(s.lanes)
+            if s.window_parts > 1:
+                # counted once a part: the family's rate over the
+                # flushes' is the share of flushes that left beside
+                # another kind's
+                metrics.labels(metrics.plane_window_parts).inc()
+            if s.turn_yielded_s:
+                metrics.labels(
+                    metrics.plane_lane_yielded, kind, s.turn_yielded_to
+                ).inc(s.turn_yielded_s)
             if s.window_closed_by:  # a host-fallback flush names none
                 metrics.labels(
                     metrics.plane_windows_closed, s.window_closed_by
@@ -521,7 +533,7 @@ async def build_node(config: Config) -> Node:
                 metrics.labels(metrics.plane_lanes_invalid).inc(
                     s.lanes_invalid
                 )
-            metrics.labels(metrics.plane_flush_seconds).observe(
+            metrics.labels(metrics.plane_flush_seconds, kind).observe(
                 s.flush_seconds
             )
             metrics.labels(metrics.plane_lanes_per_flush).observe(s.lanes)

@@ -472,7 +472,12 @@ def plane_span_bridge(
     `cryptoplane.attribute`: the per-lane program's dispatch, with the
     lanes it was given and the lanes and sets it found invalid. One
     whose RLC tier refused a set and answered for it whole — no
-    per-lane dispatch, no such span — says `set_resolved`.
+    per-lane dispatch, no such span — says `set_resolved`. The window,
+    the flush and its device stage say `duty_types`, the kind of duty
+    whose jobs the flush held; the window says in how many `parts` its
+    close was dispatched; a flush that let a more urgent kind take the
+    device first says for how long and to which (`yielded`,
+    `yielded_to`).
 
     A flush coalesces submissions from several spans of several duties;
     `stats.parents` carries each submission's captured span context, and
@@ -518,8 +523,16 @@ def plane_span_bridge(
             stages.append(
                 ("cryptoplane.pack", *stats.pack_span, {})
             )
+        # the kind of duty the flush held (one: core/cryptoplane "One
+        # kind a flush"), so that a program's seconds can be given to a
+        # kind; absent where no job named a duty
+        kind = (
+            {"duty_types": ",".join(stats.duty_types)}
+            if getattr(stats, "duty_types", ())
+            else {}
+        )
         if stats.device_span is not None:
-            device_attrs = {"fallback": stats.fallback}
+            device_attrs = {"fallback": stats.fallback, **kind}
             if programs is not None:
                 device_attrs["programs"] = ",".join(programs())
             stages.append(
@@ -538,7 +551,12 @@ def plane_span_bridge(
             "fallback": stats.fallback,
             "attributed": getattr(stats, "attributed", False),
             "set_resolved": getattr(stats, "set_resolved", False),
+            **kind,
         }
+        if getattr(stats, "turn_yielded_s", 0.0):
+            # packed, it let a more urgent kind's flush go first
+            flush_attrs["yielded"] = round(stats.turn_yielded_s, 4)
+            flush_attrs["yielded_to"] = stats.turn_yielded_to
         if stats.padded_lanes:
             flush_attrs["bucket"] = stats.padded_lanes
             flush_attrs["pad_lanes"] = stats.pad_lanes
@@ -559,9 +577,13 @@ def plane_span_bridge(
         # operators down, not unhinted traffic — on a `timer` close
         # their first slot out (still awaited), on a `complete` one
         # with sets_awaited < sets_expected every slot after it
+        # parts: the flushes its close dispatched (more than 1 where
+        # several kinds of duty closed in the same instant)
         window_attrs = {
             "verify_jobs": stats.verify_jobs,
             "recombine_jobs": stats.recombine_jobs,
+            "parts": getattr(stats, "window_parts", 1),
+            **kind,
         }
         if stats.sets_expected is not None:
             window_attrs["sets_expected"] = stats.sets_expected

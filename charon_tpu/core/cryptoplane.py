@@ -59,8 +59,9 @@ What closes a window (`FlushStats.window_closed_by`, the
     `awaited` from one roster (core/parsigex.WaveRoster): the operators
     whose set of the newest earlier slot of that duty type reached the
     verifier, all n before any has. The window keeps a ledger per
-    (kind, key): jobs seen, the largest count hinted, the senders
-    present and the UNION of the senders awaited. A wave is whole when
+    (family, key), family being verify or recombine: jobs seen, the
+    largest count hinted, the senders present and the UNION of the
+    senders awaited. A wave is whole when
     it holds the jobs counted and a job of every awaited sender; the
     set of a sender that was not awaited (an operator back from an
     outage) rides along in the same flush if it is already there. As
@@ -94,7 +95,44 @@ What closes a window (`FlushStats.window_closed_by`, the
   * "deadline" / "pulled_earlier" — a submission carrying a duty
     deadline (core/deadline.SlotClock.duty_deadline) armed the window
     already capped, or pulled an armed one earlier, so near-deadline
-    work never waits out a grown window.
+    work never waits out a grown window. The graded cap
+    (`DEADLINE_WINDOW_FRAC` of what the duty has left) is for jobs that
+    do not say whom they wait for. A wave whose hints NAME the senders
+    still awaited has its window for them and is capped by the deadline
+    itself alone: it leaves the moment they are in, and cut short it
+    would leave in two, the trailing set alone on a bucket of its own.
+
+One kind a flush (`FlushStats.duty_types`, `duty_types` on the
+`cryptoplane.window` / `.flush` / `.device` spans): the wave keys say
+what duty each job belongs to — every key the node's submitters send
+holds a core/types.Duty, and its `type` is the job's kind, whatever
+types there are ("" for a job that named none). Two kinds of duty
+triggered at the same instant (attestations and sync-committee messages
+are both due at 1/3 slot) share the armed window and nothing else: each
+kind has its own timer, from ITS first job, and leaves as a flush of
+its own the moment ITS waves are whole ("complete") or its timer runs
+out — on its own bucket, never on the bucket of the sum, never waiting
+for the other kind's sets. One kind in the window is the same code with
+one timer. A kind's verify and recombine jobs that do meet in the
+window still leave as one flush, as they always did.
+
+The device's order is a rule, not a race. Flushes that are ready for
+the device lane together are taken most urgent first: the earliest duty
+deadline, then the fewest lanes (`_urgency`), so the smaller wave's
+program does not sit out the larger one's. And a packed flush yields
+its turn while a more urgent flush of ANOTHER kind is on its way to the
+lane — that kind's window is armed, a submission of it is still on the
+decode pool (it carries its deadline, its lanes and the sets its wave
+awaits from the moment it is made), or it is closed and being packed
+(`_yield_turn`, `FlushStats.turn_yielded_s` / `.turn_yielded_to`,
+`yielded` / `yielded_to` on `cryptoplane.flush`): which wave's last set
+came 20 ms sooner does not decide the order on the device. It yields
+for the other kind's own timer at most, never to a window that holds no
+set, never to its own kind; its own window closed `complete` when its
+wave was whole, as ever. One kind of duty in the window never waits.
+A close that
+sent off several kinds says so: `FlushStats.window_parts`, `parts` on
+`cryptoplane.window`, `SlotCoalescer.windows_split`.
 
 The window's length is adaptive: it grows toward `window_max` under
 sustained multi-job load (catch more of the burst per program) and
@@ -116,6 +154,8 @@ fall back to the single-stage host API on the device lane.
 from __future__ import annotations
 
 import asyncio
+import heapq
+import threading
 import time
 from dataclasses import dataclass, field
 from typing import NamedTuple, Sequence
@@ -150,6 +190,8 @@ class _VerifyJob:
     decode_hashed: tuple = ()  # message-cache misses per decode chunk
     parent: tuple | None = None  # submitter's (trace_id, span_id)
     tenant: str | None = None  # submitting tenant (core/cryptosvc)
+    kind: str = ""  # the duty types its wave keys name (_kind_of)
+    deadline: float | None = None  # wall clock, as submitted
 
 
 @dataclass
@@ -167,6 +209,8 @@ class _RecombineJob:
     decode_hashed: tuple = ()
     parent: tuple | None = None
     tenant: str | None = None
+    kind: str = ""
+    deadline: float | None = None
 
 
 @dataclass(frozen=True)
@@ -232,6 +276,19 @@ class FlushStats:
     sets_awaited: int | None = None
     # closed "complete" with sets_awaited < sets_expected
     window_closed_short: bool = False
+    # the duty types the flush's jobs named in their wave keys (a flush
+    # holds ONE kind of duty: module docstring "One kind a flush"; more
+    # than one only where a single job spans them; () where no job
+    # named any), and how many flushes the close that made this one
+    # dispatched: 1 unless several kinds closed in the same instant
+    duty_types: tuple[str, ...] = ()
+    window_parts: int = 1
+    # seconds the packed flush yielded its device turn to a more urgent
+    # kind's flush on its way to the lane, and which kind(s) that was
+    # (`_yield_turn`; 0.0 and "" on every flush that had nobody to yield
+    # to: one kind in the window, always)
+    turn_yielded_s: float = 0.0
+    turn_yielded_to: str = ""
     # the verify tiers: `attributed` where the RLC product over the
     # flush's lanes failed and the plane re-dispatched them through its
     # per-lane program (one pairing check a lane: `attribute_span` is
@@ -278,6 +335,9 @@ class _Window(NamedTuple):
     sets_expected: int | None = None  # FlushStats, same names
     sets_seen: int | None = None
     sets_awaited: int | None = None
+    parts: int = 1  # FlushStats.window_parts
+    yielded: float = 0.0  # FlushStats.turn_yielded_s
+    yielded_to: str = ""  # FlushStats.turn_yielded_to
 
     @property
     def closed_short(self) -> bool:
@@ -291,9 +351,10 @@ class _Window(NamedTuple):
 
 @dataclass
 class _Wave:
-    """One (kind, key) of the armed window's ledger (module docstring
+    """One (family, key) of the armed window's ledger (module docstring
     "What closes a window")."""
 
+    kind: str = ""  # the duty types its key names: whose timer it is on
     seen: int = 0  # jobs in the window
     expected: int = 0  # jobs its submitters expect at most: a count, or n
     counted: int = 0  # the largest plain count hinted
@@ -322,6 +383,63 @@ class _Wave:
     @property
     def whole(self) -> bool:
         return self.seen >= self.counted and self.awaited <= self.present
+
+
+@dataclass
+class _Timer:
+    """One kind of duty's share of the armed window: its own timer,
+    from ITS first job (module docstring "One kind a flush")."""
+
+    opened: float  # wall clock: the kind's first job into the window
+    wall_offset: float  # wall->monotonic, snapshotted as the timer arms
+    flush_at: float = 0.0  # monotonic: where its timer runs out
+    # what running out is called: timer | deadline | pulled_earlier
+    closed_by: str = "timer"
+    queue_deadline: float | None = None  # monotonic, min over its jobs
+    closing: bool = False  # its close has begun (_close_kind): not judged again
+
+
+class _Decoding(NamedTuple):
+    """A submission still on the decode pool, as far as the window can
+    tell what it will be (one value of `_decode_tickets`)."""
+
+    kind: str
+    deadline: float | None  # wall clock, as submitted
+    lanes: int
+    awaits: int  # jobs its wave hints wait for (1 where it named none)
+
+
+def _key_duty_type(key) -> str:
+    """The duty type a wave key names: the `type` of the first thing in
+    it that has one (core/types.Duty; a key is a duty, or a tuple that
+    holds one, however its submitter or a tenant wrapped it)."""
+    found = getattr(key, "type", None)
+    if found is not None:
+        return str(found)
+    if isinstance(key, tuple):
+        for part in key:
+            found = _key_duty_type(part)
+            if found:
+                return found
+    return ""
+
+
+def _hint_awaits(wave) -> int:
+    """Jobs the wave(s) a submission named wait for, by its own hints."""
+    return max(
+        (
+            expected if isinstance(expected, int) else len(expected.awaited)
+            for _key, expected in wave or ()
+        ),
+        default=1,
+    )
+
+
+def _kind_of(wave) -> str:
+    """The kind of duty a submission belongs to, read off the wave keys
+    it came with: their duty types (one, in every submission the node
+    makes), "" where it named none or carried no hint."""
+    return "+".join(sorted({_key_duty_type(key) for key, _ in wave or ()} - {""}))
 
 
 class PlaneConfigError(ValueError):
@@ -450,7 +568,8 @@ class SlotCoalescer:
     path, kept for A/B benching). The pool is created lazily on first
     use, so an idle or disabled plane owns no threads.
     flushes / coalesced_flushes / lanes_flushed / windows_closed (by
-    cause) / windows_closed_short / flushes_attributed /
+    cause) / windows_closed_short / windows_split / turns_yielded /
+    flushes_attributed /
     flushes_set_resolved / lanes_invalid:
     observability counters (exported as node metrics by app/run.py).
     """
@@ -469,9 +588,11 @@ class SlotCoalescer:
     GROW_LANES = 64
     # graded deadline shrink: spend at most this fraction of the time
     # remaining before the duty deadline on coalescing — with a 60 s
-    # expiry window the cap is inert (plenty of time), but a retrying
-    # near-expiry submission (seconds left) flushes in milliseconds
-    # instead of waiting out a load-grown window
+    # expiry the cap is ~0.56 s at 1/3 slot (far above the default
+    # 20-80 ms windows), and a retrying near-expiry submission (seconds
+    # left) flushes in milliseconds instead of waiting out a load-grown
+    # window. Not applied to a wave that names the senders it still
+    # waits for (`_arm`)
     DEADLINE_WINDOW_FRAC = 0.01
 
     def __init__(
@@ -520,18 +641,30 @@ class SlotCoalescer:
         self._verify_q: list[_VerifyJob] = []
         self._recombine_q: list[_RecombineJob] = []
         self._flush_task: asyncio.Task | None = None
-        self._flush_at: float = 0.0  # monotonic flush target of armed task
         self._flush_wake = asyncio.Event()
-        self._queue_deadline: float | None = None  # monotonic, min over jobs
-        self._window_opened = 0.0  # wall clock: first job into this window
-        self._window_closed_by = "timer"
-        self._wall_offset = 0.0  # wall->monotonic, snapshotted per window
-        # submissions mid-decode (closing windows wait for these)
-        self._decode_tickets: set[asyncio.Future] = set()
-        # the armed window's wave ledger by (kind, key), and how many
-        # of its jobs carried no hint
+        # the armed window's timers, one a kind of duty in it ("" for
+        # jobs that named none): a kind leaves when ITS waves are whole
+        # or ITS timer runs out (module docstring "One kind a flush")
+        self._timers: dict[str, _Timer] = {}
+        # submissions mid-decode, each with its kind (a kind's close
+        # waits for its own) and what it will be (`_collecting_urgency`)
+        self._decode_tickets: dict[asyncio.Future, _Decoding] = {}
+        # the armed window's wave ledger by (family, key), and how many
+        # of its jobs carried no hint (their kind is "")
         self._waves: dict[tuple, _Wave] = {}
         self._unhinted_jobs = 0
+        # flushes between their window's close and their results
+        self._parts: set[asyncio.Task] = set()
+        # flushes packed and waiting for the device lane, which takes
+        # the most urgent first: (deadline, lanes, seq, fn, args, future)
+        self._ready: list[tuple] = []
+        self._ready_lock = threading.Lock()
+        self._ready_seq = 0
+        # flushes between their close and the lane's heap, each with its
+        # urgency, and the flushes that yield their turn to one of those
+        # or to a kind still collecting (`_yield_turn`)
+        self._packing: dict[object, tuple[str, tuple]] = {}
+        self._yielding: list[asyncio.Future] = []
         self._window_current = window
         # first-dispatch gate (app/run.py wires the autotune tune_done
         # event here): the boot-time tuner's trial.apply() flips the
@@ -556,6 +689,10 @@ class SlotCoalescer:
         # of the "complete" ones: verify windows whole on fewer sets
         # than the cluster has operators (the roster awaited fewer)
         self.windows_closed_short = 0
+        # closes that sent off more than one kind, a flush each
+        self.windows_split = 0
+        # flushes that yielded their device turn to a more urgent one
+        self.turns_yielded = 0
         self.coalesced_flushes = 0  # flushes that merged >= 2 jobs
         self.lanes_flushed = 0
         self.flushes_attributed = 0  # fell to the per-lane verify tier
@@ -763,7 +900,10 @@ class SlotCoalescer:
         # whose cold-cache decode outlasts the window would split into
         # one device program per submission (the anti-coalescing bug)
         ticket = loop.create_future()
-        self._decode_tickets.add(ticket)
+        kind = _kind_of(wave)
+        self._decode_tickets[ticket] = _Decoding(
+            kind, deadline, len(items), _hint_awaits(wave)
+        )
         try:
             decode_fn = (
                 _parse_verify_lane
@@ -781,18 +921,23 @@ class SlotCoalescer:
                 decode_hashed=hashed,
                 parent=self._submit_ctx(),
                 tenant=tenant,
+                kind=kind,
+                deadline=deadline,
             )
             self._verify_q.append(job)
-            self._count_wave("verify", wave)
-            self._arm(deadline)
+            self._count_wave("verify", wave, kind)
+            self._arm(deadline, kind)
         finally:
             # resolve AFTER the append above (same synchronous block):
             # the waiting flush wakes only on the next scheduler turn,
             # so the job is guaranteed to be in the collected queue
-            self._decode_tickets.discard(ticket)
+            self._decode_tickets.pop(ticket, None)
             if not ticket.done():
                 ticket.set_result(None)
             self._close_if_whole()
+            # a flush that yields its turn to this kind counted the
+            # submission as on its way: it is in now, or never will be
+            self._lane_moved()
         return await job.fut
 
     async def recombine(
@@ -849,7 +994,10 @@ class SlotCoalescer:
 
         loop = asyncio.get_running_loop()
         ticket = loop.create_future()  # see verify() for the contract
-        self._decode_tickets.add(ticket)
+        kind = _kind_of(wave)
+        self._decode_tickets[ticket] = _Decoding(
+            kind, deadline, len(roots), _hint_awaits(wave)
+        )
         try:
             rows, delays, spans, hashed = await self._map_offloop(
                 decode_row,
@@ -871,15 +1019,20 @@ class SlotCoalescer:
                 decode_hashed=hashed,
                 parent=self._submit_ctx(),
                 tenant=tenant,
+                kind=kind,
+                deadline=deadline,
             )
             self._recombine_q.append(job)
-            self._count_wave("recombine", wave)
-            self._arm(deadline)
+            self._count_wave("recombine", wave, kind)
+            self._arm(deadline, kind)
         finally:
-            self._decode_tickets.discard(ticket)
+            self._decode_tickets.pop(ticket, None)
             if not ticket.done():
                 ticket.set_result(None)
             self._close_if_whole()
+            # a flush that yields its turn to this kind counted the
+            # submission as on its way: it is in now, or never will be
+            self._lane_moved()
         sigs_pts, oks = await job.fut
         return (
             [
@@ -891,24 +1044,26 @@ class SlotCoalescer:
 
     # -- flush machinery ---------------------------------------------------
 
-    def _count_wave(self, kind: str, wave) -> None:
+    def _count_wave(self, family: str, wave, kind: str = "") -> None:
         """Enter the job just appended into the window's wave ledger."""
         if not wave:
             self._unhinted_jobs += 1
             return
         for key, expected in wave:
-            self._waves.setdefault((kind, key), _Wave()).enter(expected)
+            self._waves.setdefault((family, key), _Wave(kind)).enter(expected)
 
-    def _verify_sets(self) -> tuple[int | None, int | None, int | None]:
-        """The armed window's ledger summed over its verify waves:
+    def _verify_sets(
+        self, kind: str = ""
+    ) -> tuple[int | None, int | None, int | None]:
+        """The armed window's ledger summed over `kind`'s verify waves:
         (sets expected, sets seen, sets awaited) — Nones where it held
-        no verify wave or a job without a hint."""
+        none or a job of the kind came without a hint."""
         waves = [
             wave
-            for (kind, _key), wave in self._waves.items()
-            if kind == "verify"
+            for (family, _key), wave in self._waves.items()
+            if family == "verify" and wave.kind == kind
         ]
-        if not waves or self._unhinted_jobs:
+        if not waves or (not kind and self._unhinted_jobs):
             return None, None, None
         return (
             sum(w.expected for w in waves),
@@ -916,88 +1071,162 @@ class SlotCoalescer:
             sum(w.awaits for w in waves),
         )
 
-    def _window_whole(self) -> bool:
-        """Nothing more is waited for in the armed window: every job in
-        it said which wave it belongs to, every such wave holds what it
-        awaits, and no submission is still decoding."""
+    def _kind_whole(self, kind: str) -> bool:
+        """Nothing more is waited for by `kind`'s jobs in the armed
+        window: every one said which wave it belongs to, every such
+        wave holds what it awaits, and no submission of the kind is
+        still decoding. Another kind's waves, short or whole, say
+        nothing here."""
+        waves = [w for w in self._waves.values() if w.kind == kind]
         return bool(
-            self._waves
-            and not self._unhinted_jobs
-            and not self._decode_tickets
-            and all(w.whole for w in self._waves.values())
+            waves
+            and not (not kind and self._unhinted_jobs)
+            and not any(d.kind == kind for d in self._decode_tickets.values())
+            and all(w.whole for w in waves)
+        )
+
+    def _awaits_named(self, kind: str) -> bool:
+        """Some wave of `kind` in the armed window names senders (its
+        submitters' roster) whose set it still waits for."""
+        return any(
+            w.kind == kind and not w.awaited <= w.present
+            for w in self._waves.values()
+        )
+
+    def _window_whole(self) -> bool:
+        """Some kind in the armed window is whole: its flush can leave."""
+        return any(
+            self._kind_whole(kind)
+            for kind, timer in self._timers.items()
+            if not timer.closing
         )
 
     def _close_if_whole(self) -> None:
-        """Wake the flush task when the window's waves are whole; it
-        judges again when it runs, so a job that joins in between keeps
-        the window open for ITS wave. Otherwise the window closes as it
-        always did: timer, deadline, pulled earlier."""
+        """Wake the flush task when a kind's waves are whole; it judges
+        again when it runs, so a job that joins in between keeps its
+        kind waiting for ITS wave. Otherwise a kind leaves as windows
+        always closed: timer, deadline, pulled earlier."""
         if self._window_whole():
             self._flush_wake.set()
 
-    def _arm(self, deadline: float | None = None) -> None:
+    @property
+    def _flush_at(self) -> float | None:
+        """Monotonic: where the first of the armed timers runs out (None:
+        every kind in the window is already closing)."""
+        return min(
+            (t.flush_at for t in self._timers.values() if not t.closing),
+            default=None,
+        )
+
+    def _arm(self, deadline: float | None = None, kind: str = "") -> None:
         now = time.monotonic()
-        new_window = self._flush_task is None or self._flush_task.done()
-        if new_window:
+        timer = self._timers.get(kind)
+        if timer is None:
             # duty deadlines are wall-clock (core/deadline.SlotClock)
             # but the flush timer runs on the monotonic base — snapshot
-            # the wall->monotonic offset ONCE per window. Converting per
+            # the wall->monotonic offset ONCE per timer. Converting per
             # call meant a host clock step mid-window (chaos clock-skew)
             # translated later submissions' deadlines inconsistently,
             # wrongly collapsing or stretching the armed window.
-            self._wall_offset = now - time.time()  # lint: allow(monotonic-clock) — THE one-shot wall->mono anchor (PR 8 fix)
+            # the timer's `opened` is its span's start
+            # (FlushStats.window_span): trace attribution on the wall
+            # clock, never math
+            wall = time.time()  # lint: allow(monotonic-clock) — THE one-shot wall->mono anchor (PR 8 fix)
+            timer = _Timer(opened=wall, wall_offset=now - wall)
         if deadline is not None:
-            dl_mono = max(now, deadline + self._wall_offset)
-            if self._queue_deadline is None or dl_mono < self._queue_deadline:
-                self._queue_deadline = dl_mono
+            dl_mono = max(now, deadline + timer.wall_offset)
+            if timer.queue_deadline is None or dl_mono < timer.queue_deadline:
+                timer.queue_deadline = dl_mono
         target = now + self._window_current
-        if self._queue_deadline is not None:
-            # graded shrink toward the deadline, never below window_min
-            # (give concurrent submissions a beat to coalesce regardless)
-            remaining = self._queue_deadline - now
-            cap = max(
-                self.window_min, remaining * self.DEADLINE_WINDOW_FRAC
-            )
+        if timer.queue_deadline is not None:
+            remaining = timer.queue_deadline - now
+            if self._awaits_named(kind):
+                # its waves' roster NAMES the senders still waited for:
+                # the window is theirs to arrive in, and the moment they
+                # have the kind leaves "complete". Cutting it short on a
+                # share of what the duty has left would send the wave off
+                # in two, the straggler on a bucket of its own. Never
+                # past the deadline itself
+                cap = remaining
+            else:
+                # graded shrink toward the deadline, never below
+                # window_min (give concurrent submissions a beat to
+                # coalesce regardless)
+                cap = max(
+                    self.window_min, remaining * self.DEADLINE_WINDOW_FRAC
+                )
             target = min(target, now + cap)
-        if new_window:
-            self._flush_at = target
-            # the window's own span (FlushStats.window_span): trace
-            # attribution on the wall clock, never math
-            self._window_opened = time.time()  # lint: allow(monotonic-clock)
-            self._window_closed_by = (
+        if kind not in self._timers:
+            # a kind joins the armed window (or arms it)
+            timer.flush_at = target
+            timer.closed_by = (
                 "deadline" if target < now + self._window_current else "timer"
             )
+            self._timers[kind] = timer
+        elif target < timer.flush_at:
+            # a tighter deadline arrived while the kind's timer sleeps:
+            # pull it earlier (never later)
+            timer.flush_at = target
+            timer.closed_by = "pulled_earlier"
+        else:
+            return
+        if self._flush_task is None or self._flush_task.done():
             # fresh Event per armed task: asyncio primitives bind to the
             # running loop on first use, and one coalescer may serve
             # several asyncio.run() lifetimes (tests, CLI tools)
             self._flush_wake = asyncio.Event()
             self._flush_task = asyncio.create_task(self._flush_after_window())
-        elif target < self._flush_at:
-            # a tighter deadline arrived while the window timer sleeps:
-            # pull the armed flush earlier (never later)
-            self._flush_at = target
-            self._window_closed_by = "pulled_earlier"
+        else:
+            # the task sleeps toward the earliest timer: now maybe this
             self._flush_wake.set()
 
     async def _flush_after_window(self) -> None:
-        while True:
+        """The armed window: sleeps toward the earliest of its kinds'
+        timers, and each time it wakes sends off every kind that is
+        whole ("complete") or whose timer has run out, each as a flush
+        of its own, closed in a task of its own — a kind whose close
+        waits (the dispatch gate, its own submissions mid-decode) holds
+        no other kind back. Ends when no kind is left in it; the next
+        submission arms a fresh one."""
+        while self._timers:
             self._flush_wake.clear()
-            if self._window_whole():
-                closed_by = "complete"
-                break
-            remaining = self._flush_at - time.monotonic()
-            if remaining <= 0:
-                closed_by = self._window_closed_by
-                break
+            now = time.monotonic()
+            closing = {}
+            for kind, timer in self._timers.items():
+                if timer.closing:
+                    continue
+                if self._kind_whole(kind):
+                    closing[kind] = "complete"
+                elif timer.flush_at <= now:
+                    closing[kind] = timer.closed_by
+            if len(closing) > 1:
+                self.windows_split += 1
+            for kind, closed_by in closing.items():
+                self._timers[kind].closing = True
+                self._own(self._close_kind(kind, closed_by, len(closing)))
+            flush_at = self._flush_at
             try:
+                # a close that has taken its jobs wakes this loop too
                 await asyncio.wait_for(
-                    self._flush_wake.wait(), timeout=remaining
+                    self._flush_wake.wait(),
+                    timeout=None if flush_at is None else max(0.0, flush_at - now),
                 )
             except asyncio.TimeoutError:
                 pass
+        self._flush_task = None
+
+    def _own(self, coro) -> None:
+        """A task between a kind's close and its jobs' results."""
+        task = asyncio.create_task(coro)
+        self._parts.add(task)
+        task.add_done_callback(self._parts.discard)
+
+    async def _close_kind(self, kind: str, closed_by: str, parts: int) -> None:
+        """Take `kind`'s jobs out of the armed window and dispatch them
+        as ONE flush. `parts`: how many kinds closed in the same instant."""
         # read before the first await below: a submission arriving from
-        # here on may still pull `_flush_at`, but the window has closed
-        window_span = (self._window_opened, time.time())  # lint: allow(monotonic-clock)
+        # here on may still pull the timer, but the kind has closed
+        closed = time.time()  # lint: allow(monotonic-clock)
         gate = self.dispatch_gate
         if gate is not None and not gate.is_set():
             # startup tuner still settling the kernel dispatch flags:
@@ -1006,22 +1235,30 @@ class SlotCoalescer:
             # coalesce into this flush instead of arming more of them.
             self.gated_flushes += 1
             await gate.wait()
-        # submissions still mid-decode when the window closed join this
-        # flush (ONE snapshot — later arrivals take the next window, so
-        # sustained load cannot defer the flush unboundedly)
-        pending = list(self._decode_tickets)
+        # submissions of the kind still mid-decode when it closed join
+        # its flush (ONE snapshot — later arrivals take the next window,
+        # so sustained load cannot defer a flush unboundedly)
+        pending = [
+            ticket
+            for ticket, decoding in self._decode_tickets.items()
+            if decoding.kind == kind
+        ]
         if pending:
             await asyncio.gather(*pending, return_exceptions=True)
-        vq, self._verify_q = self._verify_q, []
-        rq, self._recombine_q = self._recombine_q, []
-        # new submissions from here on arm a fresh flush task — its
-        # decode/pack stages overlap this flush's device stage
-        self._flush_task = None
-        self._queue_deadline = None
-        sets = self._verify_sets()
-        self._waves = {}
-        self._unhinted_jobs = 0
+        # new submissions of the kind from here on arm a fresh timer —
+        # their decode/pack stages overlap this flush's device stage
+        timer = self._timers.pop(kind)
+        vq = [job for job in self._verify_q if job.kind == kind]
+        rq = [job for job in self._recombine_q if job.kind == kind]
+        self._verify_q = [j for j in self._verify_q if j.kind != kind]
+        self._recombine_q = [j for j in self._recombine_q if j.kind != kind]
+        sets = self._verify_sets(kind)
+        self._waves = {k: w for k, w in self._waves.items() if w.kind != kind}
+        if not kind:
+            self._unhinted_jobs = 0
+        self._flush_wake.set()  # the window's task: one kind fewer
         if not vq and not rq:
+            self._lane_moved()
             return
         if self._closed:
             # shutdown raced a late submission: fail the waiters fast —
@@ -1030,9 +1267,14 @@ class SlotCoalescer:
             for job in [*vq, *rq]:
                 if not job.fut.done():
                     job.fut.set_exception(TblsError("crypto plane closed"))
+            self._lane_moved()
             return
         window_used = _Window(
-            self._window_current, window_span, closed_by, *sets
+            self._window_current,
+            (timer.opened, closed),
+            closed_by,
+            *sets,
+            parts=parts,
         )
         self.windows_closed[closed_by] = self.windows_closed.get(closed_by, 0) + 1
         if window_used.closed_short:
@@ -1041,10 +1283,164 @@ class SlotCoalescer:
             # a wave that came whole is no evidence that waiting longer
             # catches more (nor that traffic thinned): controller untouched
             self._adapt_window(vq, rq)
+        await self._flush_part(kind, vq, rq, window_used)
+
+    @staticmethod
+    def _urgency(vq, rq) -> tuple[float, int]:
+        """What the device lane orders waiting flushes by: the earliest
+        duty deadline among the jobs (none: last), then the fewest
+        lanes — of two kinds due at the same instant the smaller wave
+        does not sit out the larger one's program."""
+        deadlines = [j.deadline for j in (*vq, *rq) if j.deadline is not None]
+        return (
+            min(deadlines, default=float("inf")),
+            sum(len(j.lanes) for j in vq) + sum(len(j.msgs) for j in rq),
+        )
+
+    def _collecting_urgency(self, kind: str) -> tuple[float, int]:
+        """`_urgency` of the flush `kind`'s submissions will leave as, as
+        far as the coalescer can tell: the earliest deadline among the
+        jobs in the armed window and the submissions still decoding, and
+        their lanes scaled from the sets in to the sets their waves wait
+        for."""
+        vq = [j for j in self._verify_q if j.kind == kind]
+        rq = [j for j in self._recombine_q if j.kind == kind]
+        coming = [d for d in self._decode_tickets.values() if d.kind == kind]
+        deadline, lanes = self._urgency(vq, rq)
+        deadline = min(
+            [deadline, *(d.deadline for d in coming if d.deadline is not None)]
+        )
+        lanes += sum(d.lanes for d in coming)
+        waves = [w for w in self._waves.values() if w.kind == kind]
+        seen = sum(w.seen for w in waves) + len(coming)
+        if seen:
+            awaits = max(
+                sum(max(w.awaits, w.seen) for w in waves),
+                max((d.awaits for d in coming), default=0),
+                seen,
+            )
+            lanes = -(-lanes * awaits // seen)
+        return deadline, lanes
+
+    def _lane_moved(self) -> None:
+        """A kind left the armed window, a flush reached the lane's heap
+        or a submission came off the decode pool: the flushes that yield
+        their turn look again."""
+        yielding, self._yielding = self._yielding, []
+        for waiter in yielding:
+            if not waiter.done():
+                waiter.set_result(None)
+
+    def _more_urgent(self, kind: str, mine: tuple[float, int]) -> set[str]:
+        """The OTHER kinds of duty whose flush is on its way to the lane
+        and more urgent than `mine`: still collecting (a timer is armed
+        from a kind's first job, and a submission on the decode pool is
+        one that will arm it: either way a set of the kind is in), or
+        closed and being packed."""
+        collecting = set(self._timers) | {
+            d.kind for d in self._decode_tickets.values()
+        }
+        return {
+            other
+            for other in collecting
+            if other != kind and self._collecting_urgency(other) < mine
+        } | {
+            other
+            for other, urgency in self._packing.values()
+            if other != kind and urgency < mine
+        }
+
+    async def _yield_turn(
+        self, kind: str, mine: tuple[float, int]
+    ) -> tuple[float, str] | None:
+        """Before a flush asks for its device turn: while a MORE urgent
+        flush of ANOTHER kind is on its way to the lane — a kind still
+        collecting in the armed window, or one closed and being packed —
+        wait for it to get there, so that the order on the device is
+        `_urgency`'s and not the order in which two waves due at the same
+        instant happened to become whole (the smaller wave's duties would
+        sit out the larger one's program because its last set came 20 ms
+        later). At most the other kind's timer: a kind leaves the window
+        whole or when its timer runs out, and is packed in milliseconds.
+        Equal urgency yields to nobody, a flush never yields to its own
+        kind (a straggler's window is not waited out), and with no other
+        kind armed or packing the flush goes at once: one kind in the
+        window never waits. Returns the seconds it yielded and the kinds
+        it yielded to, None if it did not."""
+        began, to = None, set()
+        while ahead := self._more_urgent(kind, mine):
+            to |= ahead
+            if began is None:
+                began = time.monotonic()
+            waiter = asyncio.get_running_loop().create_future()
+            self._yielding.append(waiter)
+            await waiter
+        if began is None:
+            return None
+        return time.monotonic() - began, "+".join(sorted(to))
+
+    def _device_turn(self) -> None:
+        """Device lane: run the most urgent flush that is ready, and
+        hand its waiter the outcome, whatever it is."""
+        with self._ready_lock:
+            *_, fn, args, done = heapq.heappop(self._ready)
+        try:
+            done.set_result(fn(*args))
+        except BaseException as e:  # noqa: BLE001 — the waiter's to judge
+            done.set_exception(e)
+
+    async def _on_device_lane(self, urgency, fn, *args):
+        """`fn(*args)` on the serialized device lane, in its turn: every
+        ready flush takes one turn, and a turn runs the most urgent."""
+        import concurrent.futures
+
+        done: concurrent.futures.Future = concurrent.futures.Future()
+        with self._ready_lock:
+            self._ready_seq += 1
+            entry = (*urgency, self._ready_seq, fn, args, done)
+            heapq.heappush(self._ready, entry)
+        try:
+            self._executor.submit(self._device_turn)
+        except BaseException:
+            # no turn was given (the lane shut down under us): the entry
+            # leaves with its flush, for no later turn to find
+            with self._ready_lock:
+                self._ready.remove(entry)
+                heapq.heapify(self._ready)
+            raise
+        return await asyncio.wrap_future(done)
+
+    async def _flush_part(
+        self, kind: str, vq, rq, window_used: _Window
+    ) -> None:
+        """One flush, from its window's close to its jobs' results."""
+        urgency = self._urgency(vq, rq)
+        token = object()
+        self._packing[token] = (kind, urgency)
+        try:
+            packed = await self._pack_part(vq, rq)
+            # still among `_packing` while it yields: a less urgent flush
+            # waits for this one too, whichever of them wakes first
+            yielded = await self._yield_turn(kind, urgency)
+            if yielded is not None:
+                self.turns_yielded += 1
+                seconds, to = yielded
+                window_used = window_used._replace(
+                    yielded=seconds, yielded_to=to
+                )
+        finally:
+            del self._packing[token]
+            # the turn is asked for before this task next yields
+            # (`_on_device_lane` pushes first): whoever yields to this
+            # flush finds it on the heap
+            self._lane_moved()
+        await self._dispatch_part(vq, rq, packed, urgency, window_used)
+
+    async def _pack_part(self, vq, rq):
+        """Host stage 2: pack the batch on the decode pool so the device
+        lane (possibly still executing the previous window) is never
+        blocked on numpy conversion of Python ints."""
         loop = asyncio.get_running_loop()
-        # host stage 2: pack the batch on the decode pool so the device
-        # lane (possibly still executing the previous window) is never
-        # blocked on numpy conversion of Python ints
         packed = None
         if self.decode_workers > 0 and self._plane_has_packed_api():
             try:
@@ -1067,6 +1463,13 @@ class SlotCoalescer:
                         count=self.pack_fallbacks,
                         err=f"{type(e).__name__}: {str(e)[:160]}",
                     )
+        return packed
+
+    async def _dispatch_part(
+        self, vq, rq, packed, urgency, window_used: _Window
+    ) -> None:
+        """A packed flush's device turn, and its jobs' results."""
+        loop = asyncio.get_running_loop()
         inflight = self._inflight + 1
         self._inflight = inflight
         self.max_inflight = max(self.max_inflight, inflight)
@@ -1074,8 +1477,8 @@ class SlotCoalescer:
             self.overlapped_flushes += 1
         try:
             try:
-                vres, rres = await loop.run_in_executor(
-                    self._executor,
+                vres, rres = await self._on_device_lane(
+                    urgency,
                     self._run_device,
                     vq,
                     rq,
@@ -1407,6 +1810,10 @@ class SlotCoalescer:
                 sets_seen=window_used.sets_seen,
                 sets_awaited=window_used.sets_awaited,
                 window_closed_short=window_used.closed_short,
+                duty_types=self._job_duty_types(vq, rq),
+                window_parts=window_used.parts,
+                turn_yielded_s=window_used.yielded,
+                turn_yielded_to=window_used.yielded_to,
                 **self._verify_verdicts(vres, self._attributions),
                 parents=self._job_parents(vq, rq),
                 tenant_lanes=self._job_tenant_lanes(vq, rq),
@@ -1504,6 +1911,13 @@ class SlotCoalescer:
         return tuple(
             job.parent for job in [*vq, *rq] if job.parent is not None
         )
+
+    @staticmethod
+    def _job_duty_types(vq, rq) -> tuple[str, ...]:
+        """The duty types this flush's jobs named (FlushStats.duty_types)."""
+        return tuple(sorted(
+            {t for job in (*vq, *rq) for t in job.kind.split("+") if t}
+        ))
 
     @staticmethod
     def _job_tenant_lanes(vq, rq) -> tuple:
@@ -1872,6 +2286,7 @@ class SlotCoalescer:
                 device_span=(w0, time.time()),  # lint: allow(monotonic-clock)
                 verify_jobs=len(vq),
                 recombine_jobs=len(rq),
+                duty_types=self._job_duty_types(vq, rq),
                 **self._verify_verdicts(vres),
                 parents=self._job_parents(vq, rq),
                 tenant_lanes=self._job_tenant_lanes(vq, rq),

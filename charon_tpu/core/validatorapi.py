@@ -206,10 +206,26 @@ class ValidatorAPI:
     async def sync_message_duty(self, slot: int, pubkey: PubKey):
         return await self._await_sync_msg(slot, pubkey)
 
-    async def submit_sync_message(self, slot: int, pubkey: PubKey, msg, signature: bytes) -> None:
-        signed = SignedData("sync_message", msg, signature)
-        duty = Duty(slot, DutyType.SYNC_MESSAGE)
-        await self._submit(duty, [(duty, pubkey, signed)])
+    async def submit_sync_messages(self, msgs: Sequence[tuple[PubKey, object]]) -> None:
+        """POST /eth/v1/beacon/pool/sync_committees analogue
+        (ref: validatorapi.go SubmitSyncCommitteeMessages): the
+        request's (pubkey, message) pairs are ONE set, as a request's
+        attestations are — one verify job under the wave key the peers'
+        sets carry, one `vapi.submit` span whose `count` is the
+        request's size; one bad partial refuses the request whole."""
+        if not msgs:
+            return
+        await self._submit(
+            Duty(msgs[0][1].slot, DutyType.SYNC_MESSAGE),
+            (
+                (
+                    Duty(msg.slot, DutyType.SYNC_MESSAGE),
+                    pubkey,
+                    SignedData("sync_message", msg, msg.signature),
+                )
+                for pubkey, msg in msgs
+            ),
+        )
 
     async def submit_exit(self, pubkey: PubKey, exit_msg, signature: bytes) -> None:
         """Voluntary exit partial (ref: exit flow, validatorapi exit

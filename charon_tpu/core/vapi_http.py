@@ -583,11 +583,15 @@ class VapiRouter:
         except (json.JSONDecodeError, KeyError, ValueError, TypeError) as e:
             return _err(400, f"malformed sync message: {e}")
         try:
-            for m in msgs:
-                pubkey = self._resolve_pubkey_by_index(m.validator_index)
-                await self.vapi.submit_sync_message(
-                    m.slot, pubkey, m, m.signature
-                )
+            # the request is ONE set (as /pool/attestations is): one
+            # pubshare check, one verify job under the wave key the
+            # peers' sets carry (core/validatorapi.submit_sync_messages)
+            await self.vapi.submit_sync_messages(
+                [
+                    (self._resolve_pubkey_by_index(m.validator_index), m)
+                    for m in msgs
+                ]
+            )
         except VapiError as e:
             return _err(400, str(e))
         return web.Response(status=200)

@@ -337,12 +337,12 @@ def _r_minus_m(ctx: ModCtx) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 
-def _add_many(ctx: ModCtx, pairs):
+def _add_many(ctx: ModCtx, pairs, rm):
     """Batched modular adds: one stacked normalize for any number of
     independent (a, b) additions. Returns a list of canonical results."""
     if not pairs:
         return []
-    rm = jnp.asarray(_r_minus_m(ctx))
+    # rm = R - p as limbs, made by the caller's side of _traced_once
     lanes = []
     for a, b in pairs:
         a, b = jnp.broadcast_arrays(a, b)
@@ -358,14 +358,14 @@ def _add_many(ctx: ModCtx, pairs):
     return res
 
 
-def _sub_many(ctx: ModCtx, pairs):
+def _sub_many(ctx: ModCtx, pairs, one0, p):
     """Batched modular subs, one stacked normalize. For canonical a, b:
     lane1 = a - b + R (carries iff a >= b), lane2 = a - b + p + R."""
     if not pairs:
         return []
     mask = ctx.u(ctx.mask)
-    one0 = jnp.asarray(_one_hot0(ctx.n_limbs, ctx.np_dtype))
-    p = jnp.asarray(ctx.limbs)
+    # one0 = 1 as limbs, p = the modulus as limbs: made by the caller's
+    # side of _traced_once, like rm above
     lanes = []
     for a, b in pairs:
         a, b = jnp.broadcast_arrays(a, b)
@@ -403,15 +403,15 @@ def sub_mod_many(ctx: ModCtx, pairs):
     return _sub_many(ctx, list(pairs))
 
 
-def addsub_mod_many(ctx: ModCtx, add_pairs, sub_pairs):
-    """Adds and subs together in ONE stacked normalize."""
+def addsub_mod_many(ctx: ModCtx, add_pairs, sub_pairs, rm, one0, p):
+    """Adds and subs together in ONE stacked normalize (rm, one0, p: as
+    in _add_many and _sub_many)."""
     add_pairs, sub_pairs = list(add_pairs), list(sub_pairs)
     if not add_pairs and not sub_pairs:
         return [], []
-    rm = jnp.asarray(_r_minus_m(ctx))
     mask = ctx.u(ctx.mask)
-    one0 = jnp.asarray(_one_hot0(ctx.n_limbs, ctx.np_dtype))
-    p = jnp.asarray(ctx.limbs)
+    # (this line and the next keep the lines below where they were: the
+    # Pallas kernels carry their callers' line numbers into the cache key)
     lanes = []
     for a, b in add_pairs:
         a, b = jnp.broadcast_arrays(a, b)
@@ -718,3 +718,47 @@ def pack_mont_host(ctx: ModCtx, values) -> np.ndarray:
 def unpack_mont_host(ctx: ModCtx, arr) -> list[int]:
     rinv = pow(ctx.r_mont, -1, ctx.modulus)
     return [v * rinv % ctx.modulus for v in ctx_unpack(ctx, arr)]
+
+
+# ---------------------------------------------------------------------------
+# The add/sub families above are most of a pairing program's trace: a few
+# hundred calls on a few dozen distinct shapes, each some hundred equations
+# through jnp's wrappers (18.7 of verify_rlc_dec@128's 27.5 s of trace +
+# lowering). As inlined jits each (ctx, shapes) is traced once and later
+# calls copy its equations into the outer trace. The limb constants are
+# made OUTSIDE the jit, one array a call and in the order the plain
+# functions made them: the outer trace hoists one constant per array
+# object, and a constant captured once by a cached trace would be hoisted
+# once, which is another module and so another compile-cache key. Bound
+# here, at the end of the file, for the sake of the line numbers above.
+# ---------------------------------------------------------------------------
+
+
+def _traced_once(fn, *consts):
+    core = jax.jit(fn, static_argnums=0, inline=True)
+
+    @functools.wraps(fn)
+    def call(ctx, *pairs):
+        if not any(pairs):
+            return fn(ctx, *pairs, *(None for _ in consts))
+        made = (jnp.asarray(c(ctx)) for c in consts)
+        if any(isinstance(x, jax.core.Tracer) for x in jax.tree_util.tree_leaves(pairs)):
+            return core(ctx, *pairs, *made)
+        # op by op on concrete limbs, as ever: a jit of its own a shape
+        # would compile where nothing is being traced
+        return fn(ctx, *pairs, *made)
+
+    return call
+
+
+def _one0(ctx: ModCtx) -> np.ndarray:
+    return _one_hot0(ctx.n_limbs, ctx.np_dtype)
+
+
+def _modulus(ctx: ModCtx) -> np.ndarray:
+    return ctx.limbs
+
+
+_add_many = _traced_once(_add_many, _r_minus_m)
+_sub_many = _traced_once(_sub_many, _one0, _modulus)
+addsub_mod_many = _traced_once(addsub_mod_many, _r_minus_m, _one0, _modulus)

@@ -9,6 +9,8 @@ core/consensus/qbft/transport.go).
 
 from __future__ import annotations
 
+import asyncio
+
 from charon_tpu.p2p.transport import P2PNode
 
 PARSIGEX_PROTOCOL = "parsigex/2.0.0"
@@ -23,6 +25,8 @@ class TcpParSigTransport:
     def __init__(self, node: P2PNode) -> None:
         self.node = node
         self.local = None
+        # the receive in flight per (peer index, duty type): _on_msg
+        self._in_flight: dict[tuple, asyncio.Task] = {}
         node.register_handler(PARSIGEX_PROTOCOL, self._on_msg)
 
     def attach(self, parsigex) -> None:
@@ -38,7 +42,35 @@ class TcpParSigTransport:
         )
 
     async def _on_msg(self, from_idx: int, msg):
-        if self.local is not None:
+        if self.local is None:
+            return None
+        # beside the connection, not in its read loop: receive() ends
+        # when the set's verify flush does (a device program, seconds),
+        # and the peer's NEXT set — another kind of duty due at the same
+        # instant — must not wait behind it. Two peers that send their
+        # two sets in opposite orders would otherwise hold each kind's
+        # wave short of the other's set until a window timer broke the
+        # circle (PERF.md, PR 39). ONE receive in flight per (peer, duty
+        # type): a second set of a type waits here, in the read loop, for
+        # the first to end, which is the backpressure the connection
+        # always had — a peer holds at most a set of each type in the
+        # decode pool and the coalescer, and its sets of a type are
+        # received in the order it sent them.
+        key = (from_idx, getattr(msg["duty"], "type", None))
+        earlier = self._in_flight.get(key)
+        if earlier is not None:
+            await asyncio.wait({earlier})
+        task = self.node.detach(self._receive(from_idx, msg))
+        self._in_flight[key] = task
+        task.add_done_callback(lambda t: self._landed(key, t))
+        return None
+
+    def _landed(self, key, task) -> None:
+        if self._in_flight.get(key) is task:
+            del self._in_flight[key]
+
+    async def _receive(self, from_idx: int, msg) -> None:
+        try:
             # channel identity: mesh node index -> 1-based share index,
             # so receive() can attribute spoofed/invalid sets to the
             # authenticated peer the frame arrived from
@@ -48,7 +80,10 @@ class TcpParSigTransport:
                 tctx=msg.get("tctx"),
                 sender=from_idx + 1,
             )
-        return None
+        except Exception as e:  # noqa: BLE001 — a frame's fault drops that frame
+            # what the read loop does with a handler's error: a malformed
+            # payload is the peer's strike, anything else a logged drop
+            self.node.drop_frame(from_idx, e)
 
 
 class TcpQbftNet:

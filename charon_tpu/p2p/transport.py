@@ -511,6 +511,46 @@ class P2PNode:
         self._recv_tasks.add(task)
         task.add_done_callback(self._recv_tasks.discard)
 
+    def detach(self, coro) -> asyncio.Task:
+        """Run a handler's long tail beside the connection that brought
+        its frame: `_recv_loop` awaits a handler before it reads the
+        connection's next frame, so a handler that awaits a device
+        program (a partial-signature set's verification) would hold
+        every later frame of that peer behind the whole flush. The task
+        is the node's own: `stop()` cancels it with the readers. How
+        many a peer may have in flight is the handler's to bound
+        (p2p/adapters.TcpParSigTransport: one a duty type)."""
+        task = asyncio.create_task(coro)
+        self._recv_tasks.add(task)
+        task.add_done_callback(self._recv_tasks.discard)
+        return task
+
+    def drop_frame(self, peer_idx: int, err: Exception) -> None:
+        """A frame whose decode or handler raised is dropped, the
+        connection lives on (`_recv_loop`, and a detached handler's own
+        task). A typed malformed-frame drop (ISSUE 7 satellite: a
+        sealed-but-malformed payload) is counted and is the peer's
+        strike. (Raw pre-AEAD garbage — chaos_p2p_node's corrupt knob —
+        fails the MAC instead and tears down the conn by design; see
+        _read_sframe.)"""
+        if isinstance(err, codec.CodecError):
+            self.codec_dropped += 1
+            self._quarantine.strike(peer_idx)
+            log.warn(
+                "dropping malformed frame",
+                topic="p2p",
+                peer=peer_idx,
+                dropped=self.codec_dropped,
+                err=f"CodecError: {err}",
+            )
+            return
+        log.warn(
+            "dropping bad frame",
+            topic="p2p",
+            peer=peer_idx,
+            err=f"{type(err).__name__}: {err}",
+        )
+
     def _decode_envelope(self, frame: bytes) -> dict:
         """Sniff-and-decode one decrypted frame in place (offset walk
         over the frame bytes; payload bytes fields slice straight out
@@ -583,30 +623,8 @@ class P2PNode:
                     resp = await handler(conn.peer_idx, env["d"])
                 except asyncio.CancelledError:
                     raise
-                except codec.CodecError as e:
-                    # typed malformed-frame drop (ISSUE 7 satellite):
-                    # a sealed-but-malformed payload lands here,
-                    # counted, and the transport task lives on. (Raw
-                    # pre-AEAD garbage — chaos_p2p_node's corrupt knob
-                    # — fails the MAC instead and tears down the conn
-                    # by design; see _read_sframe.)
-                    self.codec_dropped += 1
-                    self._quarantine.strike(conn.peer_idx)
-                    log.warn(
-                        "dropping malformed frame",
-                        topic="p2p",
-                        peer=conn.peer_idx,
-                        dropped=self.codec_dropped,
-                        err=f"CodecError: {e}",
-                    )
-                    continue
-                except Exception as e:
-                    log.warn(
-                        "dropping bad frame",
-                        topic="p2p",
-                        peer=conn.peer_idx,
-                        err=f"{type(e).__name__}: {e}",
-                    )
+                except Exception as e:  # noqa: BLE001 — per-frame isolation
+                    self.drop_frame(conn.peer_idx, e)
                     continue
                 if resp is not None:
                     body = self._encode_envelope(

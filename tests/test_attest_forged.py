@@ -2,13 +2,20 @@
 flush says when its RLC verify tier fails and the plane falls to its
 per-lane program — `FlushStats.attributed` / `lanes_invalid` / `sets_invalid`
 / `attribute_span`, the `cryptoplane.attribute` span, the coalescer's
-counters; that through the node's own submitters (ValidatorAPI, ParSigEx's
-verifier, ParSigDB, SigAgg) a well-formed forged partial fails ITS set and no
-other, lane for lane as the plain reference (benchmark/reference_verify.py)
-says, wherever in the set it sits, and every duty is made from exactly t
-partials without the forger's; that the new configuration, mix and metrics
-pass the harness's pre-boot checks; and one rehearsal of the cell's control
-flow on the CPU (benchmark/tests/rehearse_forged.py)."""
+counters (the point path, which these fakes are; on a node that decodes on
+the device the RLC program answers per set since PR 36 and the flush says
+`set_resolved`: tests/test_set_verdicts.py); that through the node's own
+submitters (ValidatorAPI, ParSigEx's verifier, ParSigDB, SigAgg) a
+well-formed forged partial fails ITS set and no other, lane for lane as the
+plain reference (benchmark/reference_verify.py) says, wherever in the set it
+sits, and every duty is made from exactly t partials without the forger's;
+that the configuration (`dv-3of4-1k`'s programs as they stand since PR 37),
+the mix and the cell's one metric of its own (`sets_invalid_per_wave`) pass
+the harness's pre-boot checks; and one rehearsal of the cell's control flow
+on the CPU (benchmark/tests/rehearse_forged.py). Restated in PR 39 for what
+PRs 36-37 retired: `verify_dec@128`, `program_s.verify_rlc`,
+`program_s.attribute`, `device_busy_s.verify_rlc`, `attribute_s`,
+`lanes_invalid_per_wave`."""
 
 from __future__ import annotations
 
@@ -43,10 +50,14 @@ from tests.test_cryptoplane import FORK  # noqa: E402
 from tests.test_tracer import _flush_stats  # noqa: E402
 
 CELL = "dv-3of4-1k-byz.attest-forged"
-NEW = ("program_s.verify_rlc", "program_s.attribute", "device_busy_s.verify_rlc", "attribute_s",
-       "lanes_invalid_per_wave")
-LEFT_OUT = ("program_s.verify", "device_busy_s.verify", "window_wait_s.verify",
-            "sets_short_per_wave")
+NEW = ("sets_invalid_per_wave",)  # the cell's own; PR 35's five went in PR 37
+# lists the cell stays out of: the node-down cell's two, and the two-kind cell's eight
+SYNC_CELL = "dv-3of4-1k-sync.attest-sync"
+SYNC_NEW = ("duty_p50_s.attester", "duty_p50_s.sync_message", "program_s.verify.sync_message",
+            "program_s.recombine.sync_message", "kinds_per_flush",
+            "vapi_submits_per_wave.sync_message", "lane_order_flips", "lane_yield_s")
+LEFT_OUT = ("window_wait_s.verify", "sets_short_per_wave") + SYNC_NEW
+FOUR = ("dv-4of7-1k.attest-slot", "dv-3of4-1k.attest-slot", "dv-5of7-1k.node-down", CELL)
 N, THRESHOLD, VALIDATORS, SLOT, FORGER = 4, 3, 4, 37, 2
 FORGED_ROOT = b"forged" + bytes(26)  # benchmark/serve.py's wrong_key partial
 
@@ -354,49 +365,54 @@ def _mix():
 def test_the_cell_is_in_the_manifest_with_its_per_layer_metrics():
     man = M.load_manifest(REPO)
     assert M.validate(man) == []
-    assert [w["name"] for w in man["workloads"]][-1] == CELL
+    cells = [w["name"] for w in man["workloads"]]
+    assert tuple(cells[:4]) == FOUR  # later cells come after it
     cell = M.load_cell(REPO, CELL, man)
     assert (cell.chips, cell.config_name, cell.traffic_name) == (
         1, "dv-3of4-1k-byz", "attest-forged")
     assert [m.name for m in cell.end_to_end] == ["duty_p50_s", "duty_p95_s", "setup_s"]
     names = [m.name for m in cell.per_layer]
-    assert len(names) == 20 and tuple(names[-5:]) == NEW and not set(names) & set(LEFT_OUT)
+    assert len(names) == 18 and set(NEW) <= set(names) and not set(names) & set(LEFT_OUT)
+    assert "program_s.verify" in names and "device_busy_s.verify" in names  # like the other three
     for m in cell.end_to_end + cell.per_layer:
         assert callable(M.load_reader(REPO, man, m.reader))
         assert m.moves in (None, "duty_p50_s")
-    # its five metrics are its alone and come last; it is in every list the
-    # three older cells share but the four that read "the" verify program
-    assert tuple(e["name"] for e in man["per_layer"][-5:]) == NEW
+    # its one metric is its alone; it is in every list the three older
+    # cells share, fourth; a list holds every cell that reports it, and
+    # whatever cell came later comes after the four
     for entry in man["per_layer"]:
+        assert set(entry["workloads"]) <= set(cells)
         if entry["name"] in NEW:
             assert entry["workloads"] == [CELL]
         elif entry["name"] in LEFT_OUT:
             assert CELL not in entry["workloads"]
         else:
-            assert entry["workloads"][-1] == CELL and len(entry["workloads"]) == 4
+            assert tuple(entry["workloads"][:4]) == FOUR
     (entry,) = [c for c in man["configs"] if c["name"] == "dv-3of4-1k-byz"]
     cfg = _config()
     assert cfg["source"] == entry["source"] and sorted(cfg["reduced"]) == entry["reduced"]
     assert len(entry["source"]) <= 200 and "parsigex.go" in entry["source"]
     (workload,) = [w for w in man["workloads"] if w["name"] == CELL]
-    assert len(workload["why"]) <= 200 and "verify_dec@128" in workload["why"]
+    assert len(workload["why"]) <= 200 and "no verify_dec@128 since PR 36" in workload["why"]
 
 
-def test_the_configuration_is_dv_3of4_1k_but_for_the_third_program():
+def test_the_configuration_is_dv_3of4_1k_programs_and_all():
+    """Since PR 37 the list is `dv-3of4-1k`'s as it stands: the per-lane
+    program left it (never dispatched since PR 36)."""
     cfg, base = _config(), _config("dv-3of4-1k")
     differ = sorted(k for k in set(cfg) | set(base) if cfg.get(k) != base.get(k))
     assert differ == ["assumed", "deployment", "guarantees", "guarantees_exercised", "name",
-                      "programs", "source"]
-    assert cfg["programs"] == ["verify_rlc_dec@128", "step_rlc_dec@32", "verify_dec@128",
-                               "g1dec@512"]
-    assert cfg["programs"][:2] == base["programs"][:2]  # traced in the same order
+                      "source"]
+    assert cfg["programs"] == base["programs"] == [
+        "verify_rlc_dec@128", "step_rlc_dec@32", "g1dec@512"]
     for key, value in base["guarantees"].items():
         assert cfg["guarantees"][key] == value  # none weaker
     assert cfg["guarantees"]["every_duty_completes_without_the_forgers_set"] is True
-    assert cfg["guarantees"]["honest_sets_of_an_attributed_flush_pass"] is True
+    assert cfg["guarantees"]["honest_sets_of_a_flush_with_a_refused_set_pass"] is True
     for key, value in base["assumed"].items():
         assert key == "validators" or cfg["assumed"][key] == value
     assert cfg["assumed"]["forger_share_index"].startswith("2:")
+    assert "verify_dec@128 left the list in PR 37" in cfg["assumed"]["programs"]
 
 
 @pytest.mark.parametrize("seed", [1, 3500000009, 2**31 + 12345])
@@ -413,39 +429,55 @@ def test_the_mix_lands_on_the_programs_the_configuration_lists(seed):
         assert [i for i in range(1, 5) if plan.forged(slot, i, 2)] == [2]
 
 
-def test_the_same_mix_on_dv_3of4_1k_is_refused_before_boot(tmp_path, capsys):
+def test_a_mix_on_a_configuration_that_lacks_its_programs_is_refused_before_boot(
+        tmp_path, capsys):
+    """Until PR 37 this very mix on `dv-3of4-1k` was the case (it asked for
+    `verify_dec@128`); now the two configurations list the same programs
+    and the mix passes there. The case that is refused: two kinds of duty
+    on a configuration that compiles one kind's whole waves."""
     base = _config("dv-3of4-1k")
-    with pytest.raises(T.TrafficError, match="verify_dec@128"):
-        T.check_programs(T.make_plan(base, _mix(), 7), base)
+    T.check_programs(T.make_plan(base, _mix(), 7), base)
+    sync_mix = json.loads((REPO / "benchmark/mixes/attest-sync.json").read_text())
+    short = dict(base, name="dv-3of4-1k-short", sync_committee_members=64)  # more than it compiles
+    with pytest.raises(T.TrafficError, match="verify_rlc_dec@256"):
+        T.check_programs(T.make_plan(short, sync_mix, 7), short)
     # ... and so says run.py, in under a second, without importing jax
     from benchmark import run
     from benchmark.tests import helpers
 
     root = helpers.make_root(tmp_path)
+    (root / "benchmark/configs/dv-3of4-1k-short.json").write_text(json.dumps(short))
     manifest = json.loads((root / "BENCHMARK.json").read_text())
-    manifest["workloads"].append({"name": "dv-3of4-1k.attest-forged", "config": "dv-3of4-1k",
-                                  "traffic": "attest-forged", "chips": 1, "why": "tests"})
+    manifest["configs"].append({"name": "dv-3of4-1k-short", "source": "tests",
+                                "file": "benchmark/configs/dv-3of4-1k-short.json",
+                                "reduced": [], "why": "tests"})
+    manifest["workloads"].append({"name": "dv-3of4-1k-short.attest-sync",
+                                  "config": "dv-3of4-1k-short", "traffic": "attest-sync",
+                                  "chips": 1, "why": "tests"})
     (root / "BENCHMARK.json").write_text(json.dumps(manifest))
     codes = []
     t0 = time.monotonic()
-    rc = run.main(["--workload", "dv-3of4-1k.attest-forged", "--seed", "7", "--seconds", "36"],
-                  root=root, exit_fn=codes.append)
+    rc = run.main(["--workload", "dv-3of4-1k-short.attest-sync", "--seed", "7",
+                   "--seconds", "36"], root=root, exit_fn=codes.append)
     assert (rc, codes) == (2, [3]) and time.monotonic() - t0 < 1.0  # the watchdog's exit code
     assert "before boot: TrafficError" in capsys.readouterr().err
 
 
-@pytest.mark.parametrize("name", NEW)
-def test_a_new_metric_reads_nothing_from_a_program_without_its_source(name, monkeypatch):
-    """The parent commit has no such field, span or program: the reader
-    returns None and the line leaves the metric out."""
+@pytest.mark.parametrize("cell,name", [(CELL, NEW[0])] + [(SYNC_CELL, n) for n in SYNC_NEW[2:]])
+def test_a_new_metric_reads_nothing_from_a_program_without_its_source(cell, name, monkeypatch):
+    """A program from before the metric's field, span or program (the
+    parent commit of the PR that brought it): the reader returns None and
+    the line leaves the metric out. The forged cell's one metric (PR 37)
+    and the two-kind cell's six that read what PR 39 added to the program
+    (its two `duty_p50_s.<kind>` read the harness's own records)."""
     from benchmark import nodespans
     from benchmark.serve import RunData
 
     man = M.load_manifest(REPO)
-    (metric,) = [m for m in M.load_cell(REPO, CELL, man).per_layer if m.name == name]
+    (metric,) = [m for m in M.load_cell(REPO, cell, man).per_layer if m.name == name]
     read = M.load_reader(REPO, man, metric.reader)
     run = RunData(window=(1000.0, 1036.0))
-    old_flush = types.SimpleNamespace(verify_jobs=4, lanes=128)  # FlushStats before this PR
+    old_flush = types.SimpleNamespace(verify_jobs=4, lanes=128)  # FlushStats before PR 35
     run.flushes = [(1005.0, old_flush)]
     run.programs = [("step_rlc_dec", 1.0, 32, 1007.0)]
     monkeypatch.setattr(nodespans, "node_spans", lambda: [])
@@ -498,8 +530,7 @@ def test_the_rehearsal_ends_correct_with_three_forged_sets_rejected(rehearsal):
     assert "forged_sets_not_rejected 0 limit 0 ok" in rehearsal.stderr
     assert rehearsal.seen["parsig_invalid"] == {"1": 0, "2": 3, "3": 0, "4": 0}
     metrics = line["metrics"]
-    assert metrics["lanes_invalid_per_wave"] == {"value": 1.0, "unit": "count"}
-    assert 0.015 <= metrics["attribute_s"]["value"] <= 0.5  # the tier's 20 ms sleep
+    assert metrics["sets_invalid_per_wave"] == {"value": 1.0, "unit": "count"}
     assert metrics["flushes_per_wave"]["value"] == 2.0
     assert not set(LEFT_OUT) & set(metrics)
 

@@ -97,9 +97,17 @@ def _duty_workload(impl: PythonImpl, slot: int):
     return pk, group_pk, psigs, root, expected, pubshares
 
 
-def test_two_duties_one_device_call():
+@pytest.mark.parametrize(
+    "second,programs",
+    [(Duty(6, DutyType.ATTESTER), 1), (Duty(5, DutyType.SYNC_MESSAGE), 2)],
+    ids=["one-kind-one-program", "two-kinds-a-program-each"],
+)
+def test_two_duties_one_device_call(second, programs):
     """Two simultaneous duties' SigAgg recombinations coalesce into ONE
-    plane program, and each duty still gets its own correct group sig."""
+    plane program where they are one kind of duty (two slots' attester
+    duties) and leave as a program each where they are two kinds (ISSUE
+    38: a flush holds one kind, on its own bucket); either way each
+    duty still gets its own correct group sig."""
     impl = PythonImpl()
     tbls.set_implementation(impl)
     fake = FakePlane(T)
@@ -126,16 +134,15 @@ def test_two_duties_one_device_call():
 
     async def main():
         d1 = Duty(5, DutyType.ATTESTER)
-        d2 = Duty(5, DutyType.SYNC_MESSAGE)
         await asyncio.gather(
             agg.aggregate(d1, {pk1: psigs1}),
-            agg.aggregate(d2, {pk2: psigs2}),
+            agg.aggregate(second, {pk2: psigs2}),
         )
 
     asyncio.run(main())
-    assert fake.recombine_calls == 1, "two duties must share one program"
+    assert fake.recombine_calls == programs
     assert fake.recombine_lane_count == 2
-    assert plane.coalesced_flushes == 1
+    assert plane.coalesced_flushes == 2 - programs
     assert out[pk1].signature == want1
     assert out[pk2].signature == want2
     # the recovered signatures actually verify against the group keys
@@ -703,3 +710,436 @@ def test_vc_submission_and_peer_sets_make_one_wave(clock):
     (s,) = stats
     assert s.window_closed_by == "complete" and s.jobs == n
     assert s.tenant_lanes == (("cluster-a", n),)
+
+
+# -- one kind a flush (ISSUE 39) ----------------------------------------------
+#
+# Two kinds of duty triggered at the same instant share the armed window
+# and nothing else: the wave keys say which kind a job belongs to (the type
+# of the Duty they hold), each kind has its own timer and leaves as a flush
+# of its own when ITS waves are whole.
+
+import random
+
+_BAD_SIG = b"\xff" * 96  # no G2 encoding: the lane fails on the host
+
+
+def _two_waves():
+    """An attester wave of 4 sets of 3 lanes and a sync-message wave of 4
+    sets of 5 lanes, one lane of the sync wave's third set malformed; each
+    set hinted as the node's submitters hint it."""
+    from charon_tpu.core.parsigex import WaveSet
+
+    everyone = frozenset({1, 2, 3, 4})
+    waves = {}
+    for duty, lanes in ((Duty(7, DutyType.ATTESTER), 3), (Duty(7, DutyType.SYNC_MESSAGE), 5)):
+        key = (duty, frozenset(range(lanes)))
+        waves[str(duty.type)] = [
+            ([_lane(bytes([sender, i]) * 16) for i in range(lanes)],
+             ((key, WaveSet(sender, everyone, 4)),))
+            for sender in (1, 2, 3, 4)
+        ]
+    items, hint = waves["sync_message"][2]
+    pk, root, _sig = items[1]
+    items[1] = (pk, root, _BAD_SIG)
+    return waves
+
+
+def _submit_all(coal, jobs):
+    return [asyncio.create_task(coal.verify(items, wave=hint)) for items, hint in jobs]
+
+
+def _alone(kind):
+    """What a window that holds `kind`'s wave alone gives: (verdicts by
+    set, the flush's lanes, jobs and sets)."""
+    coal, fake, stats = _coalescer()
+
+    async def main():
+        return await _all(*_submit_all(coal, _two_waves()[kind]))
+
+    verdicts = asyncio.run(main())
+    (s,) = stats
+    assert s.window_closed_by == "complete" and s.duty_types == (kind,)
+    return verdicts, (s.lanes, s.jobs, s.sets_expected, s.sets_seen, s.sets_awaited)
+
+
+@pytest.mark.parametrize("shuffle", range(12))
+def test_two_kinds_entering_one_window_leave_as_a_flush_each(clock, shuffle):
+    """Every interleaving of the two waves' arrival: two flushes, one a
+    kind, each with the lanes, ledger and verdicts its wave gets alone."""
+    coal, fake, stats = _coalescer()
+    waves = _two_waves()
+    order = [(kind, k) for kind, jobs in waves.items() for k in range(len(jobs))]
+    random.Random(f"two-kinds/{shuffle}").shuffle(order)
+
+    async def main():
+        tasks = {}
+        for kind, k in order:
+            (tasks[kind, k],) = _submit_all(coal, [waves[kind][k]])
+            await _settle(2)  # each job enters alone, in this order
+        return {at: await asyncio.wait_for(t, 30) for at, t in tasks.items()}
+
+    got = asyncio.run(main())
+    assert fake.verify_calls == 2 and len(stats) == 2
+    assert coal.windows_closed == {"complete": 2}
+    for s in stats:
+        (kind,) = s.duty_types  # one kind a flush
+        verdicts, shape = _alone(kind)
+        assert (s.lanes, s.jobs, s.sets_expected, s.sets_seen, s.sets_awaited) == shape
+        assert [got[kind, k] for k in range(4)] == verdicts
+        assert s.window_closed_by == "complete" and s.window_parts == 1
+    # each window closed the moment ITS wave was whole; on the device the
+    # smaller wave goes first if the window knew of it by then (a whole
+    # sync wave yields its turn to an attester wave still collecting)
+    at = {kind: [i for i, (k, _) in enumerate(order) if k == kind] for kind in waves}
+    sync_first = max(at["sync_message"]) < min(at["attester"])
+    assert [s.duty_types[0] for s in stats] == (
+        ["sync_message", "attester"] if sync_first else ["attester", "sync_message"])
+    # and a flush that waited says for whom: the attester wave for nobody,
+    # ever; the sync wave for the attester's if it was whole first
+    assert stats[0].turn_yielded_to == ""
+    assert stats[1].turn_yielded_to in ("", "attester") and coal.turns_yielded <= 1
+    assert got["sync_message", 2] == [True, False, True, True, True]
+
+
+def test_a_short_kind_waits_out_its_own_timer_beside_a_whole_one(clock):
+    """The attester wave is whole and leaves; the sync wave, one set short,
+    keeps ITS timer (from its own first job) and leaves when that runs out
+    — neither waits for the other, neither takes the other's lanes."""
+    coal, fake, stats = _coalescer()
+    waves = _two_waves()
+
+    async def main():
+        sync = _submit_all(coal, waves["sync_message"][:3])
+        await _settle()
+        clock.now += 5.0  # the attester's first job comes later
+        att = await _all(*_submit_all(coal, waves["attester"]))
+        assert fake.verify_calls == 1 and not coal._flush_task.done()
+        assert len(coal._verify_q) == 3 and set(coal._timers) == {"sync_message"}
+        assert coal._flush_at == pytest.approx(1000.0 + YEAR)  # its own first job's
+        _ring_timer(coal, clock)
+        return att, await _all(*sync)
+
+    att, sync = asyncio.run(main())
+    assert att == [[True] * 3] * 4 and [len(v) for v in sync] == [5, 5, 5]
+    first, second = stats
+    assert (first.duty_types, first.window_closed_by, first.lanes) == (("attester",), "complete", 12)
+    assert (second.duty_types, second.window_closed_by, second.lanes) == (
+        ("sync_message",), "timer", 14)
+    assert (second.sets_expected, second.sets_seen, second.sets_awaited) == (4, 3, 4)
+    assert coal.windows_closed == {"complete": 1, "timer": 1} and coal.windows_split == 0
+
+
+def test_kinds_that_close_in_the_same_instant_are_parts_of_one_close(clock):
+    """Both kinds fall to timers that have run out when the window wakes:
+    one close, two flushes, each saying it is one of 2 parts; both are
+    ready while the device lane is busy, and it takes the smaller wave
+    first though the larger came first."""
+    import threading
+
+    coal, fake, stats = _coalescer()
+    waves = _two_waves()
+    busy = threading.Event()
+
+    async def main():
+        jobs = _submit_all(coal, waves["sync_message"][:3] + waves["attester"][:3])
+        await _settle()
+        assert set(coal._timers) == {"attester", "sync_message"}
+        coal._executor.submit(busy.wait)  # a program still on the device
+        _ring_timer(coal, clock)
+        while len(coal._ready) < 2:
+            await asyncio.sleep(0)
+        busy.set()
+        return await _all(*jobs)
+
+    asyncio.run(main())
+    assert [(s.duty_types, s.window_parts, s.window_closed_by, s.lanes) for s in stats] == [
+        (("attester",), 2, "timer", 9), (("sync_message",), 2, "timer", 14)]
+    assert coal.windows_split == 1 and coal.windows_closed == {"timer": 2}
+
+
+def test_a_whole_kind_leaves_while_another_kinds_close_waits_for_its_decode(clock, monkeypatch):
+    """A kind's close is a task of its own: the sync kind's timer has run
+    out with one of its submissions still on the decode pool, and its
+    close waits for that one; the attester wave that becomes whole in the
+    meantime leaves at once, and the sync flush still takes its late job."""
+    import threading
+
+    coal, fake, stats = _coalescer(decode_workers=2)
+    waves = _two_waves()
+    gate, slow_root = threading.Event(), waves["sync_message"][3][0][4][1]  # a lane the attester wave has not
+    decode = _cp._decode_verify_lane
+
+    def held(item):
+        if item[1] == slow_root:
+            assert gate.wait(30)
+        return decode(item)
+
+    monkeypatch.setattr(_cp, "_decode_verify_lane", held)
+
+    async def main():
+        sync = _submit_all(coal, waves["sync_message"][:3])
+        while len(coal._verify_q) < 3:
+            await asyncio.sleep(0.001)
+        sync += _submit_all(coal, waves["sync_message"][3:])  # held in its decode
+        await _settle()
+        _ring_timer(coal, clock)
+        await _settle()
+        assert coal._timers["sync_message"].closing and fake.verify_calls == 0
+        att = await _all(*_submit_all(coal, waves["attester"]))
+        assert fake.verify_calls == 1 and coal._timers["sync_message"].closing
+        gate.set()
+        return att, await _all(*sync)
+
+    try:
+        att, sync = asyncio.run(main())
+    finally:
+        gate.set()
+        coal.close()
+    assert att == [[True] * 3] * 4 and [len(v) for v in sync] == [5, 5, 5, 5]
+    assert [(s.duty_types, s.window_closed_by, s.jobs, s.lanes) for s in stats] == [
+        (("attester",), "complete", 4, 12), (("sync_message",), "timer", 4, 19)]  # one lane malformed
+
+
+@pytest.mark.parametrize("comes", [True, False], ids=["its-last-set-comes", "its-timer-runs-out"])
+def test_a_whole_wave_yields_its_turn_to_a_smaller_wave_still_collecting(clock, comes):
+    """The sync wave is whole while the attester wave, due at the same
+    instant and a fraction of its lanes, has three of its four sets in:
+    the sync window closes `complete` at once, and its flush asks for the
+    device only when the attester's has — so the order on the device is
+    `_urgency`'s and not the order in which the last sets happened to
+    come. It yields for as long as the other kind's timer at most."""
+    coal, fake, stats = _coalescer()
+    waves = _two_waves()
+
+    async def main():
+        att = _submit_all(coal, waves["attester"][:3])
+        await _settle()
+        sync = _submit_all(coal, waves["sync_message"])
+        await _settle(20)
+        assert coal.windows_closed == {"complete": 1} and set(coal._timers) == {"attester"}
+        assert fake.verify_calls == 0 and len(coal._yielding) == 1  # closed, packed, yielding
+        if comes:
+            att += _submit_all(coal, waves["attester"][3:])
+        else:
+            _ring_timer(coal, clock)
+        return await _all(*att), await _all(*sync)
+
+    att, sync = asyncio.run(main())
+    assert [len(v) for v in att] == [3] * (4 if comes else 3) and [len(v) for v in sync] == [5] * 4
+    assert [(s.duty_types, s.window_closed_by, s.sets_seen) for s in stats] == [
+        (("attester",), "complete" if comes else "timer", 4 if comes else 3),
+        (("sync_message",), "complete", 4)]
+    assert not coal._yielding and not coal._packing and coal.turns_yielded == 1
+    assert [s.turn_yielded_s > 0 for s in stats] == [False, not comes]  # the still clock moved with the timer
+
+
+def test_a_wave_is_judged_by_the_lanes_it_will_have_not_those_it_has(clock):
+    """One sync set of four is in (5 lanes, fewer than the attester wave's
+    12; the wave will have 20): the whole attester wave yields to nobody."""
+    coal, fake, stats = _coalescer()
+    waves = _two_waves()
+
+    async def main():
+        sync = _submit_all(coal, waves["sync_message"][:1])
+        await _settle()
+        assert coal._collecting_urgency("sync_message") == (float("inf"), 20)
+        att = await _all(*_submit_all(coal, waves["attester"]))
+        assert fake.verify_calls == 1 and set(coal._timers) == {"sync_message"}
+        _ring_timer(coal, clock)
+        return att, await _all(*sync)
+
+    asyncio.run(main())
+    assert [s.duty_types for s in stats] == [("attester",), ("sync_message",)]
+
+
+def test_a_ready_flush_with_nothing_armed_beside_it_goes_at_once(clock):
+    """No free device waits for a window that holds no set: the sync wave
+    is whole before the attester wave's first set has come, so no attester
+    timer is armed, and its flush is dispatched without a yield."""
+    coal, fake, stats = _coalescer()
+    waves = _two_waves()
+
+    async def main():
+        sync = await _all(*_submit_all(coal, waves["sync_message"]))
+        assert fake.verify_calls == 1 and not coal._timers and not coal._yielding
+        return sync, await _all(*_submit_all(coal, waves["attester"]))
+
+    asyncio.run(main())
+    assert [s.duty_types for s in stats] == [("sync_message",), ("attester",)]
+    assert [(s.turn_yielded_s, s.turn_yielded_to) for s in stats] == [(0.0, "")] * 2
+    assert coal.turns_yielded == 0
+
+
+def test_a_set_still_on_the_decode_pool_counts_as_in(clock, monkeypatch):
+    """The attester wave's first set was submitted before the sync wave
+    closed but is still decoding (31-32 distinct roots to hash, where the
+    sync wave's one root decodes at once): no attester timer is armed yet,
+    and the packed sync flush yields all the same — the submission is on
+    its way, with its deadline, its lanes and the sets its wave awaits. A
+    decode that ends in no job wakes the flush that waited for it."""
+    import threading
+
+    coal, fake, stats = _coalescer(decode_workers=2)
+    waves = _two_waves()
+    gate, calls = threading.Event(), []
+    decode = _cp._decode_verify_lane
+
+    def held(item):
+        calls.append(item)
+        if len(calls) == 1:  # the attester set's first lane (the waves share roots)
+            assert gate.wait(30)
+        return decode(item)
+
+    monkeypatch.setattr(_cp, "_decode_verify_lane", held)
+
+    async def main():
+        att = _submit_all(coal, waves["attester"][:1])  # held in its decode
+        await _settle()
+        assert not coal._timers and coal._collecting_urgency("attester") == (float("inf"), 12)
+        sync = _submit_all(coal, waves["sync_message"])
+        while not coal._yielding:
+            await asyncio.sleep(0.001)
+        assert fake.verify_calls == 0 and "attester" not in coal._timers
+        assert coal.windows_closed == {"complete": 1}
+        gate.set()
+        att += _submit_all(coal, waves["attester"][1:])
+        return await _all(*att, *sync)
+
+    try:
+        asyncio.run(main())
+    finally:
+        gate.set()
+        coal.close()
+    assert [(s.duty_types, s.turn_yielded_to) for s in stats] == [
+        (("attester",), ""), (("sync_message",), "attester")]
+
+
+def test_a_flush_says_how_long_it_yielded_and_to_which_kind(clock):
+    coal, fake, stats = _coalescer()
+    waves = _two_waves()
+
+    async def main():
+        att = _submit_all(coal, waves["attester"][:1])
+        await _settle()
+        sync = _submit_all(coal, waves["sync_message"])
+        await _settle(20)
+        assert fake.verify_calls == 0 and len(coal._yielding) == 1
+        clock.now += 0.25  # the attester wave's other sets trail by a quarter second
+        att += _submit_all(coal, waves["attester"][1:])
+        return await _all(*att, *sync)
+
+    asyncio.run(main())
+    first, second = stats
+    assert (first.duty_types, first.turn_yielded_s, first.turn_yielded_to) == (("attester",), 0.0, "")
+    assert second.duty_types == ("sync_message",)
+    assert second.turn_yielded_s == pytest.approx(0.25) and second.turn_yielded_to == "attester"
+
+
+def test_a_flush_never_yields_to_its_own_kind(clock):
+    """An attester flush closed by its timer is packed when the set it
+    waited for comes after all (a straggler: one set, a fraction of its
+    lanes): that set's window is not waited out. One kind of duty in the
+    window is served as before the lane had an order."""
+    coal, fake, stats = _coalescer()
+    waves = _two_waves()
+    seen = []
+    more_urgent = coal._more_urgent
+
+    def watched(kind, mine):
+        seen.append((kind, set(coal._timers), more_urgent(kind, mine)))
+        return seen[-1][2]
+
+    coal._more_urgent = watched
+
+    async def main():
+        jobs = _submit_all(coal, waves["attester"][:3])
+        await _settle()
+        pack = coal._pack_part
+
+        async def straggler_comes_while_it_packs(vq, rq):
+            if len(vq) == 3:
+                jobs.extend(_submit_all(coal, waves["attester"][3:]))
+                await _settle()
+            return await pack(vq, rq)
+
+        coal._pack_part = straggler_comes_while_it_packs
+        _ring_timer(coal, clock)
+        await _settle(20)
+        assert fake.verify_calls == 1, "the packed flush went; the straggler's window is armed"
+        assert set(coal._timers) == {"attester"}
+        _ring_timer(coal, clock)
+        return await _all(*jobs)
+
+    asyncio.run(main())
+    assert seen[0] == ("attester", {"attester"}, set())
+    assert [(s.sets_seen, s.window_closed_by, s.turn_yielded_s) for s in stats] == [
+        (3, "timer", 0.0), (1, "timer", 0.0)]
+
+
+@pytest.mark.parametrize("named", [True, False], ids=["senders-named", "a-count"])
+def test_the_graded_deadline_cap_is_for_waves_that_name_nobody(clock, named):
+    """1 % of what a duty has left caps the window of a job that does not
+    say whom it waits for. A wave whose roster NAMES the senders still
+    awaited has its window for them (and leaves the moment they are in):
+    cut short it would leave in two, the trailing set on a bucket of its
+    own. The deadline itself still bounds it."""
+    from charon_tpu.core.parsigex import WaveSet
+
+    coal, fake, stats = _coalescer()
+    key = (Duty(7, DutyType.ATTESTER), frozenset({0}))
+    everyone = frozenset({1, 2})
+
+    def hint(sender):
+        return ((key, WaveSet(sender, everyone, 2) if named else 2),)
+
+    async def main():
+        first = asyncio.create_task(
+            coal.verify([_lane()], wave=hint(1), deadline=clock.now + 55.7))
+        await _settle()
+        assert coal._flush_at == pytest.approx(clock.now + (55.7 if named else 0.557))
+        clock.now += 0.8  # the trailing set: later than the graded cap
+        if not named:
+            coal._flush_wake.set()
+            await _settle()
+        second = asyncio.create_task(
+            coal.verify([_lane()], wave=hint(2), deadline=clock.now + 54.9))
+        if not named:  # the first went alone; so does the second, on a cap of its own
+            await _settle()
+            assert coal._flush_at == pytest.approx(clock.now + 0.549)
+            _ring_timer(coal, clock)
+        return await _all(first, second)
+
+    assert asyncio.run(main()) == [[True], [True]]
+    assert [(s.jobs, s.window_closed_by) for s in stats] == (
+        [(2, "complete")] if named else [(1, "deadline"), (1, "deadline")])
+
+
+def test_a_flush_refused_its_turn_leaves_nothing_on_the_ready_heap():
+    coal, _fake, _stats = _coalescer()
+
+    async def main():
+        coal._executor.shutdown()
+        with pytest.raises(RuntimeError):
+            await coal._on_device_lane((0.0, 1), lambda: 1)
+
+    asyncio.run(main())
+    assert coal._ready == []
+
+
+def test_jobs_that_name_no_duty_share_a_kind_as_they_always_did(clock):
+    """Keys that hold no Duty (tools, tests) and jobs with no hint are one
+    kind, "": one window, one timer, one flush — the code before ISSUE 39."""
+    coal, fake, stats = _coalescer()
+
+    async def main():
+        jobs = [asyncio.create_task(coal.verify([_lane()], wave=(("A", 1),))),
+                asyncio.create_task(coal.verify([_lane()]))]
+        await _settle()
+        assert set(coal._timers) == {""} and fake.verify_calls == 0, "the unhinted job waits"
+        _ring_timer(coal, clock)
+        return await _all(*jobs)
+
+    assert asyncio.run(main()) == [[True], [True]]
+    (s,) = stats
+    assert (s.jobs, s.duty_types, s.window_closed_by, s.sets_expected) == (2, (), "timer", None)

@@ -1398,17 +1398,18 @@ def test_clock_step_does_not_collapse_armed_window():
         with SkewedClock() as clock:
             deadline = time.time() + 30.0
             coal._arm(deadline)
+            (timer,) = coal._timers.values()  # one kind in the window
             armed_at = coal._flush_at
-            queue_deadline = coal._queue_deadline
+            queue_deadline = timer.queue_deadline
             clock.step(3600.0)  # host clock jumps forward an hour
             coal._arm(deadline)
             # pre-fix: deadline - time.time() went negative, the cap
             # collapsed to window_min and the armed flush fired NOW
-            assert coal._queue_deadline == queue_deadline
+            assert timer.queue_deadline == queue_deadline
             assert coal._flush_at == armed_at
             clock.step(-7200.0)  # and an hour backward past real time
             coal._arm(deadline)
-            assert coal._queue_deadline == queue_deadline
+            assert timer.queue_deadline == queue_deadline
             assert coal._flush_at == armed_at
         coal._flush_task.cancel()
 

