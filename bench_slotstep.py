@@ -1,10 +1,11 @@
 """Slot-step scale benchmark: BASELINE.json configs 2/3 on real hardware.
 
 Measures the framework's "training step": one SlotCryptoPlane step for V
-validators with t-of-n partial signatures — per-partial verify [V*t],
-Lagrange recombination [V], group verify [V] — as a single compiled
-program on the device (ref equivalents: core/sigagg/sigagg.go:84-122 +
-core/validatorapi/validatorapi.go:1213, executed per-signature on CPU).
+validators with t-of-n partial signatures — Lagrange recombination [V]
+and the group-signature verify [V], one pairing lane a validator (the
+partials are verified where they enter a node, by the verify programs) —
+as a single compiled program on the device (ref equivalent:
+core/sigagg/sigagg.go:84-122, executed per-signature on CPU).
 
 Prints one JSON line per measured config to stdout, plus an extrapolation
 to the 100k-validator north star (BASELINE config 5). Heartbeats on

@@ -214,6 +214,23 @@ class ClusterMetrics:
             "by the host's parse; the set holding one is dropped whole "
             "and billed in byzantine_evidence_total{kind=parsig_invalid}",
         )
+        self.plane_pairing_lanes = counter(
+            "tpu_plane_pairing_lanes_total",
+            "Pairing lanes the flushes' fast programs checked, by the "
+            "queue the flush held (verify: one a partial signature; "
+            "recombine: one a row, the recombined group signature under "
+            "the group key, where the t partials of the row were "
+            "verified on entry)",
+            ["family"],
+        )
+        self.plane_flushes_recombine_attributed = counter(
+            "tpu_plane_flushes_recombine_attributed_total",
+            "Recombine flushes whose group-signature check failed and "
+            "whose rows were re-dispatched through the per-lane "
+            "recombine program to name the bad row (0 while the verify "
+            "tier keeps bad partials out of ParSigDB: any rise is a "
+            "finding)",
+        )
         # pipelined host plane (ISSUE 3): per-flush latency/occupancy,
         # decode-pool queueing, bucket-padding waste, device-lane depth
         self.plane_flush_seconds = Histogram(

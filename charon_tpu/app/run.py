@@ -533,6 +533,22 @@ async def build_node(config: Config) -> Node:
                 metrics.labels(metrics.plane_lanes_invalid).inc(
                     s.lanes_invalid
                 )
+            if s.pairing_lanes:
+                family = "+".join(
+                    name
+                    for name, jobs in (
+                        ("verify", s.verify_jobs),
+                        ("recombine", s.recombine_jobs),
+                    )
+                    if jobs
+                )
+                metrics.labels(metrics.plane_pairing_lanes, family).inc(
+                    s.pairing_lanes
+                )
+            if s.recombine_attributed:
+                metrics.labels(
+                    metrics.plane_flushes_recombine_attributed
+                ).inc()
             metrics.labels(metrics.plane_flush_seconds, kind).observe(
                 s.flush_seconds
             )

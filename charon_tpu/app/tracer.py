@@ -477,7 +477,9 @@ def plane_span_bridge(
     whose jobs the flush held; the window says in how many `parts` its
     close was dispatched; a flush that let a more urgent kind take the
     device first says for how long and to which (`yielded`,
-    `yielded_to`).
+    `yielded_to`). The device stage says `pairing_lanes`: the lanes its
+    fast programs checked, a verify flush's lanes or a recombine flush's
+    rows (the recombine program checks the group signature alone).
 
     A flush coalesces submissions from several spans of several duties;
     `stats.parents` carries each submission's captured span context, and
@@ -532,7 +534,13 @@ def plane_span_bridge(
             else {}
         )
         if stats.device_span is not None:
-            device_attrs = {"fallback": stats.fallback, **kind}
+            # pairing lanes the stage's fast programs checked: verify
+            # lanes + recombine rows (one lane a row: the group signature)
+            device_attrs = {
+                "fallback": stats.fallback,
+                "pairing_lanes": getattr(stats, "pairing_lanes", 0),
+                **kind,
+            }
             if programs is not None:
                 device_attrs["programs"] = ",".join(programs())
             stages.append(

@@ -311,6 +311,16 @@ class FlushStats:
     sets_invalid: int = 0
     attribute_span: tuple[float, float] | None = None
     attribute_lanes: int = 0
+    # pairing lanes the flush's fast programs checked: its verify lanes
+    # plus its live recombine rows — the recombine program checks ONE
+    # lane a row, the group signature under the group key (the t
+    # partials of a row were judged by the verify program when they
+    # entered the node); `recombine_attributed` where that check failed
+    # and the plane re-dispatched the rows through its per-lane
+    # recombine program, which names the bad row (never on a cluster
+    # whose verify tier does its work: a miss is a finding)
+    pairing_lanes: int = 0
+    recombine_attributed: bool = False
     # (trace_id, span_id) captured from each submission's active span
     parents: tuple[tuple[str, str], ...] = ()
     # live lanes per submitting tenant (ISSUE 8): (tenant_id, lanes)
@@ -323,6 +333,8 @@ class FlushStats:
 # the plane's per-lane verify programs (parallel/mesh `on_program`
 # families): one dispatched inside a flush says the RLC tier failed
 _ATTRIBUTION_FAMILIES = frozenset({"mesh/verify", "mesh/verify_dec"})
+# and its per-lane recombine programs: the group check of some row failed
+_RECOMBINE_ATTRIBUTION_FAMILIES = frozenset({"mesh/step", "mesh/step_dec"})
 
 
 class _Window(NamedTuple):
@@ -570,7 +582,7 @@ class SlotCoalescer:
     flushes / coalesced_flushes / lanes_flushed / windows_closed (by
     cause) / windows_closed_short / windows_split / turns_yielded /
     flushes_attributed /
-    flushes_set_resolved / lanes_invalid:
+    flushes_set_resolved / lanes_invalid / flushes_recombine_attributed:
     observability counters (exported as node metrics by app/run.py).
     """
 
@@ -612,6 +624,8 @@ class SlotCoalescer:
         # lane: (wall-clock start, end, lanes), filled by _listen's hook
         # and emptied as the next flush's verify stage begins
         self._attributions: list[tuple[float, float, int]] = []
+        # and whether its recombine stage fell to the per-lane program
+        self._recombine_attributed = False
         self.plane = self._listen(plane)
         self.window = window
         self.window_min = min(window_min, window)
@@ -699,6 +713,8 @@ class SlotCoalescer:
         # the RLC tier refused a set and answered for it: no such fall
         self.flushes_set_resolved = 0
         self.lanes_invalid = 0  # verify lanes answered False
+        # the recombine program's group check failed: per-lane re-dispatch
+        self.flushes_recombine_attributed = 0
         self.host_fallback_flushes = 0  # served by the python-spec rung
         self.pack_fallbacks = 0  # pack-stage failures (single-stage flush)
         self.pad_lanes_flushed = 0  # bucket-padding lanes shipped
@@ -721,7 +737,8 @@ class SlotCoalescer:
     def _listen(self, plane):
         """Stand in front of the plane's program hook (a plane that has
         one: parallel/mesh `on_program`), so that a flush can say which
-        verify tier answered it; whoever held the hook is still called,
+        verify tier answered it and whether its recombine stage needed
+        the per-lane program; whoever held the hook is still called,
         and whoever takes it later chains to this."""
         if not hasattr(plane, "on_program"):
             return plane
@@ -731,6 +748,8 @@ class SlotCoalescer:
             if family in _ATTRIBUTION_FAMILIES:
                 end = time.time()  # lint: allow(monotonic-clock) — a span's wall clock
                 self._attributions.append((end - seconds, end, lanes))
+            elif family in _RECOMBINE_ATTRIBUTION_FAMILIES:
+                self._recombine_attributed = True
             if inner is not None:
                 inner(family, seconds, lanes)
 
@@ -1696,6 +1715,7 @@ class SlotCoalescer:
         pad_lanes = padded_lanes = 0 if packed is not None else None
         vres: list[list[bool]] = []
         self._attributions.clear()
+        self._recombine_attributed = False
         if vq:
             if vpack is not None:
                 # flat lane count came with the pack — don't re-flatten
@@ -1815,6 +1835,9 @@ class SlotCoalescer:
                 turn_yielded_s=window_used.yielded,
                 turn_yielded_to=window_used.yielded_to,
                 **self._verify_verdicts(vres, self._attributions),
+                # verify lanes + live recombine rows, a lane each
+                pairing_lanes=lanes,
+                recombine_attributed=self._recombine_attributed,
                 parents=self._job_parents(vq, rq),
                 tenant_lanes=self._job_tenant_lanes(vq, rq),
             ),
@@ -1943,6 +1966,9 @@ class SlotCoalescer:
         self.flushes_attributed += 1 if stats.attributed else 0
         self.flushes_set_resolved += 1 if stats.set_resolved else 0
         self.lanes_invalid += stats.lanes_invalid
+        self.flushes_recombine_attributed += (
+            1 if stats.recombine_attributed else 0
+        )
         if stats.pad_lanes:
             self.pad_lanes_flushed += stats.pad_lanes
         if len(vq) + len(rq) >= 2:

@@ -157,8 +157,12 @@ class SigAgg:
     ) -> list[bytes]:
         # One [V, t] recombine+verify job; the coalescer merges it with
         # any other duty's job in the same window into ONE sharded
-        # program (recombination, per-partial verify against pubshares,
-        # and group-sig verify all inside — SlotCryptoPlane.local_step).
+        # program: recombination and the group-sig verify, one pairing
+        # lane a row (SlotCryptoPlane._step_rlc_body) — what
+        # _aggregate_via_tbls does on the host. The partials themselves
+        # were verified against their pubshares on entry (ParSigEx /
+        # ValidatorAPI), as upstream does; the per-partial check runs
+        # again only to attribute a row whose group check failed.
         ps_rows, roots, sig_rows, gpks, idx_rows = [], [], [], [], []
         for pubkey, template, pmap in zip(pubkeys, templates, partial_maps):
             idx = sorted(pmap)

@@ -503,8 +503,20 @@ def threshold_recombine(ctx: ModCtx, fr_ctx: ModCtx, t: int, sig_affine, idx):
     if MSM.msm_active():
         total = MSM.windowed_joint_mul(f, fr_ctx, proj, coeffs)
     else:
-        scaled = C.point_scalar_mul(f, fr_ctx, proj, coeffs)
-        total = C.point_sum(f, scaled, axis=-1)  # reduce the t axis
+        # The V * t multiplications run as FLAT lanes and take the (V, t)
+        # grid back only for the fold over t: with t in an array's
+        # second-minor place the TPU compiler tiles a power-of-two t by
+        # (t, 128) and the scan relays every stacked product out — at
+        # t = 4 the step program read 1.67 s where t = 3 / 5 read 0.87 /
+        # 1.10 (PERF.md §6, PR 40). Flat lanes are what the verify
+        # programs run on.
+        v = idx.shape[0]
+        flat = lambda a: a.reshape(v * t, *a.shape[2:])
+        grid = lambda a: a.reshape(v, t, *a.shape[1:])
+        scaled = C.point_scalar_mul(
+            f, fr_ctx, jax.tree_util.tree_map(flat, proj), flat(coeffs)
+        )
+        total = C.point_sum(f, jax.tree_util.tree_map(grid, scaled), axis=-1)
     return C.point_to_affine(f, total)
 
 

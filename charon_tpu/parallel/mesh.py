@@ -101,6 +101,12 @@ class SlotCryptoPlane:
       sig_ok     [V]  bool      — per-partial verify AND group verify
       total_ok   []   int32     — cluster-wide count of fully-valid lanes
                                   (psum over shards)
+
+    That is `step` / `step_dec`, the attribution programs. The programs a
+    flush dispatches first (`step_rlc` / `step_rlc_dec`) recombine the
+    same way and check the group signature ALONE, one pairing lane a
+    row: every partial was verified against its pubshare when it entered
+    the node (`_step_rlc_body`).
     """
 
     # segments of the parsed RLC verify's product: part of the traced
@@ -230,16 +236,15 @@ class SlotCryptoPlane:
         return jax.jit(sharded)
 
     def _build_rlc(self):
-        """The throughput path: identical recombination, but verification
-        by random linear combination (ops/pairing.batched_verify_rlc
-        design) — each shard product-trees its lanes' pairing values and
-        runs ONE local final exponentiation (all shards in parallel), so
-        the per-lane final-exp cost disappears. Returns (group_sig,
-        all_ok) where all_ok is the cluster-wide AND (psum of per-shard
-        failures == 0). Per-lane attribution on failure comes from the
-        slower `step` (the reference pays per-signature herumi calls for
-        every duty; here the common all-valid case costs one shared tail
-        per shard — core/sigagg/sigagg.go:84-122)."""
+        """The throughput path: identical recombination, then ONE check a
+        row — the recombined group signature under the group key — by
+        random linear combination (ops/pairing.batched_verify_rlc): each
+        shard product-trees its rows' pairing values and runs ONE local
+        final exponentiation (all shards in parallel). Returns
+        (group_sig, all_ok) where all_ok is the cluster-wide AND (psum of
+        per-shard failures == 0). Which partial of a failing row is bad
+        is the slower `step`'s to say (the reference verifies the
+        aggregate alone here too — core/sigagg/sigagg.go:84-122)."""
         axis = self.axis
 
         sharded = jax.shard_map(
@@ -253,84 +258,87 @@ class SlotCryptoPlane:
         return jax.jit(sharded)
 
     def _step_rlc_body(self, pubshares, msg, partials, group_pk, indices, live, rand):
+        """Recombine each row's t partials and check the AGGREGATE:
+
+            prod_v e(r_v * group_pk_v, H(m_v)) * e(r_v * (-G1), group_sig_v) == 1
+
+        one pairing lane a row under an independent 64-bit exponent a
+        row. The partials are not judged again here: each was verified
+        against its pubshare when it entered the node (a peer's set in
+        ParSigEx, the VC's in ValidatorAPI — the verify programs), and a
+        set that failed never reached ParSigDB; upstream does the same
+        (core/parsigex/parsigex.go verifies on receipt,
+        core/sigagg/sigagg.go:84-122 the aggregate alone), and so does
+        the host rung (core/sigagg._aggregate_via_tbls). A bad partial
+        that did reach a row enters the group signature under a non-zero
+        Lagrange coefficient, so the row fails and `step` attributes.
+        The one input this accepts that a per-partial check refuses —
+        partial errors crafted to cancel in the Lagrange sum — yields a
+        VALID group signature, the object the duty broadcasts; upstream
+        and the host rung accept it too. `pubshares` stays an argument
+        (the attribution programs, which share the packs, read it)."""
+        del pubshares
         ctx, fr_ctx, t, axis = self.ctx, self.fr_ctx, self.t, self.axis
-        g2f = C.g2_ops(ctx)
         group_sig = blsops.threshold_recombine(ctx, fr_ctx, t, partials, indices)
 
-        # INDEPENDENT exponent per verify lane ([Vl, t+1] from the
-        # host): sharing one exponent across a validator's t+1 lanes
-        # would let colluding operators craft partial-sig deltas whose
-        # errors cancel deterministically inside the shared-exponent
-        # product (the group-sig lane error is a public Lagrange
-        # combination of the partial errors). Padding lanes carry
-        # live=False: zero their exponent so their (possibly garbage)
-        # pairing value contributes ^0 = 1.
-        rand_live = jnp.where(live[:, None, None], rand, 0)
-        cat_grid = lambda a, b: jnp.concatenate(
-            (a, b[:, None, ...]), axis=1
-        )
-        pk_grid = jax.tree_util.tree_map(cat_grid, pubshares, group_pk)
-        sig_grid = jax.tree_util.tree_map(cat_grid, partials, group_sig)
+        # Padding and undecodable rows carry live=False: zero their
+        # exponent so their (possibly garbage) pairing value contributes
+        # ^0 = 1.
+        rand_live = jnp.where(live[:, None], rand, 0)
 
         from charon_tpu.ops import msm as MSM
 
         if MSM.msm_active():
-            # Grouped RLC: a validator's t+1 lanes share its duty
-            # message, so they collapse into ONE bucket pair
-            # e(sum_j r_vj * pk_vj, H_v) — the Miller stage runs
-            # Vl + 1 pairs instead of Vl * (t+1), a (t+1)x cut in
-            # the dominant stage. Straus joint mul batches the
-            # 64-bit randomization over the (Vl, t+1) grid; per-lane
-            # exponents keep the independence property above (same
-            # construction as pairing.batched_verify_grouped_rlc
-            # with per-validator groups).
-            g1f = C.g1_ops(ctx)
-            buckets = MSM.windowed_joint_mul(
-                g1f,
-                fr_ctx,
-                C.affine_to_point(g1f, pk_grid),
-                rand_live,
-                nbits=64,
-            )
-            sig_v = MSM.windowed_joint_mul(
-                g2f,
-                fr_ctx,
-                C.affine_to_point(g2f, sig_grid),
-                rand_live,
-                nbits=64,
-            )
+            # Grouped form of the same equation, one lane a bucket: the
+            # rows' r_v * group_sig_v collapse into ONE aggregate pair
+            # e(-G1, sum_v r_v * group_sig_v), so the Miller stage runs
+            # Vl + 1 pairs (pairing.grouped_rlc_check, the construction
+            # of pairing.batched_verify_grouped_rlc with a group a row).
+            g1f, g2f = C.g1_ops(ctx), C.g2_ops(ctx)
+
+            def scaled(f, affine):  # [Vl] r_v * P_v, as a [Vl, 1] grid
+                return MSM.windowed_joint_mul(
+                    f,
+                    fr_ctx,
+                    jax.tree_util.tree_map(
+                        lambda a: a[:, None, ...], C.affine_to_point(f, affine)
+                    ),
+                    rand_live[:, None],
+                    nbits=64,
+                )
+
+            buckets = scaled(g1f, group_pk)
+            sig_v = scaled(g2f, group_sig)
             s_total = DP.point_sum_tree(g2f, sig_v, live.shape[0])
             ok = DP.grouped_rlc_check(ctx, buckets, msg, s_total)
         else:
-            flat = lambda a: a.reshape(-1, *a.shape[2:])
-            pk_all = jax.tree_util.tree_map(flat, pk_grid)
-            sig_all = jax.tree_util.tree_map(flat, sig_grid)
-            msg_rep = jax.tree_util.tree_map(
-                lambda a: jnp.repeat(a, t + 1, axis=0), msg
-            )
             ok = DP.batched_verify_rlc(
-                ctx,
-                fr_ctx,
-                pk_all,
-                msg_rep,
-                sig_all,
-                rand_live.reshape(-1, rand.shape[-1]),
+                ctx, fr_ctx, group_pk, msg, group_sig, rand_live
             )
         bad = jax.lax.psum(jnp.logical_not(ok).astype(jnp.int32), axis)
         return group_sig, bad == 0
 
     def _build_rlc_dec(self):
         """RLC recombine on PARSED partials: in-program decompression,
-        rows with undecodable partials excluded from the shared product
-        (exponent 0) and reported via the third output so the host can
-        attribute per-lane results on the all-valid fast path."""
+        then the shared body (one pairing lane a row: the group
+        signature under the group key). Rows with undecodable partials
+        are excluded from the shared product (exponent 0) and reported
+        via the third output so the host can answer per row on the
+        all-valid fast path."""
         ctx, fr_ctx, axis = self.ctx, self.fr_ctx, self.axis
 
         def local_step(ps, msg, px0, px1, psign, gpk, idx, live, rand):
+            # decompress the [Vl, t] grid as flat lanes (what
+            # blsops.threshold_recombine says of the t axis holds for the
+            # square-root chain too)
+            v, t = psign.shape
+            flat = lambda a: a.reshape(v * t, *a.shape[2:])
+            grid = lambda a: a.reshape(v, t, *a.shape[1:])
             partials, dec_ok = DEC.decompress_g2_graph(
-                ctx, fr_ctx, (px0, px1), psign
+                ctx, fr_ctx, (flat(px0), flat(px1)), flat(psign)
             )
-            row_ok = jnp.logical_and(jnp.all(dec_ok, axis=1), live)
+            partials = jax.tree_util.tree_map(grid, partials)
+            row_ok = jnp.logical_and(jnp.all(grid(dec_ok), axis=1), live)
             group_sig, all_ok = self._step_rlc_body(
                 ps, msg, partials, gpk, idx, row_ok, rand
             )
@@ -490,38 +498,20 @@ class SlotCryptoPlane:
         return jax.jit(sharded)
 
     def step_rlc(self, pubshares, msg, partials, group_pk, indices, live, rand):
-        """Fast path: (group_sig, all_ok). `rand` is a [V, t+1] raw Fr
-        limb array of independent nonzero 64-bit exponents (host
-        randomness, one per verify lane — see make_rand)."""
+        """Fast path: (group_sig, all_ok). `rand` is a [V] raw Fr limb
+        array of independent nonzero 64-bit exponents (host randomness,
+        one a row — see make_rand)."""
         return self._step_rlc(
             pubshares, msg, partials, group_pk, indices, live, rand
         )
 
     def make_rand(self, v: int, rng=None) -> jnp.ndarray:
-        """[V_padded, t+1] independent nonzero 64-bit exponents packed as
-        raw Fr limbs. Defaults to OS randomness (SystemRandom) — the
-        2^-64 soundness bound assumes exponents unpredictable to the
-        signers; pass a seeded Random only in tests."""
-        import random as _random
-
-        rng = rng or _random.SystemRandom()
-        vp = self.bucket_lanes(v)
-        return jnp.asarray(
-            np.asarray(
-                [
-                    [
-                        limb.int_to_limbs(
-                            rng.randrange(1, 1 << 64),
-                            self.fr_ctx.n_limbs,
-                            self.fr_ctx.limb_bits,
-                            self.fr_ctx.np_dtype,
-                        )
-                        for _ in range(self.t + 1)
-                    ]
-                    for _ in range(vp)
-                ]
-            )
-        )
+        """[V_padded] independent nonzero 64-bit exponents packed as raw
+        Fr limbs, one a recombine row (the row's one pairing lane).
+        Defaults to OS randomness (SystemRandom) — the 2^-64 soundness
+        bound assumes exponents unpredictable to the signers; pass a
+        seeded Random only in tests."""
+        return self.make_lane_rand(v, rng=rng)
 
     # -- host-facing ------------------------------------------------------
 
