@@ -81,6 +81,14 @@ class SimCluster:
         if self.partitioner is not None:
             self.partitioner.heal()
 
+    def slots_given(self) -> set[int]:
+        """The slots EVERY node's scheduler ticked. A starved event loop
+        skips slots (Scheduler.run moves on once a slot's end has
+        passed), on all nodes at once where they share the loop: what a
+        test asserts of every slot ("none missed") it asserts over
+        these, so that it says the same whatever the machine's load."""
+        return set.intersection(*(node.ticked for node in self.nodes))
+
     def close(self) -> None:
         """Release per-node resources (crypto-plane pools, trace JSONL
         handles) — tracing/crypto_plane builds should call this."""
@@ -169,6 +177,8 @@ class SimNode:
     # app/flightrec.FlightRecorder — per-node post-mortem ring, same
     # hook chains as production (flightrec=True builds)
     flightrec: object | None = None
+    # the slots this node's scheduler ticked (SimCluster.slots_given)
+    ticked: set[int] = field(default_factory=set)
 
 
 class SimHostPlane:
@@ -535,6 +545,13 @@ def _build_node(
     bcast.subscribe(inclusion.submitted)
     scheduler.subscribe_slots(inclusion.on_slot)
 
+    ticked: set[int] = set()
+
+    async def record_tick(slot) -> None:
+        ticked.add(slot.slot)
+
+    scheduler.subscribe_slots(record_tick)
+
     # priority/infosync negotiation at epoch edges, switching the
     # consensus protocol to the cluster choice (same wiring as
     # app/run.py; ref: core/priority + core/infosync)
@@ -575,4 +592,5 @@ def _build_node(
         parsigex=parsigex,
         evidence=evidence,
         flightrec=rec,
+        ticked=ticked,
     )

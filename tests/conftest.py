@@ -26,6 +26,13 @@ if "xla_force_host_platform_device_count" not in flags:
         " --xla_backend_optimization_level=0"
     )
 os.environ["JAX_PLATFORMS"] = "cpu"
+# libgomp reads this once, when native/libcharon_native.so pulls it in:
+# the native tbls backend's verify_batch is an OpenMP loop, and with the
+# default (active) policy its idle team spins between calls — a 4-node
+# simnet then burns four to five cores for one core's work (53 CPU-s in
+# 12 s of wall, 13 with `passive`: my sandbox, PR 41) and six xdist
+# workers starve each other's event loops.
+os.environ.setdefault("OMP_WAIT_POLICY", "passive")
 
 import jax
 
@@ -104,6 +111,12 @@ def _thread_task_leak_guard(request):
     watcher = _san.TaskDestroyedWatcher().install()
     yield
     destroyed = watcher.uninstall()
+    # an owner that only a reference cycle keeps (a test that hangs its
+    # own closure on the coalescer) is no leak, and whether the cyclic
+    # collector ran inside the grace must not decide the test
+    import gc
+
+    gc.collect()
     leaked = _san.check_thread_leaks(before, grace=5.0)
     problems = []
     if leaked:
@@ -118,3 +131,132 @@ def _thread_task_leak_guard(request):
         )
     if problems:
         _pytest.fail("; ".join(problems))
+
+
+# -- one limit a test (ISSUE 41) ---------------------------------------------
+#
+# pytest-timeout is not installed, so the interpreter's own timer does
+# it: an xdist worker runs its tests on its main thread, where SIGALRM's
+# handler raises INTO whatever the test is doing (an asyncio.run, a
+# subprocess.run, a lock). The test FAILS with every thread's stack on
+# stderr and its worker goes on to the next test. The limit covers the
+# test's set-up too (a module-scoped fixture that compiles in a child
+# belongs to the first test that asks for it). A main thread held in C
+# code that never comes back to the interpreter cannot be raised into:
+# HARD_GRACE seconds later faulthandler's watchdog thread dumps the
+# stacks and ends the worker, which costs xdist that one test and a new
+# worker.
+
+import faulthandler
+import signal
+import sys
+import threading
+import time
+import traceback
+
+TEST_LIMIT = 300.0  # seconds a test may take unless it says otherwise
+# The `slow` tier is outside the driver's run (`-m 'not slow'`) and by its
+# own description wall-clock heavy: its children compile for up to 75
+# minutes cold (CI.md, "Round-5 slow-tier stabilization") under
+# run_isolated timeouts of their own, up to 100 minutes.
+SLOW_TEST_LIMIT = 7200.0
+REFIRE = 5.0
+HARD_GRACE = 60.0
+TEARDOWN_LIMIT = 60.0  # what tear-down keeps when the limit is spent
+
+
+class LimitExceeded(BaseException):
+    """The test outlived its limit (`limit` marker, else TEST_LIMIT).
+    Not an Exception: a retry loop's `except Exception` must not eat it."""
+
+
+def _limit_of(item) -> float:
+    marker = item.get_closest_marker("limit")
+    if marker is not None:
+        return float(marker.args[0])
+    return SLOW_TEST_LIMIT if item.get_closest_marker("slow") else TEST_LIMIT
+
+
+def _dump_all_stacks(out) -> None:
+    names = {t.ident: t.name for t in threading.enumerate()}
+    for ident, frame in sys._current_frames().items():
+        print(f"--- thread {names.get(ident, ident)}:", file=out)
+        traceback.print_stack(frame, file=out)
+
+
+def _under_limit(item, seconds: float):
+    """Run one phase of `item` (the hook's `yield`) under `seconds`."""
+    if not hasattr(signal, "setitimer") or (
+        threading.current_thread() is not threading.main_thread()
+    ):
+        yield
+        return
+
+    fired = []
+    said = f"outlived its limit of {_limit_of(item):g} s"
+
+    def on_alarm(signum, frame):
+        if not fired:
+            fired.append(True)
+            print(
+                f"\n{item.nodeid} {said}; every thread's stack:",
+                file=sys.stderr,
+            )
+            _dump_all_stacks(sys.stderr)
+        raise LimitExceeded(f"{said} (stacks on stderr)")
+
+    previous = signal.signal(signal.SIGALRM, on_alarm)
+    # again every REFIRE seconds: raised into an asyncio task that is
+    # not the main one, the exception ends that task and not the run
+    signal.setitimer(signal.ITIMER_REAL, max(seconds, 0.001), REFIRE)
+    faulthandler.dump_traceback_later(
+        seconds + HARD_GRACE, exit=True, file=sys.__stderr__
+    )
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        faulthandler.cancel_dump_traceback_later()
+        signal.signal(signal.SIGALRM, previous)
+
+
+_limit_ends_at = _pytest.StashKey[float]()
+
+
+def _left(item) -> float:
+    return item.stash[_limit_ends_at] - time.monotonic()
+
+
+@_pytest.hookimpl(hookwrapper=True, tryfirst=True)
+def pytest_runtest_setup(item):
+    item.stash[_limit_ends_at] = time.monotonic() + _limit_of(item)
+    yield from _under_limit(item, _left(item))
+
+
+@_pytest.hookimpl(hookwrapper=True, tryfirst=True)
+def pytest_runtest_call(item):
+    yield from _under_limit(item, _left(item))
+
+
+@_pytest.hookimpl(hookwrapper=True, tryfirst=True)
+def pytest_runtest_teardown(item):
+    yield from _under_limit(item, max(_left(item), TEARDOWN_LIMIT))
+
+
+# -- longest first (ISSUE 41) ------------------------------------------------
+#
+# With `--dist loadfile` a run's wall is its slowest file plus the second
+# at which that file started. The files whose tests carry the `limit`
+# marker say themselves that they are long: they go to the head of the
+# collection, the longest limit first. The marker is the only input.
+
+
+def pytest_collection_modifyitems(items):
+    longest: dict[str, float] = {}
+    for item in items:
+        marker = item.get_closest_marker("limit")
+        if marker is not None:
+            path = item.nodeid.split("::", 1)[0]
+            longest[path] = max(longest.get(path, 0.0), float(marker.args[0]))
+    # a stable sort: every other file keeps its place
+    items.sort(key=lambda item: -longest.get(item.nodeid.split("::", 1)[0], 0.0))

@@ -29,22 +29,43 @@ _jc.configure(jax, cpu=True)
 """
 
 
-def run_isolated(script: str, marker: str, timeout: float = 1500) -> str:
+# Under REAL_PROGRAM_LIMIT, the `limit` of the tests that wait for such a
+# child: the child dies before its parent's limit, and the assertion
+# shows the child's stderr and not the parent's stack.
+REAL_PROGRAM_LIMIT = 1200.0
+DEFAULT_TIMEOUT = REAL_PROGRAM_LIMIT - 100.0
+
+
+def run_isolated(
+    script: str, marker: str, timeout: float = DEFAULT_TIMEOUT
+) -> str:
     """Run `script` (usually ISOLATED_HEADER + body) in a fresh python;
-    assert exit 0 and that `marker` was printed. Returns its stdout."""
-    proc = subprocess.run(
-        [sys.executable, "-c", script],
-        capture_output=True,
-        text=True,
-        timeout=timeout,
-        # tests/ on the path too: scripts share workload helpers with
-        # their in-process siblings (e.g. tests/meshwork.py)
-        env={
-            **os.environ,
-            "PYTHONPATH": REPO + os.pathsep + os.path.join(REPO, "tests"),
-        },
-        cwd=REPO,
-    )
+    assert exit 0 and that `marker` was printed. Returns its stdout.
+    A child that outlives `timeout` is killed, and the assertion says so
+    with what it had written."""
+    try:
+        proc = subprocess.run(
+            [sys.executable, "-c", script],
+            capture_output=True,
+            text=True,
+            timeout=timeout,
+            # tests/ on the path too: scripts share workload helpers with
+            # their in-process siblings (e.g. tests/meshwork.py)
+            env={
+                **os.environ,
+                "PYTHONPATH": REPO + os.pathsep + os.path.join(REPO, "tests"),
+            },
+            cwd=REPO,
+        )
+    except subprocess.TimeoutExpired as e:
+        # what was read so far: bytes, or None
+        out, err = (
+            (x if isinstance(x, str) else (x or b"").decode(errors="replace"))[-2000:]
+            for x in (e.stdout, e.stderr)
+        )
+        raise AssertionError(
+            f"isolated test killed after its {timeout:g} s:\n{out}\n{err}"
+        ) from None
     assert proc.returncode == 0, (
         f"isolated test failed rc={proc.returncode}:\n"
         f"{proc.stdout[-2000:]}\n{proc.stderr[-2000:]}"

@@ -12,6 +12,7 @@ from charon_tpu.app.metrics import ClusterMetrics, serve_monitoring
 from charon_tpu.app.retry import Retryer
 from charon_tpu.core.tracker import Reason, Step, Tracker, tracking
 from charon_tpu.core.types import Duty, DutyType
+from charon_tpu.testutil.waiting import wait_until
 
 
 def test_lifecycle_order_and_shutdown():
@@ -36,7 +37,10 @@ def test_lifecycle_order_and_shutdown():
 
         stop = asyncio.Event()
         task = asyncio.create_task(life.run(stop))
-        await asyncio.sleep(0.05)
+        await wait_until(
+                lambda: len(events) >= 2,
+                "both start hooks",
+            )
         assert events == ["start:p2p", "start:sched"]  # ordered
         stop.set()
         await asyncio.wait_for(task, 10)

@@ -52,6 +52,7 @@ from charon_tpu.testutil.byzantine import (
     find_instance,
     run_with_adversary,
 )
+from charon_tpu.testutil.waiting import wait_progress
 
 SEED = 160808  # one seed drives the whole battery; change = new schedule
 
@@ -414,17 +415,18 @@ def _silence(node) -> None:
     node.vmock.attest = silent_attest
 
 
-async def _await_attestation(beacon, n_expected: int, timeout: float = 60.0):
-    async def done():
-        while True:
-            by_slot: dict[int, int] = {}
-            for a in beacon.attestations:
-                by_slot[a.data.slot] = by_slot.get(a.data.slot, 0) + 1
-            if any(c >= n_expected for c in by_slot.values()):
-                return
-            await asyncio.sleep(0.05)
+async def _await_attestation(beacon, n_expected: int):
+    def done():
+        by_slot: dict[int, int] = {}
+        for a in beacon.attestations:
+            by_slot[a.data.slot] = by_slot.get(a.data.slot, 0) + 1
+        return any(c >= n_expected for c in by_slot.values())
 
-    await asyncio.wait_for(done(), timeout)
+    await wait_progress(
+        done,
+        probe=lambda: len(beacon.attestations),
+        what=f"a slot {n_expected} nodes broadcast",
+    )
 
 
 @pytest.mark.slow

@@ -32,6 +32,7 @@ from charon_tpu.core.types import Duty, DutyType
 from charon_tpu.tbls.python_impl import PythonImpl
 from charon_tpu.testutil.chaos import ChaosConfig, FlakyBackend
 from charon_tpu.testutil.simnet import build_cluster
+from charon_tpu.testutil.waiting import wait_progress
 
 SEED = 20260803  # one seed for the whole suite: failures replay exactly
 
@@ -63,25 +64,6 @@ def _slots_with(beacon, count: int, after: int = -1) -> list[int]:
         for s, c in _atts_by_slot(beacon).items()
         if c >= count and s > after
     )
-
-
-async def _wait_progress(predicate, probe, first_window=120.0, window=60.0):
-    """Await predicate() truthy. The deadline extends whenever probe()
-    changes (e.g. total broadcast count): the run may be slow under CI
-    load, but it must keep MOVING within each window."""
-    deadline = time.monotonic() + first_window
-    last = None
-    while True:
-        value = predicate()
-        if value:
-            return value
-        snapshot = probe()
-        if snapshot != last:
-            last = snapshot
-            deadline = time.monotonic() + window
-        if time.monotonic() > deadline:
-            raise TimeoutError(f"no chaos-scenario progress (probe={last})")
-        await asyncio.sleep(0.05)
 
 
 async def _stop(cluster, tasks):
@@ -120,9 +102,10 @@ def test_chaos_silenced_node():
         tasks = _start(cluster)
         beacon = cluster.beacon
         try:
-            slots = await _wait_progress(
+            slots = await wait_progress(
                 lambda: _slots_with(beacon, 4),
                 probe=lambda: len(beacon.attestations),
+                what="a slot all four nodes broadcast (node 4's VC silent)",
             )
         finally:
             await _stop(cluster, tasks)
@@ -155,29 +138,32 @@ def test_chaos_minority_partition_and_heal():
         beacon = cluster.beacon
         try:
             # healthy warm-up: some slot completed by all four
-            healthy = (await _wait_progress(
+            healthy = (await wait_progress(
                 lambda: _slots_with(beacon, 4),
                 probe=lambda: len(beacon.attestations),
+                what="a slot all four nodes broadcast, before the partition",
             ))[0]
 
             cluster.partition({1, 2, 3}, {4})
             cut_at = max(_atts_by_slot(beacon) or [0])
             # majority progress: a post-partition slot completed by the
             # three connected nodes (node 4 cannot assemble a threshold)
-            part_slot = (await _wait_progress(
+            part_slot = (await wait_progress(
                 lambda: [
                     s
                     for s in _slots_with(beacon, 3, after=cut_at + 1)
                     if _atts_by_slot(beacon)[s] == 3
                 ],
                 probe=lambda: len(beacon.attestations),
+                what="a slot after the cut that exactly the three connected nodes broadcast",
             ))[0]
 
             cluster.heal()
             healed_at = max(_atts_by_slot(beacon))
-            healed_slot = (await _wait_progress(
+            healed_slot = (await wait_progress(
                 lambda: _slots_with(beacon, 4, after=healed_at),
                 probe=lambda: len(beacon.attestations),
+                what="a slot all four nodes broadcast, after the heal",
             ))[0]
         finally:
             await _stop(cluster, tasks)
@@ -231,9 +217,10 @@ def test_chaos_flappy_beacon():
         tasks = _start(cluster)
         beacon = cluster.beacon
         try:
-            slots = await _wait_progress(
+            slots = await wait_progress(
                 lambda: _slots_with(beacon, 4),
                 probe=lambda: len(beacon.attestations),
+                what="a slot all four nodes broadcast through the flappy beacon",
             )
         finally:
             await _stop(cluster, tasks)
@@ -264,20 +251,22 @@ def test_chaos_crash_recover():
         tasks = _start(cluster)
         beacon = cluster.beacon
         try:
-            (await _wait_progress(
+            (await wait_progress(
                 lambda: _slots_with(beacon, 4),
                 probe=lambda: len(beacon.attestations),
+                what="a slot all four nodes broadcast, before the crash",
             ))[0]
 
             cluster.crash_node(4)
             crash_at = max(_atts_by_slot(beacon))
-            (await _wait_progress(
+            (await wait_progress(
                 lambda: [
                     s
                     for s in _slots_with(beacon, 3, after=crash_at + 1)
                     if _atts_by_slot(beacon)[s] == 3
                 ],
                 probe=lambda: len(beacon.attestations),
+                what="a slot after the crash that exactly the three live nodes broadcast",
             ))[0]
 
             restart_task = cluster.restart_node(4)
@@ -302,9 +291,10 @@ def test_chaos_crash_recover():
                     if s in own
                 ]
 
-            rejoined = (await _wait_progress(
+            rejoined = (await wait_progress(
                 fully_rejoined,
                 probe=lambda: len(beacon.attestations),
+                what="a slot after the restart that all four broadcast and node 4's own VC signed",
             ))[0]
         finally:
             await _stop(cluster, tasks)
@@ -349,9 +339,10 @@ def test_chaos_crypto_backend_loss():
         tasks = _start(cluster)
         beacon = cluster.beacon
         try:
-            slots = await _wait_progress(
+            slots = await wait_progress(
                 lambda: _slots_with(beacon, 4),
                 probe=lambda: len(beacon.attestations),
+                what="a slot all four nodes broadcast after the primary backend died",
             )
         finally:
             await _stop(cluster, tasks)
@@ -390,9 +381,10 @@ def test_chaos_round_change_storm():
         tasks = _start(cluster)
         beacon = cluster.beacon
         try:
-            slots = await _wait_progress(
+            slots = await wait_progress(
                 lambda: _slots_with(beacon, 4),
                 probe=lambda: len(beacon.attestations),
+                what="a slot all four nodes broadcast under 20 % QBFT message loss",
             )
         finally:
             await _stop(cluster, tasks)
@@ -469,9 +461,10 @@ def test_chaos_corrupt_duplicate_parsig_frames():
         tasks = _start(cluster)
         beacon = cluster.beacon
         try:
-            slots = await _wait_progress(
+            slots = await wait_progress(
                 lambda: _slots_with(beacon, 4),
                 probe=lambda: len(beacon.attestations),
+                what="a slot all four nodes broadcast over the mangled parsig wire",
             )
         finally:
             await _stop(cluster, tasks)

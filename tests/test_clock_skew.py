@@ -34,6 +34,7 @@ import pytest
 
 from charon_tpu.core.deadline import SlotClock
 from charon_tpu.testutil.chaos import SkewedClock
+from charon_tpu.testutil.waiting import wait_until
 
 # -- app/retry.Retryer -------------------------------------------------------
 
@@ -176,10 +177,9 @@ def test_parsigex_resend_survives_forward_wall_step():
             duty = Duty(0, DutyType.ATTESTER)
             await ex.broadcast(duty, {})  # inline attempt fails -> task
             clock.step(3600.0)  # step while the retry task backs off
-            for _ in range(200):
-                if ex.resend_total:
-                    break
-                await asyncio.sleep(0.02)
+            await wait_until(
+                lambda: ex.resend_total, "the resend through the clock step"
+            )
             assert ex.resend_total == 1  # resent THROUGH the step
             assert transport.sends == 3  # inline + failed retry + ok
 

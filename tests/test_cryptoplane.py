@@ -413,6 +413,7 @@ def test_no_dispatch_gate_means_no_gating():
 
 from charon_tpu.core import cryptoplane as _cp
 from charon_tpu.crypto import g1g2
+from charon_tpu.testutil.waiting import wait_until
 
 YEAR = 3.0e7
 
@@ -561,8 +562,7 @@ def test_submission_still_decoding_joins_the_complete_flush(clock, monkeypatch):
         slow = asyncio.create_task(coal.verify([_lane(slow_root)], wave=(("B", 1),)))
         await _settle()
         fast = [asyncio.create_task(coal.verify([_lane()], wave=(("A", 2),))) for _ in range(2)]
-        while len(coal._verify_q) < 2:  # both decoded and counted
-            await asyncio.sleep(0.001)
+        await wait_until(lambda: len(coal._verify_q) >= 2, "both decoded and counted")
         await _settle()
         assert fake.verify_calls == 0 and not coal._flush_task.done(), (
             "wave A is whole, but a submission is still decoding")
@@ -847,8 +847,7 @@ def test_kinds_that_close_in_the_same_instant_are_parts_of_one_close(clock):
         assert set(coal._timers) == {"attester", "sync_message"}
         coal._executor.submit(busy.wait)  # a program still on the device
         _ring_timer(coal, clock)
-        while len(coal._ready) < 2:
-            await asyncio.sleep(0)
+        await wait_until(lambda: len(coal._ready) >= 2, "both jobs ready")
         busy.set()
         return await _all(*jobs)
 
@@ -879,8 +878,7 @@ def test_a_whole_kind_leaves_while_another_kinds_close_waits_for_its_decode(cloc
 
     async def main():
         sync = _submit_all(coal, waves["sync_message"][:3])
-        while len(coal._verify_q) < 3:
-            await asyncio.sleep(0.001)
+        await wait_until(lambda: len(coal._verify_q) >= 3, "three sync sets decoded")
         sync += _submit_all(coal, waves["sync_message"][3:])  # held in its decode
         await _settle()
         _ring_timer(coal, clock)
@@ -998,8 +996,7 @@ def test_a_set_still_on_the_decode_pool_counts_as_in(clock, monkeypatch):
         await _settle()
         assert not coal._timers and coal._collecting_urgency("attester") == (float("inf"), 12)
         sync = _submit_all(coal, waves["sync_message"])
-        while not coal._yielding:
-            await asyncio.sleep(0.001)
+        await wait_until(lambda: coal._yielding, "the flush yielding to the sync wave")
         assert fake.verify_calls == 0 and "attester" not in coal._timers
         assert coal.windows_closed == {"complete": 1}
         gate.set()

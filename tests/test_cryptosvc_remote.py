@@ -37,6 +37,7 @@ from charon_tpu.p2p.codec import (
 from charon_tpu.p2p.quarantine import PeerQuarantine
 from charon_tpu.tbls import TblsError
 from charon_tpu.testutil.chaos import SkewedClock
+from charon_tpu.testutil.waiting import wait_until
 
 SEED = 20260808
 
@@ -99,10 +100,7 @@ async def _connected_client(svc, server_kw=None, **kw):
         local=kw.pop("local", FakeLocal()), **kw,
     )
     await client.start()
-    for _ in range(400):
-        if client.state != "down":
-            break
-        await asyncio.sleep(0.005)
+    await wait_until(lambda: client.state != "down", "the client's handshake")
     assert client.state == "probing"
     return server, client
 
@@ -132,10 +130,9 @@ def test_reconnect_backoff_matches_seeded_schedule():
             backoff_config=cfg, rng=random.Random(SEED),
         )
         await client.start()
-        for _ in range(400):
-            if len(client.reconnect_delays) >= 5:
-                break
-            await asyncio.sleep(0.005)
+        await wait_until(
+            lambda: len(client.reconnect_delays) >= 5, "five reconnect attempts"
+        )
         await client.close()
         got = client.reconnect_delays[:5]
         ref = random.Random(SEED)
@@ -190,10 +187,10 @@ def test_heartbeat_echo_refreshes_last_pong():
             # heartbeat ECHOES refresh the pong clock, so stay expired-
             # adjacent until the next echo arrives
             await client.verify([b"a", b"b"])
-            for _ in range(400):
-                if client._last_pong >= state[0]:
-                    break
-                await asyncio.sleep(0.005)
+            await wait_until(
+                lambda: client._last_pong >= state[0],
+                "the pong of the newest heartbeat",
+            )
             assert client._last_pong == state[0]
             assert not client._heartbeat_expired()
         finally:

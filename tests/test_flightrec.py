@@ -6,6 +6,7 @@ recorder is app-layer stdlib.
 
 from __future__ import annotations
 
+import asyncio
 import json
 import os
 import signal
@@ -25,6 +26,7 @@ from charon_tpu.app.flightrec import (
     merge_jsonl,
     render_timeline,
 )
+from charon_tpu.testutil.waiting import wait_until
 
 
 def test_flush_storm_cannot_evict_rare_categories():
@@ -213,9 +215,8 @@ def test_sigterm_dumps_and_chains(tmp_path):
     uninstall = install_crash_handlers(rec, path)
     try:
         os.kill(os.getpid(), signal.SIGTERM)
-        deadline = time.monotonic() + 5
-        while not chained and time.monotonic() < deadline:
-            time.sleep(0.01)  # signal lands at a bytecode boundary
+        # the signal lands at a bytecode boundary
+        asyncio.run(wait_until(lambda: chained, "the chained SIGTERM handler"))
         assert chained == ["prev"]
         header = json.loads(open(path).readline())
         assert header["trigger"] == "sigterm"

@@ -14,6 +14,7 @@ from charon_tpu.app.obolapi import ObolApiClient
 from charon_tpu.app.stacksnipe import KNOWN_BINARIES, StackSniper, snipe
 from charon_tpu.tbls.python_impl import PythonImpl
 from charon_tpu.testutil.obolapimock import ObolApiMock
+from charon_tpu.testutil.waiting import wait_until
 
 
 @pytest.fixture(autouse=True)
@@ -90,9 +91,12 @@ def test_stacksnipe_periodic_reports(tmp_path):
             interval=0.01, on_report=reports.append, proc_root=tmp_path
         )
         sniper.start()
-        await asyncio.sleep(0.05)
+        await wait_until(
+                lambda: reports,
+                "the sniper's first report",
+            )
         await sniper.stop()
-        assert reports and reports[0] == {"teku": [7]}
+        assert reports[0] == {"teku": [7]}
 
     asyncio.run(run())
 

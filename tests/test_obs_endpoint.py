@@ -266,12 +266,14 @@ def test_a_built_node_serves_and_keeps_its_own_tracer(tmp_path, monkeypatch):
             with urllib.request.urlopen(f"http://127.0.0.1:{port}{path}") as resp:
                 return json.loads(resp.read())
 
-        for _ in range(100):
-            try:
-                traces = await asyncio.to_thread(get, "/debug/traces")
-                break
-            except OSError:
-                await asyncio.sleep(0.05)
+        async def served(path):
+            while True:  # until the endpoint listens
+                try:
+                    return await asyncio.to_thread(get, path)
+                except OSError:
+                    await asyncio.sleep(0.05)
+
+        traces = await asyncio.wait_for(served("/debug/traces"), 30)
         names = {s["name"] for s in traces}
         assert "vapi.submit" in names and "parsigex.receive" not in names
         assert "parsigex.receive" not in hooked and "vapi.submit" in hooked

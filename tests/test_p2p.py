@@ -23,6 +23,7 @@ from charon_tpu.core.types import Duty, DutyType, PubKey
 from charon_tpu.p2p import codec
 from charon_tpu.p2p.adapters import TcpParSigTransport, TcpQbftNet
 from charon_tpu.p2p.transport import P2PNode, PeerSpec
+from charon_tpu.testutil.waiting import wait_until
 
 CLUSTER_HASH = b"\x11" * 32
 
@@ -166,8 +167,11 @@ def test_parsigex_over_tcp():
                 data=SignedData("randao", 0, b"\x07" * 96), share_idx=1
             )
             await exes[0].broadcast(duty, {PubKey("0xbb"): psig})
-            await asyncio.sleep(0.3)
-            assert received[1] and received[2] and not received[0]
+            await wait_until(
+                lambda: received[1] and received[2],
+                "the broadcast at both peers",
+            )
+            assert not received[0]
             assert received[1][0][1][PubKey("0xbb")] == psig
         finally:
             for node in nodes:
@@ -206,7 +210,10 @@ def test_inbound_spans_do_not_inherit_the_span_that_dialed_the_connection():
             await asyncio.sleep(0.2)
             # node 1 answers on whatever connection the pair has, from no span
             await exes[1].broadcast(later, {PubKey("0xbb"): psig(2)})
-            await asyncio.sleep(0.3)
+            await wait_until(
+                lambda: [s for s in t0.spans if s.name == "parsigex.receive"],
+                "node 0's parsigex.receive span",
+            )
             (recv,) = [s for s in t0.spans if s.name == "parsigex.receive"]
             assert recv.attrs["duty"] == str(later)
             assert recv.trace_id == tracer.duty_trace_id(later)

@@ -18,6 +18,7 @@ from charon_tpu.app.peerinfo import PeerInfoService
 from charon_tpu.app.privkeylock import PrivKeyLock, PrivKeyLockError
 from charon_tpu.testutil.chaos import blast_garbage, fuzz_node
 from charon_tpu.p2p.relay import RelayClient, RelayServer
+from charon_tpu.testutil.waiting import wait_until
 
 from tests.test_p2p import make_mesh  # reuse mesh fixture helpers
 
@@ -48,7 +49,10 @@ def test_relay_forwarding():
             await c0.connect()
             await c1.connect()
             await c0.send(1, b"hello-via-relay")
-            await asyncio.sleep(0.1)
+            await wait_until(
+                lambda: got,
+                "the relayed frame",
+            )
             assert got == [(0, b"hello-via-relay")]
             # different cluster hash is isolated
             cx = RelayClient("127.0.0.1", port, b"\x02" * 32, 0)

@@ -19,7 +19,14 @@ from charon_tpu.testutil import schemas
 from charon_tpu.testutil.simnet import build_cluster
 from charon_tpu.testutil.vapiclient import SchemaCheckedVapiClient
 
-from test_vapi_http_e2e import _start_http, _stop_http, _wire_http_vmocks
+from charon_tpu.testutil.waiting import wait_for_broadcasts
+
+from test_vapi_http_e2e import (
+    ALL_DUTIES_SLOT,
+    _start_http,
+    _stop_http,
+    _wire_http_vmocks,
+)
 
 
 @pytest.fixture(autouse=True)
@@ -40,7 +47,11 @@ def test_all_duties_schema_conformant():
 
     async def run():
         cluster = build_cluster(
-            n=4, t=3, num_validators=1, slot_duration=0.5, wire_vmock=False
+            n=4,
+            t=3,
+            num_validators=1,
+            slot_duration=ALL_DUTIES_SLOT,
+            wire_vmock=False,
         )
         routers, clients, vmocks = await _start_http(
             cluster, client_cls=SchemaCheckedVapiClient
@@ -57,8 +68,6 @@ def test_all_duties_schema_conformant():
             for vm in vmocks:
                 await vm.register(pubkey)
                 await vm.exit(pubkey, epoch=0)
-
-            from charon_tpu.testutil.waiting import wait_for_broadcasts
 
             await wait_for_broadcasts(beacon, want=4)
 

@@ -20,6 +20,7 @@ from charon_tpu.core import qbft
 from charon_tpu.core.types import Duty, DutyType
 from charon_tpu.core.wire import tracing
 from charon_tpu.tbls.python_impl import PythonImpl
+from charon_tpu.testutil.waiting import wait_progress, wait_until
 
 # the wire edges every completed attestation duty must traverse,
 # in pipeline order (core/wire.wire subscription graph)
@@ -146,10 +147,15 @@ def test_chaos_corrupted_frame_ctx_never_crashes():
         duty = Duty(slot=3, type=DutyType.ATTESTER)
         with tracer.span("parsigex.broadcast", duty=duty, tracer=tracers[0]):
             await transport.send(1, duty, {}, tctx=tracer.encode_ctx())
-        await asyncio.sleep(0.1)  # chaos delivery tasks
+        def received():
+            return [s for s in tracers[1].dump() if s["name"] == "parsigex.receive"]
+
+        await wait_until(
+                received,
+                "the corrupted frame's delivery (the chaos delivery tasks)",
+            )
         assert transport.corrupted >= 1
-        recv = [s for s in tracers[1].dump() if s["name"] == "parsigex.receive"]
-        assert recv, "corrupted frame was not delivered"
+        recv = received()
         for s in recv:
             # fallback: fresh duty-rooted root, NOT the sender's span
             assert s["parent_id"] == ""
@@ -235,11 +241,11 @@ def test_simnet_cross_node_traces_merge(host_tbls, tmp_path):
         ]
         try:
 
-            async def enough():
-                while len(_completed_attester_slots(cluster.beacon, 4)) < 2:
-                    await asyncio.sleep(0.05)
-
-            await asyncio.wait_for(enough(), timeout=60)
+            await wait_progress(
+                lambda: len(_completed_attester_slots(cluster.beacon, 4)) >= 2,
+                probe=lambda: len(cluster.beacon.attestations),
+                what="two slots all four nodes broadcast",
+            )
         finally:
             for node in cluster.nodes:
                 node.scheduler.stop()

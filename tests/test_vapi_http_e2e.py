@@ -23,6 +23,7 @@ from charon_tpu.tbls.python_impl import PythonImpl
 from charon_tpu.testutil.simnet import build_cluster
 from charon_tpu.testutil.validatormock import HttpValidatorMock
 from charon_tpu.testutil.vapiclient import HttpVapiClient
+from charon_tpu.testutil.waiting import wait_for_broadcasts
 
 
 @pytest.fixture(autouse=True)
@@ -93,10 +94,25 @@ def _wire_http_vmocks(cluster, vmocks):
         node.scheduler.subscribe_duties(on_duty)
 
 
+# The slot a LOADED tier-1 worker serves the full duty matrix in. Every
+# duty kind fires every slot here, and four nodes' BLS runs on the one
+# event loop: 1.9 CPU-s a slot (768 native verify_batch calls of 13.5 ms
+# in 5.4 slots: cProfile, my sandbox, PR 41); a worker under six-fold
+# load has a third of a core. A loop that falls behind its slots never
+# catches up, and a flow that comes later than the scheduler keeps an
+# epoch's duty definitions (two epochs) is refused for ever: 404 "no
+# attester duty".
+ALL_DUTIES_SLOT = 6.0
+
+
 def test_http_e2e_all_duties():
     async def run():
         cluster = build_cluster(
-            n=4, t=3, num_validators=1, slot_duration=0.5, wire_vmock=False
+            n=4,
+            t=3,
+            num_validators=1,
+            slot_duration=ALL_DUTIES_SLOT,
+            wire_vmock=False,
         )
         routers, clients, vmocks = await _start_http(cluster)
         _wire_http_vmocks(cluster, vmocks)
@@ -112,8 +128,6 @@ def test_http_e2e_all_duties():
             for vm in vmocks:
                 await vm.register(pubkey)
                 await vm.exit(pubkey, epoch=0)
-
-            from charon_tpu.testutil.waiting import wait_for_broadcasts
 
             await wait_for_broadcasts(beacon, want=4)
         finally:

@@ -29,6 +29,7 @@ from charon_tpu.core.eth2data import (
 )
 from charon_tpu.core.types import Duty, DutyType, PubKey
 from charon_tpu.p2p import codec
+from charon_tpu.testutil.waiting import wait_until
 
 DUTY = Duty(123456, DutyType.ATTESTER)
 ATT = Attestation(
@@ -460,7 +461,10 @@ def test_broadcast_single_encode_and_codec_error_drop():
                 node.register_handler("bcast", handler)
             payload = {"duty": DUTY, "set": _parsig_set(2), "tctx": None}
             await nodes[0].broadcast("bcast", payload)
-            await asyncio.sleep(0.3)
+            await wait_until(
+                lambda: len(seen) >= 2,
+                "the broadcast at both peers",
+            )
             assert len(seen) == 2
             # one timed binary encode + one timed JSON encode (node 2);
             # no third encode — the binary body was cached per codec
@@ -474,7 +478,10 @@ def test_broadcast_single_encode_and_codec_error_drop():
             async with conn.lock:
                 tmod._write_sframe(conn, bytes([1, 0x7F, 0xFF, 0xFF]))
                 await conn.writer.drain()
-            await asyncio.sleep(0.2)
+            await wait_until(
+                lambda: nodes[1].codec_dropped > before,
+                "the malformed frame's drop",
+            )
             assert nodes[1].codec_dropped == before + 1
             pong = await nodes[0].send(1, "ping", None, await_response=True)
             assert pong == {"pong": 1}
@@ -512,12 +519,18 @@ def test_peer_codec_quarantine_exponential_backoff(monkeypatch):
 
     from charon_tpu.p2p import transport as tmod
 
+    # a mute of 1 s (then 2 s): the valid frame below must ARRIVE
+    # inside it, also where a loaded worker's event loop stalls for
+    # some tenths of a second
     monkeypatch.setattr(tmod, "QUARANTINE_STRIKES", 3)
-    monkeypatch.setattr(tmod, "QUARANTINE_BASE", 0.2)
+    monkeypatch.setattr(tmod, "QUARANTINE_BASE", 1.0)
     monkeypatch.setattr(tmod, "RECV_TIMEOUT", 0.5)
 
     async def blast_malformed(src, dst_idx, n):
-        conn = src._conns[dst_idx]
+        # through the transport's own look-up: a send that timed out
+        # has dropped its connection from the table (P2PNode.send,
+        # "drop the dead connection"), and the next frame re-dials
+        conn = await src._get_conn(dst_idx)
         async with conn.lock:
             for _ in range(n):
                 tmod._write_sframe(conn, bytes([1, 0x7F, 0xFF, 0xFF]))
@@ -529,32 +542,32 @@ def test_peer_codec_quarantine_exponential_backoff(monkeypatch):
             await node.start()
         mutes = []
         nodes[1].quarantine_observer = lambda p, m: mutes.append((p, m))
+
         try:
             assert await nodes[0].send(1, "ping", None, await_response=True)
             # strikes 1..3 inside the window: mute imposed at base
             await blast_malformed(nodes[0], 1, 3)
-            await asyncio.sleep(0.1)
-            assert nodes[1].peer_quarantines == 1
+            await wait_until(lambda: nodes[1].peer_quarantines == 1, "the first mute")
             assert nodes[1].peer_quarantined(0)
-            assert mutes == [(0, 0.2)]
+            assert mutes == [(0, 1.0)]
             # while muted, even a VALID frame drops before decode
             dropped_before = nodes[1].quarantined_frames
             with pytest.raises(asyncio.TimeoutError):
                 await nodes[0].send(1, "ping", None, await_response=True)
             assert nodes[1].quarantined_frames > dropped_before
             # repeat offense right after expiry: the mute DOUBLES
-            await asyncio.sleep(0.2)
+            await wait_until(lambda: not nodes[1].peer_quarantined(0), "the mute's end")
             await blast_malformed(nodes[0], 1, 3)
-            await asyncio.sleep(0.1)
-            assert mutes == [(0, 0.2), (0, 0.4)]
+            await wait_until(lambda: nodes[1].peer_quarantines == 2, "the second mute")
+            assert mutes == [(0, 1.0), (0, 2.0)]
             # a clean frame after expiry forgives the backoff level
-            await asyncio.sleep(0.45)
+            await wait_until(lambda: not nodes[1].peer_quarantined(0), "the mute's end")
             assert await nodes[0].send(1, "ping", None, await_response=True)
             assert not nodes[1]._quarantine._level
             # next offense starts back at the base mute
             await blast_malformed(nodes[0], 1, 3)
-            await asyncio.sleep(0.1)
-            assert mutes[-1] == (0, 0.2)
+            await wait_until(lambda: nodes[1].peer_quarantines == 3, "the third mute")
+            assert mutes[-1] == (0, 1.0)
         finally:
             for node in nodes:
                 await node.stop()
