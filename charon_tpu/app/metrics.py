@@ -223,6 +223,14 @@ class ClusterMetrics:
             "verified on entry)",
             ["family"],
         )
+        self.plane_miller_pairs = counter(
+            "tpu_plane_miller_pairs_total",
+            "Miller pairs the flushes' fast programs ran, from the "
+            "buckets dispatched, by the queue the flush held (verify: "
+            "bucket + 8, a pair a lane and one a set, whose signatures "
+            "are summed in G2 first; recombine: two a row of the bucket)",
+            ["family"],
+        )
         self.plane_flushes_recombine_attributed = counter(
             "tpu_plane_flushes_recombine_attributed_total",
             "Recombine flushes whose group-signature check failed and "

@@ -545,6 +545,9 @@ async def build_node(config: Config) -> Node:
                 metrics.labels(metrics.plane_pairing_lanes, family).inc(
                     s.pairing_lanes
                 )
+                metrics.labels(metrics.plane_miller_pairs, family).inc(
+                    s.miller_pairs
+                )
             if s.recombine_attributed:
                 metrics.labels(
                     metrics.plane_flushes_recombine_attributed

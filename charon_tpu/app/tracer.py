@@ -479,7 +479,9 @@ def plane_span_bridge(
     device first says for how long and to which (`yielded`,
     `yielded_to`). The device stage says `pairing_lanes`: the lanes its
     fast programs checked, a verify flush's lanes or a recombine flush's
-    rows (the recombine program checks the group signature alone).
+    rows (the recombine program checks the group signature alone), and
+    `miller_pairs`: the Miller pairs those programs ran, from the buckets
+    dispatched (a verify program pairs a set's summed signature once).
 
     A flush coalesces submissions from several spans of several duties;
     `stats.parents` carries each submission's captured span context, and
@@ -539,6 +541,7 @@ def plane_span_bridge(
             device_attrs = {
                 "fallback": stats.fallback,
                 "pairing_lanes": getattr(stats, "pairing_lanes", 0),
+                "miller_pairs": getattr(stats, "miller_pairs", 0),
                 **kind,
             }
             if programs is not None:

@@ -321,6 +321,15 @@ class FlushStats:
     # whose verify tier does its work: a miss is a finding)
     pairing_lanes: int = 0
     recombine_attributed: bool = False
+    # Miller pairs the flush's fast programs ran, from the buckets it
+    # dispatched: bucket + VERIFY_SETS for a parsed verify dispatch (a
+    # pair a lane, the key side, and ONE a set, the set's summed
+    # signature: `_miller_pairs`), two a lane of the bucket for a
+    # point-path verify or a recombine dispatch. Work, where
+    # `pairing_lanes` is verdicts: padding lanes ride the scan too.
+    # Counted where `padded_lanes` is, a packed flush: 0 on the
+    # single-stage rung and the host-oracle fallback
+    miller_pairs: int = 0
     # (trace_id, span_id) captured from each submission's active span
     parents: tuple[tuple[str, str], ...] = ()
     # live lanes per submitting tenant (ISSUE 8): (tenant_id, lanes)
@@ -1711,7 +1720,7 @@ class SlotCoalescer:
             # single-stage flush (pool disabled / pack failed): lane
             # normalization runs here on the device lane instead
             parsed = self._normalize_jobs(vq, rq)
-        lanes = 0
+        lanes = miller_pairs = 0
         pad_lanes = padded_lanes = 0 if packed is not None else None
         vres: list[list[bool]] = []
         self._attributions.clear()
@@ -1730,6 +1739,7 @@ class SlotCoalescer:
                 shipped = self._packed_lane_count(arrays)
                 pad_lanes += shipped - n
                 padded_lanes += shipped
+                miller_pairs += self._miller_pairs(shipped, sets=vparsed)
             else:
                 flat = self._flat_verify_lanes(vq)
                 n = len(flat)
@@ -1769,6 +1779,7 @@ class SlotCoalescer:
                 shipped = self._packed_lane_count(arrays)
                 pad_lanes += shipped - v
                 padded_lanes += shipped
+                miller_pairs += self._miller_pairs(shipped)
             else:
                 ps, msg, sig, gpk, idx = self._live_recombine_rows(rq)
                 if msg and parsed:
@@ -1838,6 +1849,7 @@ class SlotCoalescer:
                 # verify lanes + live recombine rows, a lane each
                 pairing_lanes=lanes,
                 recombine_attributed=self._recombine_attributed,
+                miller_pairs=miller_pairs,
                 parents=self._job_parents(vq, rq),
                 tenant_lanes=self._job_tenant_lanes(vq, rq),
             ),
@@ -1860,6 +1872,17 @@ class SlotCoalescer:
             "attribute_span": (ran[0][0], ran[-1][1]) if ran else None,
             "attribute_lanes": sum(lanes for _s, _e, lanes in ran),
         }
+
+    def _miller_pairs(self, bucket: int, sets: bool = False) -> int:
+        """Miller pairs one RLC dispatch of `bucket` lanes (rows) runs:
+        two a lane — (r * pk, H(m)) and (r * -G1, sig) — except in the
+        parsed verify program (`sets`), which sums a set's r * sig in G2
+        and pairs (-G1, sum) once a set: a pair a lane and one for each
+        of the plane's VERIFY_SETS segments (ops/pairing.
+        batched_verify_rlc_sets)."""
+        if sets:
+            return bucket + getattr(self.plane, "VERIFY_SETS", 0)
+        return 2 * bucket
 
     @staticmethod
     def _packed_lane_count(arrays) -> int:

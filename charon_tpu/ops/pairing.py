@@ -433,6 +433,57 @@ def _point_sum_tree(C, f, pts, n: int, axis: int = 0):
     )
 
 
+def _point_sum_scan(C, f, pts, n: int, rows: int):
+    """Sum of projective points over axis 0 — [n, *grid] in, [*grid] out
+    — as ONE lax.scan of complete adds on flat lanes. The points are cut
+    into slices of `rows` flat lanes (a power of two; a whole number of
+    axis-0 entries, at least one and at most all n); the scan adds the
+    slices into the first, one a step, then folds what it holds by
+    log2 steps of acc + roll(acc) at halving distances, after which
+    every entry of the slice is the total, and the first is returned.
+    The compiled module holds the add ONCE, in the scan's body, where a
+    halving tree over n holds it log2(n) times: an unrolled level of a
+    complete G2 add is ~17 MB of generated code on a v5e, and a plane
+    program's load at boot goes with its code. A step of adds costs the
+    device the same from 32 lanes to a kernel tile of them (launches:
+    PERF.md §6 PR 42), so slices of one tile make the fewest steps."""
+    pts, n = _pad_pow2(C, f, pts, 0, n)
+    grid = jax.tree_util.tree_leaves(pts)[0].shape[1:-1]
+    width = int(np.prod(grid, dtype=np.int64))  # flat lanes an entry
+    per = max(1, min(rows // width, n))  # entries a slice
+    slices = n // per
+    parts = jax.tree_util.tree_map(
+        lambda a: a.reshape(slices, per * width, a.shape[-1]), pts
+    )
+
+    def step(acc, k):
+        nxt = jax.tree_util.tree_map(
+            lambda a: lax.dynamic_index_in_dim(
+                a, jnp.minimum(k + 1, slices - 1), 0, keepdims=False
+            ),
+            parts,
+        )
+        # once the slices are in: per / 2, per / 4 .. 1 entries away
+        away = (per * width) >> jnp.maximum(k + 2 - slices, 1)
+        other = jax.tree_util.tree_map(
+            lambda a, b: jnp.where(
+                k + 1 < slices, a, jnp.roll(b, -away, axis=0)
+            ),
+            nxt,
+            acc,
+        )
+        return C.point_add(f, acc, other), None
+
+    acc, _ = lax.scan(
+        step,
+        jax.tree_util.tree_map(lambda a: a[0], parts),
+        jnp.arange(slices - 1 + per.bit_length() - 1),
+    )
+    return jax.tree_util.tree_map(
+        lambda a: a[:width].reshape(*grid, a.shape[-1]), acc
+    )
+
+
 def batched_verify_grouped_rlc(
     ctx: ModCtx, fr_ctx: ModCtx, pk, msg, sig, rand, nbits: int = 64
 ):
@@ -555,7 +606,10 @@ def batched_verify_rlc(
     64-bit G1 double-and-add and a log2(N)-depth fp12 product tree with
     ONE shared final exponentiation. The Miller stage is byte-identical
     in structure (same stacked 2-pair scan), so the compiled program is
-    no bigger than the per-lane kernel's.
+    no bigger than the per-lane kernel's. The recombine programs' form:
+    their 32- and 64-row batches sit under one kernel tile with two
+    pairs a lane or one; batched_verify_rlc_sets (the parsed verify
+    program) sums r_i * sig_i in G2 and pairs the sum once a set.
 
     Returns a scalar bool (all-valid).
     """
@@ -588,50 +642,93 @@ def batched_verify_rlc_sets(
     ctx: ModCtx, fr_ctx: ModCtx, pk, msg, sig, rand, seg, n_sets: int,
     nbits: int = 64,
 ):
-    """batched_verify_rlc with the product taken per SET: `seg` is an
-    int32 [N] segment id per lane (0 <= seg < n_sets, n_sets static) and
-    the answer a bool [n_sets] — set s passes iff
+    """RLC verification with one verdict per SET, the signature side
+    summed in G2: `seg` is an int32 [N] segment id per lane (0 <= seg <
+    n_sets, n_sets static) and the answer a bool [n_sets] — set s passes
+    iff
 
-        prod_{i : seg_i == s} (e(pk_i, H(m_i)) * e(-G1, sig_i))^(r_i) == 1
+        prod_{i : seg_i == s} e(r_i * pk_i, H(m_i))  *  e(-G1, S_s) == 1,
+        S_s = sum_{i : seg_i == s} r_i * sig_i
 
-    Same stacked scalar mul and Miller stage as batched_verify_rlc
-    (which the recombine programs keep calling, left as it is); then
-    `n_sets` masked product trees over the lanes' Miller values and ONE
-    final exponentiation over the [n_sets] batch. Each set's product is
-    its own Schwartz-Zippel check at 2^-nbits under the lanes' own
-    independent exponents, and the whole-batch answer is the AND of the
-    sets'. An empty segment's product is 1 and reads True; a lane with
-    exponent 0 (padding, undecodable) is neutral in whichever segment it
-    rides. A partial-signature set is dropped whole on one bad lane, so
-    a verdict per set is the verdict the protocol needs — on a failing
-    batch this saves the per-lane program's dispatch.
+    which is batched_verify_rlc's product over the set's lanes, factor
+    for factor (e(r_i * (-G1), sig_i) = e(-G1, r_i * sig_i), and the
+    pairing is bilinear on the sum because decompression checked every
+    sig_i into the r-torsion subgroup). Per lane: one 64-bit G1
+    double-and-add (r_i * pk_i), one 64-bit G2 double-and-add (r_i *
+    sig_i), ONE Miller pair; per set: the sum S_s (_point_sum_scan over
+    the identity-masked [N, n_sets] grid: one scan of complete G2 adds,
+    a kernel tile of lanes a step), one Miller pair (-G1, S_s) riding
+    the same Miller scan as lanes N .. N + n_sets - 1, a masked Fp12
+    product tree; one inversion over N + n_sets rows takes the lanes
+    and the sums to affine together, and ONE final exponentiation over
+    the [n_sets] batch ends it. Each set's
+    product is its own Schwartz-Zippel check at 2^-nbits under the lanes'
+    own independent exponents (two genuine signatures swapped between two
+    lanes of a set fail it), and the whole-batch answer is the AND of the
+    sets'. An empty segment sums to the identity — affine (0, 0), a dead
+    Miller pair — its product is 1 and reads True; a lane with exponent 0
+    (padding, undecodable) is the identity on both sides, neutral in
+    whichever segment it rides. A partial-signature set is dropped whole
+    on one bad lane, so a verdict per set is the verdict the protocol
+    needs — on a failing batch this saves the per-lane program's
+    dispatch.
     """
-    from charon_tpu.ops import curve as C
+    from charon_tpu.ops.pallas_mont import TILE
 
-    g1f = C.g1_ops(ctx)
-    batch_shape = pk[0].shape[:-1]
-    neg_g = neg_g1_gen(ctx, batch_shape)
-    pts = jax.tree_util.tree_map(
-        lambda a, b: jnp.stack(jnp.broadcast_arrays(a, b)), pk, neg_g
+    g1f, g2f = C.g1_ops(ctx), C.g2_ops(ctx)
+    n = seg.shape[0]
+    pk_r = C.point_scalar_mul(
+        g1f, fr_ctx, C.affine_to_point(g1f, pk), rand, nbits=nbits
     )
-    rand2 = jnp.stack(jnp.broadcast_arrays(rand, rand))
-    scaled = C.point_scalar_mul(
-        g1f, fr_ctx, C.affine_to_point(g1f, pts), rand2, nbits=nbits
+    sig_r = C.point_scalar_mul(
+        g2f, fr_ctx, C.affine_to_point(g2f, sig), rand, nbits=nbits
     )
-    aff = C.point_to_affine(g1f, scaled)
-    pk_r = jax.tree_util.tree_map(lambda a: a[0], aff)
-    negg_r = jax.tree_util.tree_map(lambda a: a[1], aff)
-
-    f_lanes = miller_loop(ctx, [(pk_r, msg), (negg_r, sig)])  # [N] fp12
-    # [N, n_sets]: lane i's value in its own segment's column, 1 in the
-    # others; the halving tree over axis 0 then takes every set's
-    # product at once
     in_set = seg[:, None] == jnp.arange(n_sets, dtype=seg.dtype)[None, :]
-    ones = T.fp12_one(ctx, (n_sets,))
-    f_sets = jax.tree_util.tree_map(
-        lambda a, o: jnp.where(in_set[..., None], a[:, None, :], o),
-        f_lanes,
-        ones,
+
+    def by_set(lanes, neutral):
+        # [N, n_sets]: lane i's value in its own segment's column, the
+        # neutral element in the others; a fold over axis 0 then takes
+        # every set's sum (product) at once
+        return jax.tree_util.tree_map(
+            lambda a, o: jnp.where(in_set[..., None], a[:, None, :], o),
+            lanes,
+            neutral,
+        )
+
+    def append(a, b):
+        return jnp.concatenate((a, b), axis=0)
+
+    s_sets = _point_sum_scan(
+        C, g2f, by_set(sig_r, C.point_identity(g2f, (n_sets,))), n, TILE
     )
-    e = final_exp(ctx, _fp12_prod_tree(ctx, f_sets))  # [n_sets]
-    return T.fp12_is_one(ctx, e)
+    # both conversions to affine (identity -> (0, 0)) through ONE Fermat
+    # inversion: its 381-step chain costs a kernel launch a step whatever
+    # the rows, so the norms of the n_sets sums' Z (Fp2: 1 / z = conj(z) /
+    # norm(z)) ride with the lanes' Z
+    z0, z1 = s_sets[2]
+    norm = limb.add_mod(ctx, limb.mont_sqr(ctx, z0), limb.mont_sqr(ctx, z1))
+    inv = limb.inv_mod(ctx, append(pk_r[2], norm))
+    zinv_s = (
+        limb.mont_mul(ctx, z0, inv[n:]),
+        limb.neg_mod(ctx, limb.mont_mul(ctx, z1, inv[n:])),
+    )
+    pk_aff = tuple(g1f.mul(c, inv[:n]) for c in pk_r[:2])
+    s_aff = tuple(g2f.mul(c, zinv_s) for c in s_sets[:2])  # [n_sets]
+
+    p_lanes = jax.tree_util.tree_map(
+        append, pk_aff, neg_g1_gen(ctx, (n_sets,))
+    )
+    q_lanes = jax.tree_util.tree_map(append, msg, s_aff)
+    f_lanes = miller_loop(ctx, [(p_lanes, q_lanes)])  # [N + n_sets] fp12
+    f_sets = _fp12_prod_tree(
+        ctx,
+        by_set(
+            jax.tree_util.tree_map(lambda a: a[:n], f_lanes),
+            T.fp12_one(ctx, (n_sets,)),
+        ),
+    )
+    # aggregate lane N + s is set s's alone: one multiply, no mask
+    f_sets = T.fp12_mul(
+        ctx, f_sets, jax.tree_util.tree_map(lambda a: a[n:], f_lanes)
+    )
+    return T.fp12_is_one(ctx, final_exp(ctx, f_sets))  # [n_sets]

@@ -382,13 +382,19 @@ class SlotCryptoPlane:
     def _build_verify_rlc_dec(self):
         """RLC verify on PARSED signature lanes, one verdict per SET:
         `seg` names each lane's segment (< VERIFY_SETS) and the RLC
-        product is taken per segment (ops/pairing.batched_verify_rlc_sets)
-        — each shard judges its own lanes of a set under its own
-        exponents, the cross-device op is a psum of per-segment failure
-        counts. Undecodable lanes get exponent 0 (neutral in their set's
-        product) and come back False in the per-lane mask output; a set's
-        verdict therefore means 'every lane of it that DECODED verified'
-        — the host resolves a lane as decode_mask AND its set's verdict."""
+        product is taken per segment (ops/pairing.batched_verify_rlc_sets:
+        a Miller pair a lane, (r * pk, H(m)), and ONE a segment, (-G1,
+        the segment's r * sig summed in G2) — local lanes + VERIFY_SETS
+        pairs a shard, one scan) — each shard judges its own lanes of a
+        set under its own exponents and against its own sum, the
+        cross-device op is a psum of per-segment failure counts.
+        Undecodable lanes get exponent 0 (the identity on both sides:
+        neutral in their set's product and its sum) and come back False
+        in the per-lane mask output; a set's verdict therefore means
+        'every lane of it that DECODED verified' — the host resolves a
+        lane as decode_mask AND its set's verdict. Decompression keeps
+        its subgroup check: the pairing is bilinear on the summed point
+        only for signatures in the r-torsion subgroup."""
         ctx, fr_ctx, axis = self.ctx, self.fr_ctx, self.axis
         n_sets = self.VERIFY_SETS
 

@@ -279,6 +279,21 @@ def test_errors_that_cancel_in_the_lagrange_sum_give_the_group_signature(plane_r
 # -- the program's shape: one Miller pair of lanes a row ------------------------------
 
 
+def miller_scan_carries(closed) -> list[list]:
+    """The carries' avals of every Miller loop in a traced program: a scan
+    over the loop parameter's bits with the conditional add step inside."""
+    from charon_tpu.analysis.jaxpr_check import walk_eqns
+    from charon_tpu.ops import pairing
+
+    return [
+        eqn.params["jaxpr"].in_avals[
+            eqn.params["num_consts"]: eqn.params["num_consts"] + eqn.params["num_carry"]]
+        for eqn in walk_eqns(closed.jaxpr)
+        if eqn.primitive.name == "scan" and eqn.params["length"] == len(pairing.X_BITS)
+        and any(inner.primitive.name == "cond" for inner in walk_eqns(eqn.params["jaxpr"].jaxpr))
+    ]
+
+
 @pytest.mark.parametrize("t", sorted(ROWS), ids=lambda t: f"t{t}")
 def test_the_step_rlc_programs_miller_batch_is_two_lanes_a_row(monkeypatch, t):
     """Traced, not run: `step_rlc` on 4 rows holds ONE Miller loop (the
@@ -287,9 +302,8 @@ def test_the_step_rlc_programs_miller_batch_is_two_lanes_a_row(monkeypatch, t):
     the generator's, a row each — not [2, rows * (t + 1), ...], whatever t."""
     import jax
 
-    from charon_tpu.analysis.jaxpr_check import walk_eqns
     from charon_tpu.crypto.g1g2 import G1_GEN, G2_GEN
-    from charon_tpu.ops import msm, pairing
+    from charon_tpu.ops import msm
     from charon_tpu.parallel import SlotCryptoPlane, make_mesh
 
     monkeypatch.setattr(msm, "msm_active", lambda: False)
@@ -300,16 +314,7 @@ def test_the_step_rlc_programs_miller_batch_is_two_lanes_a_row(monkeypatch, t):
         [list(range(1, t + 1))] * rows)
     rand = plane.make_rand(rows)
     assert rand.shape == (rows, plane.fr_ctx.n_limbs)  # one exponent a row
-    closed = jax.make_jaxpr(plane._step_rlc)(*args, rand)
-    miller = [
-        eqn for eqn in walk_eqns(closed.jaxpr)
-        if eqn.primitive.name == "scan" and eqn.params["length"] == len(pairing.X_BITS)
-        and any(inner.primitive.name == "cond" for inner in walk_eqns(eqn.params["jaxpr"].jaxpr))
-    ]
-    assert len(miller) == 1
-    (loop,) = miller
-    carries = loop.params["jaxpr"].in_avals[
-        loop.params["num_consts"]: loop.params["num_consts"] + loop.params["num_carry"]]
+    (carries,) = miller_scan_carries(jax.make_jaxpr(plane._step_rlc)(*args, rand))
     assert carries and {aval.shape[:2] for aval in carries} == {(2, rows)}
 
 
@@ -402,3 +407,98 @@ def test_the_span_and_the_families_carry_them():
     docs = (REPO / "docs/metrics.md").read_text()
     for name in ("tpu_plane_pairing_lanes_total", "tpu_plane_flushes_recombine_attributed_total"):
         assert f"`{name}`" in docs, name
+
+
+# -- the Miller pairs a flush's programs ran (ISSUE 42) --------------------------------
+
+
+def bucketed_plane():
+    """tests/test_hostplane.ParsedFakePlane whose packs are padded to a
+    bucket of 16, as `parallel/mesh`'s are, and which says how many segments
+    its parsed verify program judges."""
+    import numpy as np
+
+    from tests.test_hostplane import ParsedFakePlane
+
+    def padded(pack):
+        return lambda self, lanes, *rest, **kw: (pack, np.empty(-(-len(lanes) // 16) * 16))
+
+    class BucketedPlane(ParsedFakePlane):
+        VERIFY_SETS = 8
+        pack_verify_inputs, pack_verify_inputs_parsed = padded("v"), padded("vp")
+        pack_inputs = pack_inputs_parsed = padded("r")
+
+    return BucketedPlane(3)
+
+
+@pytest.mark.parametrize("queue, decode_mode, pairs", [
+    ("verify", "device", 16 + 8),  # a pair a lane and one a set's summed signature
+    ("verify", "python", 2 * 16),  # the point path's program: two a lane
+    ("recombine", "device", 2 * 16),
+    ("recombine", "python", 2 * 16),
+])
+def test_a_flush_says_the_miller_pairs_its_programs_ran(queue, decode_mode, pairs):
+    """`FlushStats.miller_pairs` is computed from the BUCKET dispatched:
+    bucket + VERIFY_SETS for the parsed verify program, which pairs each
+    set's summed signature once, two a lane (a row) of the bucket for the
+    point-path verify program and for the recombine programs; `pairing_lanes`
+    beside it still reads the lanes judged."""
+    from tests.test_hostplane import _sig_items
+
+    flushes: list = []
+    coalescer = cp.SlotCoalescer(bucketed_plane(), window=0.005, decode_workers=1,
+                                 decode_mode=decode_mode, stats_hook=flushes.append)
+    batch, pubshares, _secrets, root = wave(3)
+    try:
+        if queue == "verify":
+            assert asyncio.run(coalescer.verify(_sig_items(3))) == [True] * 3
+        else:
+            rows = [(pk, sorted(partials, key=lambda p: p.share_idx)) for pk, partials in batch.items()]
+            asyncio.run(coalescer.recombine(
+                [[pubshares[p.share_idx][pk] for p in row] for pk, row in rows],
+                [root] * len(rows),
+                [[p.data.signature for p in row] for _pk, row in rows],
+                [pubkey_to_bytes(pk) for pk, _row in rows],
+                [[p.share_idx for p in row] for _pk, row in rows]))
+    finally:
+        coalescer.close()
+    (flush,) = flushes
+    assert flush.miller_pairs == pairs
+    assert flush.pairing_lanes == flush.lanes == (3 if queue == "verify" else len(batch))
+    assert flush.padded_lanes == 16
+
+
+def test_a_flush_whose_bucket_the_coalescer_never_saw_counts_no_pairs():
+    """A plane without the packed API (the single-stage rung through
+    `recombine_host`) packs for itself: `miller_pairs` stays 0 there, and
+    `pairing_lanes` still reads the rows."""
+    flushes: list = []
+    coalescer = cp.SlotCoalescer(TwoTierPlane(3), window=0.005, stats_hook=flushes.append)
+    batch, pubshares, *_ = wave(3)
+    try:
+        aggregate(SigAgg(threshold=3, fork=FORK, plane=coalescer, pubshares_by_idx=pubshares), batch)
+    finally:
+        coalescer.close()
+    assert [(f.miller_pairs, f.pairing_lanes) for f in flushes] == [(0, len(batch))]
+
+
+def test_the_device_span_and_the_family_carry_the_miller_pairs():
+    """`miller_pairs` on `cryptoplane.device`; the node feeds
+    `tpu_plane_miller_pairs_total{family}` from a flush's stats beside the
+    pairing lanes, and the catalogue names it."""
+    from charon_tpu.app import tracer
+    from charon_tpu.app.metrics import ClusterMetrics
+
+    t = tracer.Tracer()
+    tracer.plane_span_bridge(t)(cp.FlushStats(
+        jobs=7, lanes=217, flush_seconds=1.0, window=0.3, inflight=1, pad_lanes=39,
+        padded_lanes=256, decode_queue_seconds=(), device_span=(10.0, 11.0),
+        verify_jobs=7, pairing_lanes=217, miller_pairs=264))
+    (device,) = [s for s in t.spans if s.name == "cryptoplane.device"]
+    assert (device.attrs["pairing_lanes"], device.attrs["miller_pairs"]) == (217, 264)
+    assert "metrics.plane_miller_pairs" in (REPO / "charon_tpu/app/run.py").read_text()
+    m = ClusterMetrics("hash", "name", "peer")
+    m.labels(m.plane_miller_pairs, "verify").inc(264)
+    out = m.render().decode()
+    assert 'tpu_plane_miller_pairs_total{' in out and 'family="verify"' in out
+    assert "`tpu_plane_miller_pairs_total`" in (REPO / "docs/metrics.md").read_text()

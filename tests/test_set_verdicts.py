@@ -36,23 +36,33 @@ from tests.isolation_util import (  # noqa: E402
 from tests.test_hostplane import ParsedFakePlane  # noqa: E402
 
 SETS = (1, 2, 1)  # lanes of the three sets of a flush: lanes 0 | 1, 2 | 3
-# where the forgery sits: (lanes forged, lanes that do not decode)
+# where the forgery sits: (lanes forged, lanes that do not decode, lanes whose
+# signatures are swapped round)
 CASES = {
-    "no-forgery": ((), ()),
-    "first-lane-of-the-first-set": ((0,), ()),
-    "last-lane-of-the-last-set": ((3,), ()),
-    "two-lanes-of-one-set": ((1, 2), ()),
-    "one-lane-in-each-of-two-sets": ((0, 2), ()),
-    "a-lane-that-does-not-decode-beside-a-forged-one": ((3,), (2,)),
+    "no-forgery": ((), (), ()),
+    "first-lane-of-the-first-set": ((0,), (), ()),
+    "last-lane-of-the-last-set": ((3,), (), ()),
+    "two-lanes-of-one-set": ((1, 2), (), ()),
+    "one-lane-in-each-of-two-sets": ((0, 2), (), ()),
+    "a-lane-that-does-not-decode-beside-a-forged-one": ((3,), (2,), ()),
+    # each signature genuine, by the other lane's key on the other lane's
+    # root: the set's signatures SUM to what its keys signed, so only the
+    # lanes' independent exponents refuse it (ISSUE 42)
+    "two-genuine-signatures-swapped-inside-one-set": ((), (), (1, 2)),
+    # nothing of set 1 reaches its aggregate: the empty sum is the identity,
+    # the set's product reads True, its lanes False by their decode mask
+    "a-set-whose-every-lane-does-not-decode": ((), (1, 2), ()),
 }
 MARKER = "SET-VERDICTS "
 
 
 @functools.cache
-def lanes_of(forged=(), undecodable=(), sets=SETS):
+def lanes_of(forged=(), undecodable=(), swapped=(), sets=SETS):
     """(pubkey, root, signature) per lane of a flush of `sets`, the plain
     reference's own keys and signatures: a forged lane is a well-formed
-    signature by another secret, an undecodable one a flipped byte of x."""
+    signature by another secret, an undecodable one a flipped byte of x, a
+    swapped one carries the genuine signature of the lane before it in
+    `swapped` (the first the last's)."""
     out = []
     for i in range(sum(sets)):
         secret = R.seeded_scalar("set-verdicts", i).to_bytes(32, "big")
@@ -62,6 +72,9 @@ def lanes_of(forged=(), undecodable=(), sets=SETS):
         if i in undecodable:
             sig = sig[:95] + bytes([sig[95] ^ 1])
         out.append((R.secret_to_public_key(secret), root, sig))
+    sigs = [out[j][2] for j in swapped[-1:] + swapped[:-1]]
+    for i, sig in zip(swapped, sigs):
+        out[i] = (*out[i][:2], sig)
     return tuple(out)
 
 
@@ -121,9 +134,9 @@ def plane_main() -> None:
     record["live_programs"], record["live_set_resolved"] = list(programs), coalescer.flushes_set_resolved
 
     record["cases"] = {}
-    for name, (forged, undecodable) in CASES.items():
+    for name, where in CASES.items():
         del programs[:]
-        lanes = lanes_of(forged, undecodable)
+        lanes = lanes_of(*where)
         oks = plane.verify_packed_parsed(
             pack(lanes, set_of_lane()), plane.make_lane_rand(len(lanes)), len(lanes))
         record["cases"][name] = {"oks": oks, "programs": list(programs)}
@@ -152,14 +165,14 @@ def test_each_sets_verdict_is_the_references(plane_record, case):
     set's lanes; an honest set passes whole, a set holding a forgery is
     refused whole (None: its lanes are not judged apart), a lane that does
     not decode fails alone (False) — in ONE dispatch."""
-    forged, undecodable = CASES[case]
+    forged, undecodable, swapped = CASES[case]
     got = plane_record["cases"][case]
-    lanes, owner = lanes_of(forged, undecodable), set_of_lane()
+    lanes, owner = lanes_of(*CASES[case]), set_of_lane()
     sound = [reference_verdict(*lane) for lane in lanes]
-    assert sound == [i not in forged and i not in undecodable for i in range(len(lanes))]
+    assert sound == [i not in forged + undecodable + swapped for i in range(len(lanes))]
     by_set = lambda oks: [all(ok for ok, s in zip(oks, owner) if s == k) for k in range(len(SETS))]
     assert by_set(got["oks"]) == by_set(sound)
-    refused = {owner[i] for i in forged}
+    refused = {owner[i] for i in forged + swapped}
     assert got["oks"] == [False if i in undecodable else None if owner[i] in refused else True
                           for i in range(len(lanes))]
     assert got["programs"] == ["mesh/verify_rlc_dec"]
@@ -176,6 +189,75 @@ def test_the_prewarm_entry_compiles_what_a_live_flush_dispatches(plane_record):
     assert plane_record["live"] == [[None], [True], [True]]
     assert plane_record["live_programs"] == ["mesh/verify_rlc_dec"]
     assert plane_record["live_set_resolved"] == 1
+
+
+@pytest.mark.parametrize("devices", (1, 8))
+def test_the_programs_miller_batch_is_a_pair_a_lane_and_a_pair_a_set(devices):
+    """Traced, not run: `verify_rlc_dec` holds ONE Miller loop (the scan
+    over the loop parameter's bits with the conditional add step inside)
+    and its carries are [lanes + VERIFY_SETS, limbs] — lane i's (r_i * pk_i,
+    H(m_i)) and set s's (-G1, S_s), the signature side summed in G2 — not
+    [2, lanes, limbs] (ISSUE 42). On one device at the four lanes of the
+    cases above; on the eight-device mesh at the bucket of a lone
+    submission, ONE lane a shard: the smallest body a node compiles (its
+    prewarm's first shape), each shard pairing its own lane and its own
+    eight sums."""
+    import jax
+
+    from charon_tpu.crypto.g1g2 import G1_GEN, G2_GEN
+    from charon_tpu.ops import decompress as DEC
+    from charon_tpu.parallel import SlotCryptoPlane, make_mesh
+    from tests.test_aggregate_group_check import miller_scan_carries
+
+    plane = SlotCryptoPlane(make_mesh(jax.devices()[:devices]), t=3)
+    lanes = sum(SETS) if devices == 1 else 1
+    a_shard = plane.bucket_lanes(lanes) // devices
+    assert a_shard == (4 if devices == 1 else 1)
+    sets, *arrays = plane.pack_verify_inputs_parsed(
+        [G1_GEN] * lanes, [G2_GEN] * lanes,
+        [DEC.parse_g2_lane(lanes_of()[0][2])] * lanes, set_of_lane()[:lanes])
+    (carries,) = miller_scan_carries(jax.make_jaxpr(plane._verify_rlc_dec)(
+        *arrays, plane.make_lane_rand(lanes), sets.seg))
+    assert carries and {aval.shape[:-1] for aval in carries} == {(a_shard + plane.VERIFY_SETS,)}
+
+
+@pytest.mark.parametrize("lanes", (1, 2, 32, 128))
+def test_each_sets_sum_is_the_plain_sum_of_its_lanes(lanes):
+    """No pairing: the fold `batched_verify_rlc_sets` takes its S_s by —
+    `_point_sum_scan` at the kernel tile over the identity-masked [lanes,
+    sets] grid — against plain affine adds, set by set. 128 lanes of 8
+    sets are four slices of a tile: three steps add them into the first,
+    five fold it; 32 lanes are one slice (five folds), 1 and 2 a shard's share of a small flush (no step, one). The
+    points repeat four lanes apart inside one set, so a step adds a point
+    to ITSELF (the complete add's doubling case), every fourth lane is
+    the identity (an exponent of 0), and the last set is empty."""
+    import jax
+    import jax.numpy as jnp
+
+    from charon_tpu.crypto import g1g2 as REF
+    from charon_tpu.ops import curve as C, limb, pairing as DP
+    from charon_tpu.ops.pallas_mont import TILE
+
+    n_sets = 8  # the program's own: SlotCryptoPlane.VERIFY_SETS
+    multiples = [REF.g2_mul(REF.G2_GEN, k) for k in (5, 7, 11)]
+    points = [None if i % 4 == 3 else multiples[i % 4] for i in range(lanes)]
+    seg = [(i // 4) % (n_sets - 1) if i % 4 != 1 else 0 for i in range(lanes)]
+    g2f = C.g2_ops(limb.FP)
+
+    @jax.jit
+    def sums(aff, seg):
+        in_set = seg[:, None] == jnp.arange(n_sets, dtype=seg.dtype)[None, :]
+        grid = jax.tree_util.tree_map(
+            lambda a, o: jnp.where(in_set[..., None], a[:, None, :], o),
+            C.affine_to_point(g2f, aff), C.point_identity(g2f, (n_sets,)))
+        return C.point_to_affine(
+            g2f, DP._point_sum_scan(C, g2f, grid, lanes, TILE))
+
+    want = [None] * n_sets
+    for point, s in zip(points, seg):
+        want[s] = REF.g2_add(want[s], point)
+    assert want[-1] is None and (lanes < 32 or None not in want[:-1])
+    assert C.g2_unpack(limb.FP, sums(C.g2_pack(limb.FP, points), jnp.asarray(seg, jnp.int32))) == want
 
 
 # -- the fold and the tier behind it: the host's half, the programs stood in for --
