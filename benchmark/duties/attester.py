@@ -99,11 +99,11 @@ def beacon(scene) -> dict:
     return {"attester_duties": attester_duties, "attestation_data": attestation_data}
 
 
-def submitted(plan, att):
+def submitted(scene, att):
     """The aggregate the node's beacon got -> (slot, validator, signature,
     raw fields) of its record, or None where it is no duty of the plan."""
     slot = att.data.slot
-    members = plan.members(slot)
+    members = scene.plan.members(slot)
     if not 0 <= att.data.index < len(members):
         return None
     d = att.data

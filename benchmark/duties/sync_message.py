@@ -4,10 +4,12 @@ committee and sign, in EVERY slot, the one head block root of that slot (the
 root the slot's attesters vote for, from the seed, the same on every
 operator's beacon), the trigger at 1/3 slot beside the attester's. The whole
 wave shares one signing root. Contributions (2/3 slot, selection proofs) are
-not driven: no VC sends their selections, and the node's contribution duty
-waits out its deadline as its aggregator duty does. README.md, "Adding
-things", says what the harness asks of a kind's module. Added in PR 37 with
-no cell: rehearsed on the CPU only (tests/rehearse_sync.py)."""
+not driven: the harness's VC sends no selections yet (a duty that validator
+clients start is a kind of its own since PR 43: README.md, "Adding things"),
+and the node's contribution duty waits out its deadline as its aggregator
+duty does. README.md says what the harness asks of a kind's module. Added in
+PR 37 and rehearsed on the CPU (tests/rehearse_sync.py); since PR 39 the cell
+`dv-3of4-1k-sync.attest-sync` runs it on the chip."""
 
 from __future__ import annotations
 
@@ -67,7 +69,7 @@ def beacon(scene) -> dict:
     return {"sync_duties": sync_duties, "sync_committee_block_root": sync_committee_block_root}
 
 
-def submitted(plan, msg):
+def submitted(scene, msg):
     """The aggregate the node's beacon got -> (slot, validator, signature,
     raw fields) of its record; a validator's index is its place in the lock."""
     return (msg.slot, msg.validator_index, msg.signature,

@@ -9,6 +9,7 @@ import dataclasses
 import functools
 import importlib.util
 import json
+import pkgutil
 import re
 from pathlib import Path
 
@@ -134,6 +135,19 @@ def _load_duty(kind: str, bdir: Path):
     if not NAME.match(kind) or not path.exists():
         raise ManifestError(f"no duty kind {kind!r} at {path}")
     return _module(path, f"bench_duty_{kind}")
+
+
+def unresolved(names) -> list[str]:
+    """Of a configuration's `requires` — dotted names of what it needs of
+    the program (`package.module.Class.attribute`) — those that do not
+    resolve: the longest importable prefix, then attributes."""
+    missing = []
+    for name in names:
+        try:
+            pkgutil.resolve_name(str(name))
+        except (ImportError, AttributeError, ValueError):
+            missing.append(str(name))
+    return missing
 
 
 def validate(manifest: dict) -> list[str]:

@@ -47,16 +47,17 @@ def node_spans():
     return list(tracers[0].spans)
 
 
-def duty_spans(run, spans, duty: str):
-    """The spans of the traces of the window's duties of one kind (`duty`:
-    "attester"): a trace belongs where one of its spans names such a duty
+def duty_spans(run, spans, kinds):
+    """The spans of the traces of the window's duties of the `kinds` named
+    (["attester"]: duty types as a span's `duty` attribute spells them; a
+    mix's kinds): a trace belongs where one of its spans names such a duty
     of one of the window's slots. The node runs other duties too, and
     their spans say nothing of a wave though they may be open all through
     it: an aggregator duty's fetch waits a slot and more for selection
     proofs no VC sends, and a duty from before the window that never
     reached a decision holds its consensus spans open until its deadline
     cancels them, slots later."""
-    mine = {f"{slot}/{duty}" for slot in run.slots}
+    mine = {f"{slot}/{kind}" for slot in run.slots for kind in kinds}
     traces = {s.trace_id for s in spans if s.attrs.get("duty") in mine}
     return [s for s in spans if s.trace_id in traces]
 
@@ -111,24 +112,28 @@ def wave_self_seconds(run, spans, names) -> list[float]:
     return values
 
 
-def cause_segments(spans, dues, slot_duration, a: float, b: float):
+def cause_segments(spans, dues, starts, a: float, b: float):
     """[a, b) cut at every span boundary and trigger, each piece with its
     ONE cause: the cause nearest the device among the innermost open
-    spans; with no span open, `pre_trigger` between a slot's start and
-    its trigger and `awaiting_input` after it."""
+    spans, of whatever kind of duty (none is assumed to be there: a duty
+    that validator clients start has no fetch and no consensus span); with
+    no span open, `pre_trigger` between a slot's start and its first
+    trigger and `awaiting_input` after it. `dues` are the slots' first
+    triggers, `starts` the same slots' starts."""
     live = [s for s in spans if s.end > a and s.start < b]
+    before_due = list(zip(starts, dues))
     points = {a, b}
     for s in live:
         points.update(t for t in (s.start, s.end) if a < t < b)
-    for due in dues:
-        points.update(t for t in (due - slot_duration / 3, due) if a < t < b)
+    for pair in before_due:
+        points.update(t for t in pair if a < t < b)
     points = sorted(points)
     out = []
     for lo, hi in zip(points, points[1:]):
         mid = (lo + hi) / 2
         open_ = [s for s in live if s.start <= mid < s.end]
         if not open_:
-            before = any(due - slot_duration / 3 <= mid < due for due in dues)
+            before = any(start <= mid < due for start, due in before_due)
             out.append((lo, hi, NO_SPAN[0] if before else NO_SPAN[1]))
             continue
         parents = {(s.trace_id, s.parent_id) for s in open_}
@@ -138,28 +143,32 @@ def cause_segments(spans, dues, slot_duration, a: float, b: float):
     return out
 
 
-_last: tuple = (None, None)  # ((run, duty), seconds by cause)
+_last: tuple = (None, None)  # (run, seconds by cause)
 
 
-def idle_seconds(run, duty: str) -> dict | None:
+def idle_seconds(run) -> dict | None:
     """Device 0's idle seconds in the traced window, by cause: every idle
     instant gets exactly one, so the values sum to `window_s - busy_s`.
+    Read from the window's duties of EVERY kind the run's mix names
+    (`run.duty_types`).
     Worked out once a run (and noted on stderr, with the causes that are
     no metric): the five `idle_s.*` metrics read one answer."""
     global _last
-    if _last[0] == (id(run), duty):
+    if _last[0] == id(run):
         return _last[1]
     recorded, trace = node_spans(), run.trace
     if recorded is None or trace is None:
         return None
-    spans = duty_spans(run, recorded, duty)
+    spans = duty_spans(run, recorded, run.duty_types)
     t0 = trace.wall_start
     t1 = t0 + trace.window_s
     kept = {id(s) for s in spans}
     others = [s for s in recorded if id(s) not in kept and s.end > t0 and s.start < t1]
     longest = max(others, key=lambda s: min(s.end, t1) - max(s.start, t0), default=None)
-    segments = cause_segments(spans, [w["due"] for w in run.waves() if w["duties"]],
-                              run.slot_duration, t0, t1)
+    waves = [w for w in run.waves() if w["duties"]]
+    segments = cause_segments(
+        spans, [w["due"] for w in waves],
+        [run.window[0] + (w["slot"] - run.slots[0]) * run.slot_duration for w in waves], t0, t1)
     total = dict.fromkeys(ORDER + NO_SPAN, 0.0)
     i = 0
     for a, b in sorted((t0 + a, t0 + b) for a, b in trace.idle_gaps()):
@@ -171,7 +180,7 @@ def idle_seconds(run, duty: str) -> dict | None:
             lo, hi, cause = segments[j]
             total[cause] += min(b, hi) - max(a, lo)
             j += 1
-    print(f"node spans: {len(recorded)} in the ring, {len(spans)} of the window's {duty} "
+    print(f"node spans: {len(recorded)} in the ring, {len(spans)} of the window's {'+'.join(run.duty_types)} "
           f"duties, {len(others)} of other duties open in the traced window"
           + (f" (longest {longest.name} of {longest.attrs.get('duty')}, "
              f"{longest.end - longest.start:.3f} s {longest.status})" if longest else "")
@@ -179,5 +188,5 @@ def idle_seconds(run, duty: str) -> dict | None:
           + ", ".join(f"{c} {v:.6f}" for c, v in total.items())
           + f"; sum {sum(total.values()):.6f} of window - busy "
           f"{trace.window_s - trace.busy_s:.6f}", file=sys.stderr, flush=True)
-    _last = ((id(run), duty), total)
+    _last = (id(run), total)
     return total

@@ -15,5 +15,5 @@ def read(run, spans: list, duty: str):
     if recorded is None:
         return None
     values = nodespans.wave_self_seconds(
-        run, nodespans.duty_spans(run, recorded, duty), set(spans))
+        run, nodespans.duty_spans(run, recorded, [duty]), set(spans))
     return statistics.median(values) if values else None

@@ -18,6 +18,7 @@ import asyncio
 import concurrent.futures
 import dataclasses
 import gc
+import importlib.util
 import json
 import os
 import sys
@@ -411,6 +412,15 @@ def main(argv=None, root: Path = ROOT, exit_fn=os._exit,
     if rehearsal.cpu:
         os.environ["JAX_PLATFORMS"] = "cpu"
     cache, named = cachedir.configure(root)
+    # what the configuration says it needs of the program, before anything
+    # boots or compiles (a directory without the program says so below)
+    requires = cell.config.get("requires", ())
+    if requires and importlib.util.find_spec("charon_tpu") is not None:
+        missing = manifestlib.unresolved(requires)
+        if missing:
+            wd.fail(f"before boot: the configuration requires {missing} of the program, "
+                    f"which this tree does not have")
+            return 2
     with wd.phase("import jax", 60):
         import jax
 

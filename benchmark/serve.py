@@ -8,9 +8,12 @@ duty is done when the node's beacon holds the broadcast aggregate. Every
 duty is timed from the instant its trigger was DUE on the slot clock.
 
 What belongs to ONE kind of duty — who holds it when, what the beacon
-answers, what is signed and submitted where — is the kind's module
-(duties/<kind>.py, found by the name in the mix); what is here serves
-whatever kinds the plan carries."""
+answers, what is signed and submitted where, and who STARTS it — is the
+kind's module (duties/<kind>.py, found by the name in the mix); what is here
+serves whatever kinds the plan carries. A kind the cluster decides is driven
+by the node's scheduler (its VC) and each peer's QBFT decision; a kind that
+validator clients start (`STARTS = "vc"`) by the slot clock, `started_rounds`:
+README.md, "Adding things"."""
 
 from __future__ import annotations
 
@@ -50,6 +53,8 @@ class RunData:
     window: tuple[float, float] = (0.0, 0.0)
     slot_duration: float = 12.0
     slots: list[int] = dataclasses.field(default_factory=list)
+    # the mix's kinds as a node span's `duty` spells them ("7/attester")
+    duty_types: tuple[str, ...] = ()
     duties: list[DutyRecord] = dataclasses.field(default_factory=list)
     flushes: list = dataclasses.field(default_factory=list)  # (done, FlushStats)
     programs: list = dataclasses.field(default_factory=list)  # (family, s, lanes, end)
@@ -86,11 +91,17 @@ def free_ports(n: int) -> list[int]:
 
 class Gate:
     """Slots the window serves: [first, first + count). Peers and the VC
-    stay silent outside it (the node boots, warms and loads first)."""
+    stay silent outside it (the node boots, warms and loads first).
+    `opened` is set once the window's slots are known."""
 
     def __init__(self) -> None:
         self.first: int | None = None
         self.count = 0
+        self.opened = asyncio.Event()
+
+    def serve(self, first: int, count: int) -> None:
+        self.first, self.count = first, count
+        self.opened.set()
 
     def open(self, slot: int) -> bool:
         return self.first is not None and self.first <= slot < self.first + self.count
@@ -121,6 +132,7 @@ class Scene:
     plan: Plan
     cluster: object
     memo: SlotMemo = dataclasses.field(default_factory=SlotMemo)
+    genesis: float = 0.0  # the run's slot clock: slot s starts at genesis + s * slot_duration
 
     @functools.cached_property
     def fork(self):
@@ -134,6 +146,40 @@ def kinds_by_type(plan: Plan) -> dict:
     from charon_tpu.core.types import DutyType
 
     return {DutyType[kind.DUTY_TYPE]: kind for kind in plan.kinds}
+
+
+def vc_started(kind) -> bool:
+    """Who starts a duty of this kind: absent or "decided", the node's
+    scheduler triggers the VC's round and a peer sends when its QBFT
+    decides; "vc", the duty exists because validator clients send it — no
+    scheduler emits it and no consensus runs on it."""
+    starts = getattr(kind, "STARTS", "decided")
+    if starts not in ("decided", "vc"):
+        raise ValueError(f"duty kind {kind.NAME}: STARTS {starts!r}")
+    return starts == "vc"
+
+
+def started_rounds(scene: Scene, gate: Gate, fire) -> list:
+    """One task a kind that validator clients start: once the window is
+    known, `fire(kind, slot)` as a task of its own at each of its slots in
+    which the kind has members, the instant the kind's request is DUE on
+    the slot clock (slot start + OFFSET x slot duration)."""
+    plan = scene.plan
+
+    async def rounds(kind):
+        await gate.opened.wait()
+        fired = set()
+        for slot in range(gate.first, gate.first + gate.count):
+            if not kind.members(plan, slot):
+                continue
+            due = scene.genesis + (slot + kind.OFFSET) * plan.slot_duration
+            await asyncio.sleep(max(0.0, due - time.time()))
+            task = asyncio.create_task(fire(kind, slot))
+            fired.add(task)
+            task.add_done_callback(fired.discard)
+        await asyncio.gather(*fired)
+
+    return [asyncio.create_task(rounds(kind)) for kind in plan.kinds if vc_started(kind)]
 
 
 # the BeaconMock's own schedules (every validator, every slot): none of them
@@ -167,8 +213,10 @@ class HostPeer:
     participant, scheduler and fetcher (so the cluster decides every
     duty), and instead of a VC + ValidatorAPI + SigAgg the harness's signer
     that sends this operator's partials through ParSigEx once the duty is
-    decided (after the plan's jitter). It verifies and aggregates nothing:
-    tbls is process-global and belongs to the chip-backed node."""
+    decided (after the plan's jitter) — or, for a kind that validator
+    clients start, once its request is due on the slot clock, with no
+    decision behind it. It verifies and aggregates nothing: tbls is
+    process-global and belongs to the chip-backed node."""
 
     def __init__(self, scene: Scene, index, ports, genesis, gate, spans):
         self.scene, self.plan, self.cluster, self.index = scene, scene.plan, scene.cluster, index
@@ -217,16 +265,26 @@ class HostPeer:
         self.scheduler.subscribe_duties(self._fetch)
         self.qbft.subscribe(self._decided)
         self._task = asyncio.create_task(self.scheduler.run())
+        self._rounds = started_rounds(self.scene, self.gate, self._started)
 
     async def _fetch(self, duty, defs) -> None:
         if duty.type in self.kinds:
             await self._fetcher.fetch(duty, defs)
 
     async def _decided(self, duty, unsigned_set) -> None:
-        share_idx = self.index + 1
-        if duty.type not in self.kinds or not self.gate.open(duty.slot):
+        kind = self.kinds.get(duty.type)
+        if kind is None or vc_started(kind) or not self.gate.open(duty.slot):
             return
         self.spans.append(("qbft_decided", time.time(), time.time()))
+        self._speak(duty, unsigned_set)
+
+    async def _started(self, kind, slot: int) -> None:
+        """This operator's VC has sent its request: the same set every
+        operator's VC signs in that slot, with no decision behind it."""
+        self._speak(kind.duty(self.plan, slot), kind.unsigned(self.scene, slot))
+
+    def _speak(self, duty, unsigned_set) -> None:
+        share_idx = self.index + 1
         if share_idx in self.plan.silent:
             return
         task = asyncio.create_task(self._send(duty, unsigned_set, share_idx))
@@ -237,6 +295,9 @@ class HostPeer:
         from charon_tpu.core.eth2data import ParSignedData
 
         plan = self.plan
+        # jitter and fault are drawn for the slot of SENDING: duty.slot for
+        # every kind there is (a kind whose duties travel under another slot
+        # than the one they are sent in has to say both)
         await asyncio.sleep(plan.jitter(share_idx, duty.slot))
         forge = plan.forged(duty.slot, share_idx, self.gate.last, self.kinds[duty.type].NAME)
         # on a thread: this operator is another machine, and its
@@ -263,7 +324,7 @@ class HostPeer:
     async def stop(self) -> None:
         self.scheduler.stop()
         self._task.cancel()
-        for t in list(self._sends):
+        for t in [*self._rounds, *self._sends]:
             t.cancel()
         await self.p2p.stop()
 
@@ -280,7 +341,8 @@ class Server:
         self.cache_log, self.events = cache_log, events
         self.allowed = allowed  # {"family@bucket"}
         self.require_plane = require_plane
-        self.run = RunData(slot_duration=plan.slot_duration)
+        self.run = RunData(slot_duration=plan.slot_duration,
+                           duty_types=tuple(kind.DUTY_TYPE.lower() for kind in plan.kinds))
         self.gate = Gate()
         self.in_window = False
         self.warm_stats: list[dict] = []
@@ -294,6 +356,7 @@ class Server:
         self.life = None
         self.stop = asyncio.Event()
         self.peers: list[HostPeer] = []
+        self._rounds: list = []
 
     # -- phase: cluster -----------------------------------------------------
 
@@ -315,7 +378,7 @@ class Server:
         plan, cfg = self.plan, self.cell.config
         self.ports = free_ports(plan.operators)
         self.genesis = time.time()
-        self.scene = Scene(plan, self.cluster)
+        self.scene = Scene(plan, self.cluster, genesis=self.genesis)
         self.kinds = kinds_by_type(plan)
         self.beacon = make_beacon(self.scene, self.genesis)
         self._hook_beacon()
@@ -337,6 +400,7 @@ class Server:
         if self.coalescer is None and self.require_plane:
             raise RuntimeError("build_node installed no crypto plane")
         self.node.scheduler.subscribe_duties(self._vc_on_duty)
+        self._rounds = started_rounds(self.scene, self.gate, self._vc_started)
 
     def record(self, kind: str, slot: int, vidx: int) -> DutyRecord | None:
         return self._records.get((kind, slot, vidx))
@@ -352,7 +416,7 @@ class Server:
     def _stamped(self, kind, inner):
         async def submit(*args):
             now = time.time()
-            found = kind.submitted(self.plan, *args)
+            found = kind.submitted(self.scene, *args)
             if found is not None:
                 slot, vidx, signature, data = found
                 rec = self.record(kind.NAME, slot, vidx)
@@ -368,9 +432,20 @@ class Server:
         """This node's validator client: HTTP against the ValidatorAPI,
         each kind's own round."""
         kind = self.kinds.get(duty.type)
-        if kind is None or not self.gate.open(duty.slot):
+        if kind is None or vc_started(kind) or not self.gate.open(duty.slot):
             return
         self.run.spans += await kind.vc_round(self, duty, defs)
+
+    async def _vc_started(self, kind, slot: int) -> None:
+        """The same client, for a kind no scheduler emits: its round at the
+        instant its request is due, `defs` the objects it signs. A round
+        the node refuses is noted, and its duties are then missing."""
+        try:
+            self.run.spans += await kind.vc_round(
+                self, kind.duty(self.plan, slot), kind.unsigned(self.scene, slot))
+        except Exception as e:  # noqa: BLE001 — the VC is another process: the run goes on
+            self.wd.note(f"the VC's {kind.NAME} round of slot {slot} failed: "
+                         f"{type(e).__name__}: {e}")
 
     # -- phase: programs ----------------------------------------------------
 
@@ -516,7 +591,7 @@ class Server:
         the `slots` slots from it; returns the boundary (wall clock)."""
         clock = self.beacon.clock()
         first = clock.slot_at(time.time() + lead) + 1
-        self.gate.first, self.gate.count = first, slots
+        self.gate.serve(first, slots)
         start = clock.slot_start(first)
         run, plan = self.run, self.plan
         run.slots = list(range(first, first + slots))
@@ -535,7 +610,7 @@ class Server:
             1 for slot in self.run.slots for kind in self.plan.kinds
             for idx in range(2, self.plan.operators + 1)
             if self.plan.forged(slot, idx, self.gate.last, kind.NAME)
-            and idx not in self.plan.silent
+            and idx not in self.plan.silent and kind.members(self.plan, slot)
         )
 
     # -- phase: teardown ----------------------------------------------------
@@ -546,6 +621,8 @@ class Server:
         line)."""
         late = []
         self.stop.set()
+        for t in self._rounds:
+            t.cancel()
 
         async def bounded(name, coro, seconds):
             try:

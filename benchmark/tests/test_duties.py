@@ -98,7 +98,8 @@ def test_the_generic_files_name_no_kind_of_duty(name):
     text = (REPO / "benchmark" / name).read_text()
     for token in ("DutyType.ATTESTER", "ATTESTER_OFFSET", "attestation_fields",
                   "attestation_signing_root", "sign_attestations", "submit_attestation",
-                  "sync_message", "SYNC_MESSAGE"):
+                  "sync_message", "SYNC_MESSAGE", "registration", "REGISTRATION",
+                  "submit_registration", "register_validators"):
         assert token not in text, token
 
 
@@ -184,7 +185,7 @@ def test_the_sync_committee_is_the_head_of_the_seeded_order_in_every_slot(seed):
     assert sync.block_root(plan, 37) != sync.block_root(plan, 38)
     msg = types.SimpleNamespace(slot=37, beacon_block_root=b"r" * 32, validator_index=9,
                                 signature=b"s" * 96)
-    assert sync.submitted(plan, msg) == (37, 9, b"s" * 96, (37, b"r" * 32, 9))
+    assert sync.submitted(types.SimpleNamespace(plan=plan), msg) == (37, 9, b"s" * 96, (37, b"r" * 32, 9))
 
 
 @pytest.mark.parametrize("seed", [1, 3700000011, 2**31 + 12345])
@@ -365,7 +366,8 @@ def test_a_retired_metric_left_the_manifest_and_took_its_file(name):
 def test_the_forged_cell_reports_the_verify_program_like_the_other_three():
     man = M.load_manifest(REPO)
     assert M.validate(man) == []
-    assert len(man["per_layer"]) == 20 and len(man["workloads"]) == 4
+    # the manifest's size is no longer pinned here (20 metrics and 4 cells when
+    # PR 37 wrote this; later PRs add cells and metrics): the cell's own are
     names = [m.name for m in M.load_cell(REPO, FORGED_CELL, man).per_layer]
     assert len(names) == 18 and names[-1] == "sets_invalid_per_wave"
     assert {"program_s.verify", "device_busy_s.verify"} <= set(names)

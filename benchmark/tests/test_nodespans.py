@@ -11,6 +11,7 @@ node's.
 from __future__ import annotations
 
 import sys
+import types
 from pathlib import Path
 
 import pytest
@@ -23,6 +24,7 @@ from benchmark.tests import helpers  # noqa: E402
 # the duty kinds' tests: tier-1 collects tests/ alone, and this file's tests
 # come in there through tests/test_node_spans.py's `import *` — so do these
 from benchmark.tests.test_duties import *  # noqa: E402,F401,F403
+from benchmark.tests.test_starts import *  # noqa: E402,F401,F403
 from charon_tpu.app import tracer  # noqa: E402
 
 TRACE = "c" * 32
@@ -75,6 +77,7 @@ class Run:
     slot_duration = SLOT
     window = (T0, T0 + SLOT)
     slots = [7]
+    duty_types = ("attester",)  # as serve.RunData says its mix's kinds
     trace = None
 
     def in_window(self, ts):
@@ -128,9 +131,9 @@ def test_the_span_metrics_on_the_synthetic_wave(node):
 
 
 def test_an_idle_instant_gets_the_cause_nearest_the_device(node):
-    spans = nodespans.duty_spans(Run(), list(node.spans), "attester")
+    spans = nodespans.duty_spans(Run(), list(node.spans), ["attester"])
     assert all(s.trace_id == TRACE for s in spans)  # the parked fetch is another duty's
-    seg = nodespans.cause_segments(spans, [T0 + 4.0], SLOT, T0, T0 + 7.0)
+    seg = nodespans.cause_segments(spans, [T0 + 4.0], [T0], T0, T0 + 7.0)
     assert seg[0][0] == T0 and seg[-1][1] == T0 + 7.0
     assert all(a[1] == b[0] for a, b in zip(seg, seg[1:]))  # a partition
 
@@ -154,7 +157,7 @@ def test_the_idle_causes_sum_to_window_minus_busy(node):
     run = Run()
     run.trace = tracered.reduce_file(
         str(helpers.RECORDED), T0 + 4.1, helpers.RECORDED_WINDOW_S)
-    total = nodespans.idle_seconds(run, "attester")
+    total = nodespans.idle_seconds(run)
     assert set(total) == set(nodespans.ORDER + nodespans.NO_SPAN)
     assert sum(total.values()) == pytest.approx(run.trace.window_s - run.trace.busy_s, abs=1e-9)
     # the recorded 0.7-0.9 s from 4.1: consensus to 4.2, other to 4.25, waiting
@@ -276,3 +279,103 @@ def test_the_new_metrics_are_files_and_entries_like_the_old_ones():
         assert m["moves"] == "duty_p50_s" and m["workloads"] == cells
         assert M._metric(M.bench_dir(REPO, man), m).reader in (
             "span_self", "span_duration", "idle_cause")
+
+
+# -- a mix of several kinds (ISSUE 43): any duty's innermost span, (slot, kind) waves --
+
+
+def _two_kinds():
+    """The synthetic attester wave and, in the same slot, a sync-message
+    duty whose verify flush holds a window open from 4.25 to 4.38 — where
+    the attester duty has nothing open — and a duty that validator clients
+    start: no fetch, no consensus, a submission at the slot's start."""
+    sync = {"duty": "7/sync_message", "slot": 7}
+    reg = {"duty": "7/builder_registration", "slot": 7}
+    return wave() + [
+        span("vapi.submit", 4.25, 4.4, "sv", trace="e" * 32, **sync),
+        span("cryptoplane.window", 4.25, 4.38, "sw", "sv", trace="e" * 32),
+        span("vapi.submit", 0.0, 0.3, "bv", trace="b" * 32, **reg),
+        span("cryptosvc.queue", 0.1, 0.2, "bq", "bv", trace="b" * 32),
+    ]
+
+
+def test_a_one_kind_run_reads_the_idle_causes_it_read_before(node):
+    """On tests/data/tiny.xplane.pb, the seconds the parent's reader gave
+    this run when the metric files named the kind (`"duty": "attester"`):
+    they name none any more, and the run says its own."""
+    run = Run()
+    run.trace = tracered.reduce_file(
+        str(helpers.RECORDED), T0 + 4.1, helpers.RECORDED_WINDOW_S)
+    total = nodespans.idle_seconds(run)
+    w = helpers.RECORDED_WINDOW_S
+    assert {c: round(v, 3) for c, v in total.items() if v} == {
+        "consensus": 0.1, "other": 0.05, "awaiting_input": 0.15,
+        "entry": round(total["entry"], 3), "window": round(0.13 + min(w, 0.8) - 0.7, 3),
+        "pack": round(0.25 + max(0.0, w - 0.8), 3)}
+    assert read("idle_s.window", run) == total["window"]
+    man = M.load_manifest(REPO)
+    for entry in man["per_layer"]:
+        if entry["name"].startswith("idle_s."):
+            metric = M._metric(M.bench_dir(REPO, man), entry)
+            assert metric.params == {"cause": entry["name"].split(".", 1)[1]}
+
+
+def test_an_idle_instant_takes_the_innermost_span_of_any_of_the_windows_duties():
+    spans = _two_kinds()
+    run = Run()
+    one = nodespans.duty_spans(run, spans, ["attester"])
+    both = nodespans.duty_spans(run, spans, ("attester", "sync_message"))
+    assert len(both) == len(one) + 2
+    starts = [T0]
+
+    def cause(mine, at):
+        seg = nodespans.cause_segments(mine, [T0 + 4.0], starts, T0, T0 + 7.0)
+        return next(c for lo, hi, c in seg if lo <= T0 + at < hi)
+
+    # decided at 4.25, the VC back at 4.4: the attester duty has nothing open,
+    # the sync duty's window is — the two-kind cell's view until PR 43 said
+    # `awaiting_input` there
+    assert cause(one, 4.3) == "awaiting_input" and cause(both, 4.3) == "window"
+    assert cause(both, 4.39) == "entry"  # the sync submission, its window closed
+    for at in (2.0, 4.1, 4.5, 4.75, 6.8):  # elsewhere the nearer cause wins as before
+        assert cause(both, at) == cause(one, at)
+    # a kind that validator clients start: no fetch, no consensus span is
+    # assumed; its request is due at the slot's start, so nothing of the slot
+    # is `pre_trigger`
+    every = nodespans.duty_spans(run, spans, ("attester", "builder_registration"))
+    seg = nodespans.cause_segments(every, [T0], starts, T0, T0 + 7.0)
+    at = lambda t: next(c for lo, hi, c in seg if lo <= T0 + t < hi)  # noqa: E731
+    assert (at(0.05), at(0.15), at(0.25), at(2.0)) == ("entry", "window", "entry",
+                                                       "awaiting_input")
+    assert at(4.1) == "consensus"  # the attester's, as ever
+
+
+def test_flushes_per_wave_counts_a_wave_a_slot_and_kind():
+    from benchmark.serve import DutyRecord, RunData
+
+    man = M.load_manifest(REPO)
+    read_flushes = M.load_reader(REPO, man, "flushes_per_wave")
+    run = RunData(window=(1000.0, 1036.0), slots=[7, 8, 9])
+    stat = types.SimpleNamespace()
+    run.flushes = [(1004.0 + 12 * k + i, stat) for k in range(3) for i in (0.5, 1.5)]
+    run.flushes.append((990.0, stat))  # before the window
+    assert read_flushes(run) is None  # no duty, no wave: nothing to read
+    for slot in run.slots:
+        run.duties += [DutyRecord("attester", slot, v, "0x", 1000.0) for v in range(3)]
+    assert read_flushes(run) == 2.0  # one kind: (slot, kind) waves ARE the slots
+    # a second kind every slot: four flushes a slot read 2, where they read 4
+    run.flushes += [(1004.0 + 12 * k + i, stat) for k in range(3) for i in (0.7, 1.7)]
+    for slot in run.slots:
+        run.duties += [DutyRecord("sync_message", slot, v, "0x", 1000.0) for v in range(5)]
+    assert read_flushes(run) == 2.0
+    # a kind with a duty in ONE slot of the three: seven waves, fourteen flushes
+    run.duties.append(DutyRecord("registration", 8, 0, "0x", 1000.0))
+    run.flushes += [(1012.2, stat), (1013.1, stat)]
+    assert read_flushes(run) == 2.0
+    run.flushes.append((1013.5, stat))  # a wave that split
+    assert read_flushes(run) == pytest.approx(15 / 7)
+    assert read_flushes(RunData(window=(1000.0, 1036.0), slots=[7])) is None
+    # the one-kind cells list the metric; the two-kind cell's tier-1 test
+    # (tests/test_two_kinds.py) still holds it out: PERF.md §7
+    entry = next(m for m in man["per_layer"] if m["name"] == "flushes_per_wave")
+    assert len(entry["workloads"]) == 4
