@@ -53,6 +53,16 @@ class ClusterMetrics:
         self.bcast_total = counter(
             "core_bcast_broadcast_total", "Broadcast duties", ["duty"]
         )
+        self.vapi_registrations = counter(
+            "core_validatorapi_registrations_total",
+            "Builder registrations the validator API took in through "
+            "register_validator, by what became of their request: "
+            "accepted (verified and stored under the slot of their "
+            "timestamp), rejected (a partial failed its pubshare check "
+            "or names no validator: the request is refused whole), "
+            "pre_genesis (a timestamp before genesis names no slot)",
+            ["result"],
+        )
         self.tracker_failed = counter(
             "core_tracker_failed_duties_total", "Failed duties", ["duty", "step"]
         )

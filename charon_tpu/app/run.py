@@ -934,6 +934,7 @@ async def build_node(config: Config) -> Node:
         plane=tenant_plane,
         tracer=node_tracer,
         roster=roster,
+        clock=clock,
     )
     verifier = Eth2Verifier(
         fork,
@@ -1158,6 +1159,9 @@ async def build_node(config: Config) -> Node:
         slot_duration=config.slot_duration,
         clock=clock,
     )
+    vapi_router.on_registrations = lambda result, count: metrics.labels(
+        metrics.vapi_registrations, result
+    ).inc(count)
     if config.beacon_urls:
         # unmatched VC requests forward to the first beacon endpoint
         # (ref: router.go proxyHandler)

@@ -96,8 +96,9 @@ class Broadcaster:
                 await self._submit(
                     duty, self.beacon.submit_registration, signed.payload, signed.signature
                 )
-                # merge per pubkey — separate submissions share the duty
-                # key (slot 0), and the recaster needs all of them
+                # merge per pubkey — a VC's requests of one slot share
+                # the duty key (the slot of their timestamps), and the
+                # recaster needs all of them
                 merged = dict(self._registrations.get(duty, {}))
                 merged.update(data_set)
                 self._registrations[duty] = merged

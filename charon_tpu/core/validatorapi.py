@@ -33,6 +33,10 @@ class VapiError(Exception):
     pass
 
 
+class PreGenesisError(VapiError):
+    """A registration whose timestamp lies before genesis."""
+
+
 @dataclass
 class ValidatorAPI:
     """share_idx: this node's 1-based share index; pubshares maps group
@@ -53,6 +57,10 @@ class ValidatorAPI:
     # that the coalescing window closes when the wave is whole; None =
     # no hint (core/cryptoplane)
     roster: object | None = None
+    # core/deadline.SlotClock: a builder registration's duty is the slot
+    # of its timestamp; None = the router's (core/vapi_http hands a
+    # clockless ValidatorAPI its own)
+    clock: object | None = None
 
     def __post_init__(self) -> None:
         self._subs: list = []
@@ -235,10 +243,49 @@ class ValidatorAPI:
         duty = Duty(slot, DutyType.EXIT)
         await self._submit(duty, [(duty, pubkey, signed)])
 
-    async def submit_registration(self, pubkey: PubKey, reg, signature: bytes, slot: int = 0) -> None:
-        signed = SignedData("registration", reg, signature)
-        duty = Duty(slot, DutyType.BUILDER_REGISTRATION)
-        await self._submit(duty, [(duty, pubkey, signed)])
+    def registration_slot(self, reg) -> int:
+        """The slot a builder registration's duty lies under: the slot
+        of its timestamp (ref: validatorapi.go slotFromTimestamp), the
+        one every operator's VC and so every peer's partial names. A
+        timestamp before genesis names no slot — upstream fails the
+        request ("registration timestamp before genesis"); filed under
+        slot 0 it would meet no peer's partial and outlive no deadline."""
+        if self.clock is None:
+            raise VapiError("a registration's slot needs the slot clock")
+        if reg.timestamp < self.clock.genesis_time:
+            raise PreGenesisError("registration timestamp before genesis")
+        return self.clock.slot_at(reg.timestamp)
+
+    async def submit_registrations(self, items, slot: int | None = None) -> None:
+        """POST /eth/v1/validator/register_validator analogue (ref:
+        validatorapi.go SubmitValidatorRegistrations): `items` are the
+        (pubkey, ValidatorRegistration, signature) of ONE request and,
+        as a request's attestations or sync messages, ONE set a duty
+        slot — one pubshare batch, one verify job under the wave key the
+        peers' sets carry, one `vapi.submit` span whose `count` is the
+        request's size; one bad partial refuses the request whole.
+        `slot`: None = each registration's own (`registration_slot`)."""
+        items = list(items)
+        if not items:
+            return
+        entries = [
+            (
+                Duty(
+                    self.registration_slot(reg) if slot is None else slot,
+                    DutyType.BUILDER_REGISTRATION,
+                ),
+                pubkey,
+                SignedData("registration", reg, signature),
+            )
+            for pubkey, reg, signature in items
+        ]
+        await self._submit(entries[0][0], entries)
+
+    async def submit_registration(
+        self, pubkey: PubKey, reg, signature: bytes, slot: int | None = None
+    ) -> None:
+        """A request of one registration."""
+        await self.submit_registrations([(pubkey, reg, signature)], slot=slot)
 
     # -- helpers -----------------------------------------------------------
 

@@ -47,7 +47,7 @@ from charon_tpu.core.eth2data import (
     signed_proposal_from_ssz,
 )
 from charon_tpu.core.types import Duty, DutyType, PubKey
-from charon_tpu.core.validatorapi import ValidatorAPI, VapiError
+from charon_tpu.core.validatorapi import PreGenesisError, ValidatorAPI, VapiError
 from charon_tpu.eth2util import spec
 
 # ---------------------------------------------------------------------------
@@ -148,6 +148,14 @@ class VapiRouter:
         self.slots_per_epoch = slots_per_epoch
         self.slot_duration = slot_duration
         self.clock = clock or SlotClock(genesis_time, max(slot_duration, 1e-9))
+        if getattr(vapi, "clock", None) is None:  # a registration's slot is its timestamp's
+            vapi.clock = self.clock
+        # builder registrations taken in through register_validator, by
+        # what became of their request: counted per registration, a
+        # request is accepted or refused whole; `on_registrations(result,
+        # count)` feeds core_validatorapi_registrations_total (app/run)
+        self.registrations_taken = {"accepted": 0, "rejected": 0, "pre_genesis": 0}
+        self.on_registrations = None
         # pubshare (this node's) -> group pubkey, for VC keystore lookups
         # (ref: validatorapi.go:1080,1167 pubshare<->group mapping)
         self._group_by_pubshare = {
@@ -668,6 +676,12 @@ class VapiRouter:
     # -- registrations / exits ---------------------------------------------
 
     async def _register_validator(self, request: web.Request) -> web.Response:
+        """ref: router.go register_validator ->
+        validatorapi.go SubmitValidatorRegistrations: the VC's whole
+        batch is ONE submission (one set a duty slot, each registration
+        under the slot of its timestamp). A timestamp before genesis
+        fails the request with 400 and files nothing (upstream answers
+        "registration timestamp before genesis")."""
         try:
             body = await request.json()
             items = []
@@ -683,12 +697,25 @@ class VapiRouter:
         except (json.JSONDecodeError, KeyError, ValueError, TypeError) as e:
             return _err(400, f"malformed registration: {e}")
         try:
-            for reg, sig in items:
-                pubkey = self._resolve_pubkey("0x" + reg.pubkey.hex())
-                await self.vapi.submit_registration(pubkey, reg, sig)
+            await self.vapi.submit_registrations(
+                [
+                    (self._resolve_pubkey("0x" + reg.pubkey.hex()), reg, sig)
+                    for reg, sig in items
+                ]
+            )
         except VapiError as e:
+            self._registrations_taken(
+                "pre_genesis" if isinstance(e, PreGenesisError) else "rejected",
+                len(items),
+            )
             return _err(400, str(e))
+        self._registrations_taken("accepted", len(items))
         return web.Response(status=200)
+
+    def _registrations_taken(self, result: str, count: int) -> None:
+        self.registrations_taken[result] += count
+        if self.on_registrations is not None and count:
+            self.on_registrations(result, count)
 
     async def _voluntary_exit(self, request: web.Request) -> web.Response:
         try:

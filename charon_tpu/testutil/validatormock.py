@@ -185,10 +185,14 @@ class HttpValidatorMock:
         from charon_tpu.core.eth2data import ValidatorRegistration
         from charon_tpu.core.types import pubkey_to_bytes
 
+        # a registration's duty is the slot of its timestamp, and one
+        # before genesis names none (400): the first whole second after
+        # the genesis every node reports, so every node's VC signs the
+        # same message
         reg = ValidatorRegistration(
             fee_recipient=fee_recipient,
             gas_limit=30_000_000,
-            timestamp=0,
+            timestamp=await self.client.genesis_time() + 1,
             pubkey=pubkey_to_bytes(pubkey),
         )
         sig = self._sign(pubkey, "registration", reg, 0)

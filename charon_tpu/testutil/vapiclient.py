@@ -278,6 +278,10 @@ class HttpVapiClient:
         j = await self._get(f"/eth/v1/validator/duties/proposer/{epoch}")
         return j["data"]
 
+    async def genesis_time(self) -> int:
+        j = await self._get("/eth/v1/beacon/genesis")
+        return int(j["data"]["genesis_time"])
+
     async def node_version(self) -> str:
         j = await self._get("/eth/v1/node/version")
         return j["data"]["version"]

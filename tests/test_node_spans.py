@@ -53,6 +53,67 @@ def test_the_forged_cell_reports_the_verify_program_like_the_other_three():  # n
     assert len(_json.dumps(man)) < 64 * 1024
 
 
+# -- PR 44: what benchmark/tests/test_starts.py pinned of the PARENT program ------
+#
+# Three tests of that file (brought in by the `import *` above, frozen with
+# the benchmark) assert what the parent lacked: `ValidatorAPI.
+# submit_registrations` does not resolve, no configuration states `requires`,
+# and the unpatched rehearsal at bare quorum loses every registration. PR 44
+# brings the method, the sixth configuration that requires it, and the path
+# that files a registration under the slot of its timestamp. The later
+# definitions below — the ones pytest collects — hold everything the frozen
+# ones hold but those three facts, which they hold the new way round.
+
+
+def test_requires_names_what_is_missing_of_the_program():  # noqa: F811
+    from benchmark import manifest as M
+
+    name = "charon_tpu.core.validatorapi.ValidatorAPI.submit_registrations"
+    assert M.unresolved([]) == []
+    have = ["charon_tpu.core.validatorapi.ValidatorAPI.submit_registration", name,
+            "charon_tpu.core.validatorapi.ValidatorAPI", "charon_tpu.core.deadline",
+            "benchmark.traffic.Plan.wave_shapes"]
+    lack = ["charon_tpu.core.validatorapi.ValidatorAPI.submit_registrations_split",
+            "charon_tpu.core.no_such_module.Thing", "no_such_package.x", "charon_tpu.core.deadline.X.y"]
+    assert M.unresolved(have + lack) == lack
+    stated = {w["name"]: M.load_cell(REPO, w["name"]).config.get("requires")
+              for w in M.load_manifest(REPO)["workloads"]}
+    assert list(stated.values())[:5] == [None] * 5  # the five of PR 42 state none
+    assert stated["dv-3of4-1k-reg.attest-register"] == [name]
+
+
+def test_a_requirement_that_does_not_resolve_ends_the_run_before_boot():  # noqa: F811
+    import time
+
+    name = "charon_tpu.core.validatorapi.ValidatorAPI.submit_registrations_split"
+    t0 = time.monotonic()
+    proc = subprocess.run(
+        [sys.executable, str(REPO / "benchmark/tests/rehearse_register.py"), "--requires", name],
+        capture_output=True, text=True, timeout=120, cwd=str(REPO))
+    took = time.monotonic() - t0
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert proc.returncode == 3 and took < 30
+    assert line["correct"] is False and line["attempted"] == 0 and line["metrics"] == {}
+    assert line["error"].startswith("before boot:") and name in line["error"]
+    assert "phase cluster" not in proc.stderr  # nothing booted, nothing compiled
+
+
+def test_the_parent_program_files_its_vcs_registrations_under_slot_zero():  # noqa: F811
+    """The frozen test of this name asserts the parent's gap: `--silent
+    --unpatched` is `correct` false, `duties_missing` 6 of 6. The program
+    now files each registration under the slot of its timestamp and a
+    request is one set: the same run, NO patch, at bare quorum, completes
+    all 13 duties."""
+    from benchmark.tests import test_starts
+
+    rc, (_info, line, seen), err = test_starts._rehearse("--silent", "--unpatched")
+    assert rc == 0, err[-3000:]
+    assert line["correct"] is True and line["attempted"] == 13 and line["failed"] == 0
+    assert all(c == {"value": 0, "limit": 0} for c in line["checks"].values())
+    assert seen["patches"] == [] and len(seen["vc_rounds"]) == 1
+    assert [s[0] for s in seen["peer_sends"]] == [3, 4]  # operator 2 silent: bare quorum
+
+
 NEW = ("entry_self_s", "qbft_decide_s", "agg_bcast_self_s", "svc_queue_s", "window_wait_s",
        "idle_s.consensus", "idle_s.awaiting_input", "idle_s.entry", "idle_s.window",
        "idle_s.pack")

@@ -172,7 +172,8 @@ def _mix():
 def test_the_cell_is_in_the_manifest_with_its_per_layer_metrics():
     man = M.load_manifest(REPO)
     assert M.validate(man) == []
-    assert [w["name"] for w in man["workloads"]][-1] == CELL
+    # the fifth cell; later PRs' cells follow it (PR 44: dv-3of4-1k-reg.attest-register)
+    assert [w["name"] for w in man["workloads"]].index(CELL) == 4
     assert sum(w["chips"] == 4 for w in man["workloads"]) == 0
     cell = M.load_cell(REPO, CELL, man)
     assert (cell.chips, cell.config_name, cell.traffic_name) == (
@@ -183,12 +184,21 @@ def test_the_cell_is_in_the_manifest_with_its_per_layer_metrics():
     for m in cell.end_to_end + cell.per_layer:
         assert callable(M.load_reader(REPO, man, m.reader))
         assert m.moves in (None, "duty_p50_s")
-    assert tuple(e["name"] for e in man["per_layer"][-8:]) == NEW
-    for entry in man["per_layer"]:
+    # the manifest as PR 39 left it is its first 28 metrics: the cell's eight
+    # close it, each list its own from the start; a later cell (PR 44's) is
+    # appended behind, to these lists and to the manifest, and moves nothing
+    older = [w["name"] for w in man["workloads"]][:4]
+    assert tuple(e["name"] for e in man["per_layer"][20:28]) == NEW
+    for entry in man["per_layer"][:28]:
         if entry["name"] in NEW:
-            assert entry["workloads"] == [CELL]
+            assert entry["workloads"][0] == CELL
+            assert set(entry["workloads"][1:]) <= {"dv-3of4-1k-reg.attest-register"}
         elif entry["name"] not in LEFT_OUT:
-            assert entry["workloads"][-1] == CELL  # appended, nothing else moved
+            assert entry["workloads"][:5] == older + [CELL]  # appended, nothing else moved
+        else:
+            assert CELL not in entry["workloads"]
+    for entry in man["per_layer"][28:]:
+        assert CELL not in entry["workloads"]  # a later cell's own metrics
     (entry,) = [c for c in man["configs"] if c["name"] == "dv-3of4-1k-sync"]
     cfg = _config()
     assert cfg["source"] == entry["source"] and sorted(cfg["reduced"]) == entry["reduced"]
