@@ -492,9 +492,26 @@ def threshold_recombine(ctx: ModCtx, fr_ctx: ModCtx, t: int, sig_affine, idx):
     """(V, t) affine G2 share sigs + (V, t) int32 share indices -> [V]
     affine group signatures. THE threshold-recombination routine — the
     single place that decides Straus joint windowed mul (one shared
-    doubling chain per validator, ops/msm.py) vs per-lane 255-bit
-    double-and-add; both _threshold_agg_kernel and the sharded mesh
-    plane (parallel/mesh.py) call it."""
+    doubling chain per validator, ops/msm.py) vs the per-lane four-base
+    multiplication over psi (curve.g2_scalar_mul_psi: 64 joint steps a
+    lane, not 255); both _threshold_agg_kernel and the sharded mesh
+    plane (parallel/mesh.py) call it.
+
+    PRECONDITION: every share sig is a point of order r (or the (0, 0)
+    identity). The four-base form rests on psi(P) == [x]P, which holds on
+    G2 and nowhere else on the twist; this routine does not check again.
+    Where each caller's check happens: `step_rlc_dec` / `step_dec`
+    (parallel/mesh.py, the served path) decompress the partials in the
+    program, and decompress_g2_graph's psi subgroup check turns a lane
+    that fails into the identity and its row's `row_ok` off;
+    BLSOps.threshold_aggregate_batch takes what tbls/tpu_impl._sig_points
+    decoded (subgroup-checked: `verify_inputs`); core/autotune's probe
+    multiplies the generator. `step_rlc` / `step` on POINTS take what the
+    coalescer's host decode rung made (core/cryptoplane._decode_sig: on
+    the curve, NOT subgroup-checked, there as before this form): a partial
+    outside G2 gave a group signature outside G2 under the 255-step
+    ladder and gives another such here, and the row's group check judges
+    it either way."""
     f = C.g2_ops(ctx)
     coeffs = lagrange_coeffs_at_zero(fr_ctx, idx, t)  # (V, t, L)
     proj = C.affine_to_point(f, sig_affine)
@@ -513,10 +530,17 @@ def threshold_recombine(ctx: ModCtx, fr_ctx: ModCtx, t: int, sig_affine, idx):
         v = idx.shape[0]
         flat = lambda a: a.reshape(v * t, *a.shape[2:])
         grid = lambda a: a.reshape(v, t, *a.shape[1:])
-        scaled = C.point_scalar_mul(
-            f, fr_ctx, jax.tree_util.tree_map(flat, proj), flat(coeffs)
+        scaled = C.g2_scalar_mul_psi(
+            ctx, fr_ctx, jax.tree_util.tree_map(flat, sig_affine), flat(coeffs)
         )
-        total = C.point_sum(f, jax.tree_util.tree_map(grid, scaled), axis=-1)
+        # the fold over t is a scan of t - 1 adds on V rows: one add site
+        # in the module whatever t (each unrolled one is 13 MB of code)
+        parts = jax.tree_util.tree_map(lambda a: jnp.moveaxis(grid(a), 1, 0), scaled)
+        total, _ = jax.lax.scan(
+            lambda acc, part: (C.point_add(f, acc, part), None),
+            jax.tree_util.tree_map(lambda a: a[0], parts),
+            jax.tree_util.tree_map(lambda a: a[1:], parts),
+        )
     return C.point_to_affine(f, total)
 
 

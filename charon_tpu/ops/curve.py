@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from charon_tpu.crypto import g1g2 as REF
-from charon_tpu.crypto.fields import P
+from charon_tpu.crypto.fields import P, X_ABS
 from charon_tpu.ops import fptower as T
 from charon_tpu.ops import limb
 from charon_tpu.ops.limb import ModCtx
@@ -301,6 +301,165 @@ def point_sum(f: FieldOps, p, axis: int = -1):
     acc = terms[0]
     for t in terms[1:]:
         acc = point_add(f, acc, t)
+    return acc
+
+
+# ---------------------------------------------------------------------------
+# Four-base G2 multiplication over the psi endomorphism
+#
+# On G2 psi acts as multiplication by the BLS parameter x = -X_ABS
+# (ops/decompress.py: the subgroup check is exactly psi(P) == [x]P), so
+#
+#     [k]P = [d0]P + [d1](-psi P) + [d2](psi^2 P) + [d3](-psi^3 P)
+#
+# with d0..d3 the base-X_ABS digits of k: k < r < X_ABS^4, so four digits,
+# each under X_ABS < 2^64. One joint double-and-add of 64 steps over a
+# 16-entry table of the bases' subset sums does the work of 255 steps over
+# one base. TRUE ONLY for P of order r: blsops.threshold_recombine, the one
+# caller, says where each of ITS callers' points were subgroup-checked.
+# ---------------------------------------------------------------------------
+
+PSI_STEPS = X_ABS.bit_length()  # 64: a digit's bits, the scan's steps
+_CUT_BITS = 255  # a cut scalar is under 2^255 (Fr elements are: r < 2^255)
+
+
+@functools.lru_cache(maxsize=None)
+def _psi_cut_consts(fr_ctx: ModCtx):
+    """(shift, reciprocal limbs) for X_ABS, X_ABS^2, X_ABS^3, and X_ABS's
+    limbs. With s = 255 + bitlen(d) and m = floor(2^s / d) + 1,
+    floor(k * m / 2^s) == floor(k / d) for EVERY k < 2^255: m * d - 2^s
+    is some e in (0, d], and k * e < 2^255 * 2^bitlen(d) = 2^s keeps the
+    estimate's excess under 1 / d (Granlund-Montgomery 1994, thm 4.2).
+    No correction step."""
+    shifts, recips = [], []
+    for i in (1, 2, 3):
+        d = X_ABS**i
+        s = _CUT_BITS + d.bit_length()
+        m = (1 << s) // d + 1
+        if m.bit_length() > fr_ctx.n_limbs * fr_ctx.limb_bits:
+            raise ValueError("reciprocal wider than the Fr limbs")
+        shifts.append(s)
+        recips.append(
+            limb.int_to_limbs(m, fr_ctx.n_limbs, fr_ctx.limb_bits, fr_ctx.np_dtype)
+        )
+    x = limb.int_to_limbs(X_ABS, fr_ctx.n_limbs, fr_ctx.limb_bits, fr_ctx.np_dtype)
+    return tuple(shifts), tuple(recips), x
+
+
+def _limbs_shift_right(ctx: ModCtx, t, s: int):
+    """floor(t / 2^s) as ctx.n_limbs limbs, t canonical limbs (..., w)."""
+    q, r = divmod(s, ctx.limb_bits)
+    n = ctx.n_limbs
+
+    def window(start):
+        w = t[..., start : start + n]
+        short = n - w.shape[-1]
+        return jnp.pad(w, [(0, 0)] * (w.ndim - 1) + [(0, short)]) if short else w
+
+    if r == 0:
+        return window(q)
+    return (window(q) >> r) | ((window(q + 1) << (ctx.limb_bits - r)) & ctx.u(ctx.mask))
+
+
+def psi_digits(fr_ctx: ModCtx, scalars):
+    """Raw Fr limbs (..., n_limbs), each scalar under 2^255 -> its four
+    base-X_ABS digits (4, ..., n_limbs), least significant first:
+    sum_i d_i * X_ABS^i == k exactly, every d_i < X_ABS. Integer limb
+    work: three exact quotients by reciprocal (one stacked product), three
+    remainders (one stacked product and subtraction)."""
+    shifts, recips, x = _psi_cut_consts(fr_ctx)
+    recips = jnp.stack(recips).reshape(3, *([1] * (scalars.ndim - 1)), -1)
+    prod, _ = limb._normalize(fr_ctx, limb._conv_full(fr_ctx, scalars[None], recips))
+    # q[i] = floor(k / X_ABS^(i+1))
+    q = [_limbs_shift_right(fr_ctx, prod[i], s) for i, s in enumerate(shifts)]
+    # d_i = floor(k / X^i) - floor(k / X^(i+1)) * X lies in [0, X): taken
+    # mod 2^(limb bits) it is exact
+    back, _ = limb._normalize(
+        fr_ctx, limb._conv_low(fr_ctx, jnp.stack(q), jnp.asarray(x))
+    )
+    low = jnp.stack([scalars, q[0], q[1]]) + (fr_ctx.u(fr_ctx.mask) - back)
+    low, _ = limb._normalize(fr_ctx, low + jnp.asarray(limb._one0(fr_ctx)), passes=1)
+    return jnp.concatenate([low, q[2][None]])
+
+
+def g2_psi_bases(ctx: ModCtx, affine):
+    """Affine G2 lanes P [L] -> the affine bases (P, -psi P, psi^2 P,
+    -psi^3 P) = (P, [X]P, [X^2]P, [X^3]P), X = X_ABS, for P of order r,
+    stacked [4, L]. The (0, 0) identity maps to itself. psi three times
+    over is ONE scan of decompress.g2_psi_graph: one site of its two
+    multiplications in the compiled module."""
+    import jax
+
+    from charon_tpu.ops.decompress import g2_psi_graph
+
+    def step(a, _):
+        a = g2_psi_graph(ctx, a)
+        return a, a
+
+    _, (xs, ys) = lax.scan(step, affine, None, length=3)
+    ys = T.fp2_select(jnp.asarray([[True], [False], [True]]), T.fp2_neg(ctx, ys), ys)
+    head = lambda one, rest: jnp.concatenate([one[None], rest])
+    return jax.tree_util.tree_map(head, affine, (xs, ys))
+
+
+# T[m] = T[m - 2^k] + T[2^k] for every m with more than one bit, in an
+# order that finds both terms made: (m, m - 2^k, 2^k), k the top bit of m
+_TABLE_ADDS = tuple(
+    (m, m - (1 << k), 1 << k)
+    for k in (1, 2, 3)
+    for m in range((1 << k) + 1, 2 << k)
+)
+
+
+def g2_scalar_mul_psi(ctx: ModCtx, fr_ctx: ModCtx, affine, scalars):
+    """[k]P for FLAT affine G2 lanes [L] of order r (or the (0, 0)
+    identity) and raw Fr scalars [L, n_limbs]: projective points [L].
+
+    The table T[m] = sum_{bit i of m} B_i over the four bases (T[0] the
+    identity, so the complete add needs no mask) is filled by one scan of
+    11 adds on L lanes — ONE add site in the compiled module: an unrolled
+    complete G2 add is 13-17 MB of generated code, which a warm boot
+    loads (PERF.md §6, PRs 42 and 46) — then ONE lax.scan of 64 steps:
+    acc = 2 acc + T[m_j], m_j the j-th bits of the four digits, MSB first.
+    The coefficients are public (share indices), so nothing secret indexes
+    the table."""
+    import jax
+
+    f = g2_ops(ctx)
+    tree = jax.tree_util.tree_map
+    lanes = scalars.shape[0]
+    identity = tree(
+        lambda a: limb.match_vary(a, affine[0][0]), point_identity(f, (lanes,))
+    )
+    bases = affine_to_point(f, g2_psi_bases(ctx, affine))  # [4, L] leaves
+    table = tree(  # [16, L]: the identity but for T[2^i] = B_i
+        lambda i, b: jnp.stack(
+            [b[m.bit_length() - 1] if m in (1, 2, 4, 8) else i for m in range(16)]
+        ),
+        identity, bases,
+    )
+
+    def fill(table, add):
+        at = lambda i: tree(lambda t: lax.dynamic_index_in_dim(t, i, keepdims=False), table)
+        made = point_add(f, at(add[1]), at(add[2]))
+        return tree(lambda t, v: lax.dynamic_update_index_in_dim(t, v, add[0], 0), table, made), None
+
+    table, _ = lax.scan(fill, table, jnp.asarray(_TABLE_ADDS, jnp.int32))
+
+    bits = _scalar_bits_msb(fr_ctx, psi_digits(fr_ctx, scalars), PSI_STEPS)
+    weights = jnp.asarray([1, 2, 4, 8], jnp.int32)[None, :, None]
+    picks = jnp.sum(bits.astype(jnp.int32) * weights, axis=1, dtype=jnp.int32)  # [64, L]
+    entry = jnp.arange(16, dtype=jnp.int32)[:, None]
+
+    def step(acc, pick):
+        hit = (pick[None, :] == entry)[..., None]  # [16, L, 1]
+        addend = tree(
+            lambda t: jnp.sum(jnp.where(hit, t, jnp.zeros((), t.dtype)), axis=0, dtype=t.dtype),
+            table,
+        )
+        return point_add(f, point_double(f, acc), addend), None
+
+    acc, _ = lax.scan(step, identity, picks)
     return acc
 
 
